@@ -30,7 +30,7 @@ is then invalid.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Iterator, List
+from typing import Any, Callable, Dict, Iterator, List, Tuple
 
 from repro._compat import api_managed
 from repro.api.spec import CUSTOM_ARCH, RunSpec, SpecError
@@ -566,6 +566,12 @@ class ThreadedPSSession(TrainingSession):
     obs_rig = None
     ft_rig = None
     serve_results = None
+    #: The shared jitted packed-wire step ``(wire_p, wire_g, batch) ->
+    #: (wire_g, aux)`` once ``run`` built it (None on the tree wire or
+    #: with a ``step_fn`` override) — for inspecting what compiled.
+    worker_step = None
+    #: JAX platform of each worker's step output, after ``run``.
+    worker_platforms: Tuple[str, ...] = ()
 
     def _start(self) -> None:
         self.server = build_server(self.spec, self._ov.get("params"))
@@ -601,6 +607,7 @@ class ThreadedPSSession(TrainingSession):
         serve_threads, serve_results = _serve_threads(self)
         run_cluster(self.server, workers,
                     timeout=self._ov.get("timeout", 1200.0))
+        self.worker_platforms = tuple(wk.platform for wk in workers)
         for t in serve_threads:
             t.join(timeout=self._ov.get("timeout", 1200.0))
         if serve_threads:
@@ -655,6 +662,8 @@ class ThreadedPSSession(TrainingSession):
             # keep_unused=False prunes unread args before donation.
             return wire_g_prev.at[:].set(plan.pack(grads)), {"loss": loss}
 
+        self.worker_step = _packed_step
+
         def make_step():
             # One gradient wire buffer per worker, donated back into
             # the jit every iteration; the params buffer is the
@@ -692,7 +701,8 @@ class ThreadedPSSession(TrainingSession):
 
     # -- reporting ----------------------------------------------------
     def metrics(self) -> Dict[str, Any]:
-        out = _ps_metrics(self.engine, self.server, self.obs_rig)
+        out = _ps_metrics(self.engine, self.server, self.obs_rig,
+                          self.worker_platforms)
         if self.ft_rig is not None:
             out["ft"] = self.ft_rig.metrics()
         if self.serve_results is not None:
@@ -729,6 +739,8 @@ class TransportPSSession(TrainingSession):
     obs_rig = None
     ft_rig = None
     serve_results = None
+    #: JAX platform each spawned worker reported, after ``run``.
+    worker_platforms: Tuple[str, ...] = ()
 
     def _start(self) -> None:
         from repro.transport import PSServerEndpoint, make_transport
@@ -840,6 +852,7 @@ class TransportPSSession(TrainingSession):
             pool.terminate()
             if rpool is not None:
                 rpool.terminate()
+        self.worker_platforms = tuple(r.platform for r in self.results)
         raise_on_failure(self.results)
         if rpool is not None:
             from repro.serve import raise_on_replica_failure
@@ -847,13 +860,15 @@ class TransportPSSession(TrainingSession):
         if self.verbose:
             m = self.server.metrics
             done = sum(r.iterations_done for r in self.results)
-            print(f"workers={w} ({spec.transport.kind}) "
+            print(f"workers={w} ({spec.transport.kind}, platform="
+                  f"{','.join(sorted(set(self.worker_platforms)))}) "
                   f"iterations={done} pushes={m.total_pushes} "
                   f"applied_updates={self.server.version} "
                   f"max_stale={m.max_staleness}")
 
     def metrics(self) -> Dict[str, Any]:
-        out = _ps_metrics(self.engine, self.server, self.obs_rig)
+        out = _ps_metrics(self.engine, self.server, self.obs_rig,
+                          self.worker_platforms)
         if self.results is not None:
             out["iterations_done"] = sum(r.iterations_done
                                          for r in self.results)
@@ -906,7 +921,8 @@ def _reshard_watch(server, ft, stop) -> None:
             last = cur
 
 
-def _ps_metrics(engine: str, server, obs_rig=None) -> Dict[str, Any]:
+def _ps_metrics(engine: str, server, obs_rig=None,
+                worker_platforms=()) -> Dict[str, Any]:
     if server is None:
         return {"engine": engine}
     from repro.perfcount import snapshot_all
@@ -923,6 +939,8 @@ def _ps_metrics(engine: str, server, obs_rig=None) -> Dict[str, Any]:
         "first_loss": losses[0] if losses else None,
         "final_loss": losses[-1] if losses else None,
         "perfcount": snapshot_all(),
+        # the JAX platforms the workers' steps ran on
+        "worker_platforms": sorted({p for p in worker_platforms if p}),
     }
     if obs_rig is not None and obs_rig.summary is not None:
         out["obs"] = obs_rig.summary

@@ -10,6 +10,7 @@ from repro.models.config import ModelConfig
 
 _ARCH_MODULES = (
     "h2o_danube_1p8b",
+    "h2o_danube_1p8b_chip",
     "qwen15_110b",
     "qwen15_32b",
     "mistral_large_123b",
@@ -24,6 +25,7 @@ _ARCH_MODULES = (
 # CLI ids use dashes (match the assignment listing)
 _ALIASES = {
     "h2o-danube-1.8b": "h2o_danube_1p8b",
+    "h2o-danube-1.8b-chip": "h2o_danube_1p8b_chip",
     "qwen1.5-110b": "qwen15_110b",
     "qwen1.5-32b": "qwen15_32b",
     "mistral-large-123b": "mistral_large_123b",

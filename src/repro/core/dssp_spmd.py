@@ -151,16 +151,8 @@ def cross_pod_sync(tree: Tree, mesh: jax.sharding.Mesh,
         return jax.tree_util.tree_map(
             lambda x: jax.lax.pmean(x, "pod"), t)
 
-    try:  # jax >= 0.6: top-level API with per-axis manual mode
-        from jax import shard_map
-
-        fn = shard_map(avg, mesh=mesh, in_specs=(specs,), out_specs=specs,
+    fn = jax.shard_map(avg, mesh=mesh, in_specs=(specs,), out_specs=specs,
                        axis_names=frozenset({"pod"}), check_vma=False)
-    except ImportError:  # the experimental API this container ships
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(avg, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                       check_rep=False)
     return fn(tree)
 
 

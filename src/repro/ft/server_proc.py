@@ -48,6 +48,8 @@ import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
+from repro.launch.runtime import pin_child_platform
+
 _SPILL_PERIOD_S = 0.2
 
 
@@ -67,7 +69,7 @@ def _server_main(spec_dict: Dict[str, Any], port: int, queue,
                  trace_spill: str, kill_server_round: int,
                  incarnation: int) -> None:
     """Entry point of the spawned server process."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    pin_child_platform()
     from repro.api.session import build_server
     from repro.api.spec import RunSpec
     from repro.checkpoint.manager import CheckpointManager

@@ -10,11 +10,16 @@ across the innermost grid dimension, which TPU executes sequentially.
 
 Grid: (batch, q_heads, lq/block_q, lk/block_k) — the kv-block axis is
 innermost; GQA maps q-head h to kv-head h // (hq // hkv) in the K/V
-index_map (no materialized head broadcast).
+index_map (no materialized head broadcast).  The wrapper moves heads
+ahead of the sequence, (b, l, h, d) -> (b, h, l, d), so every block's
+last two dims are (seq block, head_dim): Mosaic tiles the last two
+dims in (8, 128) units and cannot squeeze a head axis out of the
+second-minor position.  The running max / normalizer scratch is
+lane-replicated (block_q, 128) for the same reason.
 
 The kernel is forward-only; ``ops.flash_attention`` wraps it in a
 ``jax.custom_vjp`` whose backward recomputes through the jnp reference
-(flash-bwd kernel is listed as future work in DESIGN.md §7).
+(a flash backward kernel is future work).
 """
 
 from __future__ import annotations
@@ -29,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128    # lane width of the m / l scratch (values replicated)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -39,7 +45,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     Refs (VMEM):
       q_ref (block_q, d), k_ref/v_ref (block_k, d), o_ref (block_q, d)
-      m_ref/l_ref (block_q,) f32 scratch, acc_ref (block_q, d) f32 scratch
+      m_ref/l_ref (block_q, 128) f32 scratch (lane-replicated),
+      acc_ref (block_q, d) f32 scratch
     """
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -81,22 +88,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[:, :1]                                    # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
+        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
         v = v_ref[...].astype(jnp.float32)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -121,24 +129,27 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash_kernel, scale=1.0 / math.sqrt(d), block_q=block_q,
         block_k=block_k, causal=causal, window=window, lq=lq, lk=lk)
 
-    return pl.pallas_call(
+    # heads ahead of the sequence: blocks tile (seq block, head_dim)
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block_q, None, d),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((None, block_k, None, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-            pl.BlockSpec((None, block_k, None, d),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
+            pl.BlockSpec((None, None, block_q, d),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((None, None, block_k, d),
+                         lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+            pl.BlockSpec((None, None, block_k, d),
+                         lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, None, d),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, lq, hq, d), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, d),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, lq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),    # running max m
-            pltpu.VMEM((block_q,), jnp.float32),    # normalizer l
-            pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max m
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # normalizer l
+            pltpu.VMEM((block_q, d), jnp.float32),       # accumulator
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    return out.transpose(0, 2, 1, 3)

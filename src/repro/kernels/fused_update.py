@@ -51,11 +51,19 @@ def fused_update(p: jax.Array, m: jax.Array, g: jax.Array, *,
     """One fused momentum step on an arbitrary-shaped leaf.
 
     Returns (p', m') with the input dtypes.  lr/scale may be python
-    floats or traced scalars (no recompile on change).
+    floats or traced scalars (no recompile on change).  The call is one
+    jitted program: called eagerly (the server's apply), each reshape
+    and pad would otherwise be its own dispatch with its own full-size
+    copy in device memory.
     """
     WIRE.pallas_calls += 1
     if TRACE.enabled:
         TRACE.instant("kernel_launch", args={"kernel": "fused_update"})
+    return _fused_update(p, m, g, lr, scale, beta=beta, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("beta", "interpret"))
+def _fused_update(p, m, g, lr, scale, *, beta: float, interpret: bool):
     orig_shape = p.shape
     n = p.size
     tile = _ROWS * _LANES
@@ -113,10 +121,19 @@ def _round_to(x: jax.Array, dtype) -> jax.Array:
     computation is elided by XLA's excess-precision rule, which would
     make the batched fold drift (by 1 ulp) from K sequential launches
     that physically store the narrow dtype between steps.
-    ``lax.reduce_precision`` is the documented non-elidable rounding.
+    ``lax.reduce_precision`` is the documented non-elidable rounding,
+    but Mosaic has no lowering for it, so bf16 (the wire dtype) rounds
+    to nearest-even on the f32 bit pattern instead — the same result,
+    from integer ops both compilers keep.
     """
     if jnp.dtype(dtype) == jnp.float32:
         return x
+    if jnp.dtype(dtype) == jnp.bfloat16:
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        lsb = (bits >> jnp.uint32(16)) & jnp.uint32(1)
+        bits = (bits + jnp.uint32(0x7FFF) + lsb) & jnp.uint32(0xFFFF0000)
+        return jnp.where(jnp.isnan(x), x,
+                         jax.lax.bitcast_convert_type(bits, jnp.float32))
     fi = jnp.finfo(dtype)
     return jax.lax.reduce_precision(x, fi.nexp, fi.nmant)
 
@@ -167,6 +184,14 @@ def fused_update_batched(p: jax.Array, m: jax.Array, gs: jax.Array, *,
     WIRE.pallas_calls += 1
     if TRACE.enabled:
         TRACE.instant("kernel_launch", args={"kernel": "fused_update_batched"})
+    return _fused_update_batched(p, m, gs, lr, tuple(scales), beta=beta,
+                                 interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("beta", "interpret"))
+def _fused_update_batched(p, m, gs, lr, scales, *, beta: float,
+                          interpret: bool):
+    k = gs.shape[0]
     orig_shape = p.shape
     n = p.size
     tile = _ROWS * _LANES

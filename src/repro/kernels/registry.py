@@ -27,7 +27,8 @@ grid fwd AND bwd against them.
 Fallback behavior is part of the contract: the PALLAS attention variant
 requires a block size from ``_BLOCKS`` to divide both sequence lengths
 (the flash kernel's grid constraint) and otherwise falls back to the
-XLA formulation — never an error, never a silent wrong answer.
+XLA formulation — never an error, never a silent wrong answer.  Inside
+a sharded step every PALLAS pick runs the XLA formulation (``resolved``).
 """
 
 from __future__ import annotations
@@ -54,8 +55,22 @@ def on_tpu() -> bool:
 def resolved(op: str, kernels: str = AUTO) -> KernelType:
     """The variant a spec string picks for ``op`` on the live backend
     (what a jitted step will actually compile).  Exposed for dispatch
-    tests and the kernel benchmark."""
-    return resolve(kernels, op, tpu=on_tpu())
+    tests and the kernel benchmark.
+
+    GSPMD cannot partition a Mosaic kernel, so inside a sharded step
+    (axis rules with a mesh in scope) a PALLAS pick runs the op's
+    off-TPU XLA formulation instead — as the sharded attention paths
+    always have."""
+    kt = resolve(kernels, op, tpu=on_tpu())
+    if kt is KernelType.PALLAS and _partitioned():
+        return resolve(AUTO, op, tpu=False)
+    return kt
+
+
+def _partitioned() -> bool:
+    from repro.models.sharding import current_rules
+    rules = current_rules()
+    return rules is not None and rules.mesh is not None
 
 
 # ================================================================ attention

@@ -12,7 +12,7 @@ TODO (``def add_norm(): pass``); this is the filled-in version.
 
 Grid: row blocks over the flattened (rows, d) view, same tiling as
 ``kernels/rmsnorm.py`` — a (8, d) f32 tile stays comfortably in VMEM
-for every model width in the zoo.
+for every model width in the zoo, and a partial last block is padded.
 """
 
 from __future__ import annotations
@@ -23,6 +23,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.rmsnorm import pad_rows
 
 
 def _residual_rmsnorm_kernel(x_ref, r_ref, w_ref, s_ref, o_ref, *,
@@ -49,12 +51,10 @@ def residual_rmsnorm(x: jax.Array, res: jax.Array, weight: jax.Array, *,
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
-    x2 = x.reshape(rows, d)
-    r2 = res.reshape(rows, d)
     block_rows = min(block_rows, rows)
-    if rows % block_rows:
-        block_rows = 1
-    grid = (rows // block_rows,)
+    x2 = pad_rows(x.reshape(rows, d), block_rows)
+    r2 = pad_rows(res.reshape(rows, d), block_rows)
+    grid = (x2.shape[0] // block_rows,)
 
     summed, normed = pl.pallas_call(
         functools.partial(_residual_rmsnorm_kernel, eps=eps),
@@ -69,9 +69,10 @@ def residual_rmsnorm(x: jax.Array, res: jax.Array, weight: jax.Array, *,
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, d), x.dtype),
-            jax.ShapeDtypeStruct((rows, d), x.dtype),
+            jax.ShapeDtypeStruct(x2.shape, x.dtype),
+            jax.ShapeDtypeStruct(x2.shape, x.dtype),
         ],
         interpret=interpret,
     )(x2, r2, weight)
-    return summed.reshape(orig_shape), normed.reshape(orig_shape)
+    return (summed[:rows].reshape(orig_shape),
+            normed[:rows].reshape(orig_shape))

@@ -13,6 +13,7 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -25,7 +26,12 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices: Optional[Sequence[jax.Device]] = None,
               ) -> jax.sharding.Mesh:
     """jax.make_mesh over the first prod(shape) devices (the dry-run
-    forces 512 host devices; the single-pod mesh uses the first 256)."""
+    forces 512 host devices; the single-pod mesh uses the first 256).
+
+    Every axis is Auto: sharding is propagated by GSPMD from the
+    argument shardings and the ``shard`` constraints, as the model code
+    assumes (Explicit axes would make mixed-sharding ops such as the
+    embedding gather and the DSSP ring update type errors)."""
     need = math.prod(shape)
     devs = list(devices if devices is not None else jax.devices())
     if len(devs) < need:
@@ -33,14 +39,9 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str],
             f"mesh {tuple(shape)} needs {need} devices, have {len(devs)} — "
             "run under dryrun.py (it forces 512 host devices) or shrink "
             "the mesh")
-    devs = devs[:need]
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes), devices=devs)
-    except TypeError:
-        # older jax: make_mesh without the devices kwarg
-        import numpy as np
-        arr = np.asarray(devs).reshape(tuple(shape))
-        return jax.sharding.Mesh(arr, tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs[:need])
 
 
 def elastic_mesh(n_devices: int, *, model_parallel: int = 16,

@@ -32,6 +32,8 @@ import time
 import traceback
 from typing import Any, Dict, List, Optional, Sequence
 
+from repro.launch.runtime import pin_child_platform
+
 
 @dataclasses.dataclass(frozen=True)
 class WorkerTask:
@@ -105,14 +107,16 @@ class WorkerResult:
     iterations_done: int
     error: Optional[str] = None      # traceback text for failed workers
     exitcode: Optional[int] = None
+    platform: str = ""               # the JAX backend the worker ran on
 
 
 def _worker_main(task: Dict[str, Any], address, worker_id: int,
                  slowdown: float, queue) -> None:
     """Entry point of one spawned worker process."""
     done = 0
+    platform = ""
     try:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        pin_child_platform()
         import functools
 
         import jax
@@ -126,6 +130,7 @@ def _worker_main(task: Dict[str, Any], address, worker_id: int,
         from repro.transport import connect
         from repro.wireformat import WIRE_LANES, FrameError
 
+        platform = jax.default_backend()
         cfg = (get_smoke_config(task["arch"]) if task["smoke"]
                else get_config(task["arch"]))
         if task.get("kernels", "auto") != cfg.kernels:
@@ -354,10 +359,11 @@ def _worker_main(task: Dict[str, Any], address, worker_id: int,
             client.close()
             if spill_fh is not None:
                 spill_fh.close()
-        queue.put(WorkerResult(worker_id, done))
+        queue.put(WorkerResult(worker_id, done, platform=platform))
     except BaseException:
         queue.put(WorkerResult(worker_id, done,
-                               error=traceback.format_exc()))
+                               error=traceback.format_exc(),
+                               platform=platform))
         raise
 
 

@@ -21,6 +21,8 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.launch.runtime import enable_compile_cache
+
 
 def build_spec(args) -> "RunSpec":
     from repro.api import (
@@ -90,13 +92,16 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.api import build_session
+    enable_compile_cache()
     spec = build_spec(args)
     with build_session(spec) as session:
         metrics = session.run(steps=args.steps)
 
     serve = metrics.get("serve", {})
     print(f"\narch={args.arch} transport={args.transport} "
-          f"workers={args.workers} replicas={args.replicas}")
+          f"workers={args.workers} replicas={args.replicas} "
+          f"worker_platforms={','.join(metrics['worker_platforms'])} "
+          f"replica_platforms={','.join(serve.get('platforms', []))}")
     print(f"train: pushes={metrics['pushes']} "
           f"applied_updates={metrics['applied_updates']} "
           f"final_loss={metrics['final_loss']}")

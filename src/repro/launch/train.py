@@ -29,6 +29,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.core import dssp_spmd
 from repro.data.synthetic import DataConfig, batches, loss_floor
+from repro.launch.runtime import enable_compile_cache
 from repro.models import registry
 from repro.models.sharding import use_rules
 from repro.obs.trace import TRACE
@@ -394,6 +395,7 @@ def main() -> None:
                          "real wire (implies --ps-wire packed; enables "
                          "--ps-shards 1 if unset)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.spec:
         # Every wiring flag (anything a RunSpec field covers) is

@@ -16,8 +16,6 @@ Instrumented sites:
   * ``packs`` / ``unpacks`` — pytree <-> packed-buffer boundary
     crossings (``pack_shard`` / ``unpack_shard`` and the plan-level
     ``pack`` / ``unpack``),
-  * ``gathers``       — wire-permutation gathers (one per plan-level
-    pack/unpack; the packed path's only data-movement op),
   * ``pallas_calls``  — kernel launches (``fused_update``, the fused
     compressors),
   * ``apply_launches_saved`` — contributions folded into an already-
@@ -58,7 +56,6 @@ class HotPathCounters(_CounterBase):
     leaf_concats: int = 0
     packs: int = 0
     unpacks: int = 0
-    gathers: int = 0
     pallas_calls: int = 0
     #: Launches the coalesced server apply amortized away: a window of
     #: K contributors folded in ONE ``fused_update_batched`` launch

@@ -29,13 +29,15 @@ lane-aligned ``(rows, 512)`` buffer the native representation instead:
 the whole tree lives in ONE flat buffer laid out shard-by-shard (each
 shard's slices contiguous in ``(leaf, start)`` order, each shard region
 zero-padded to a multiple of 8 rows so a Pallas ``(8, 512)`` tile grid
-lands exactly), and a precomputed index permutation converts between
-canonical flat order and wire order in a single gather:
+lands exactly).  Every slice is a contiguous run in both orders, so the
+conversion is static slices and one concatenate each way — no index
+array, which at published widths would be as large as the parameters
+and live on the device beside them:
 
-    ``pack(tree)``      1 concatenate (all leaves -> canonical flat)
-                        + 1 gather (canonical -> wire)        [jittable]
-    ``unpack(wire)``    1 gather (wire -> canonical flat)
-                        + per-leaf slice *views*               [jittable]
+    ``pack(tree)``      1 concatenate of the slices in wire order,
+                        with each shard's zero padding     [jittable]
+    ``unpack(wire)``    per-leaf slices of the flat wire (a concatenate
+                        only for a leaf split across shards) [jittable]
     ``shard_wire``      a row-slice view — NO per-leaf work at all.
 
 A worker packs its gradients once inside its jitted step; every later
@@ -86,8 +88,7 @@ class WireLayout:
     """Precomputed flat offsets of a plan's packed wire format.
 
     One layout per wire dtype (cached on the plan).  All fields are
-    host-side metadata; the two index arrays are jit constants, so
-    ``pack``/``unpack`` trace to a single fused gather each.
+    host-side metadata, so ``pack``/``unpack`` trace to static slices.
     """
 
     dtype: Any                                # wire buffer dtype
@@ -98,11 +99,10 @@ class WireLayout:
     slice_offsets: Tuple[Tuple[int, ...], ...]  # per shard: element offset
                                               # of each slice in the shard's
                                               # flat region
-    pack_idx: jax.Array                       # (total_rows*512,) wire pos ->
-                                              # canonical flat pos; padding
-                                              # points at slot total_elems
-    unpack_idx: jax.Array                     # (total_elems,) canonical flat
-                                              # pos -> wire pos
+    leaf_segments: Tuple[Tuple[Tuple[int, int], ...], ...]
+                                              # per leaf, in leading-axis
+                                              # order: (wire element offset,
+                                              # size) of each of its slices
 
     @property
     def total_wire(self) -> int:
@@ -193,9 +193,8 @@ class ShardPlan:
         return layout
 
     def _build_wire_layout(self, wdt) -> WireLayout:
-        sizes = [math.prod(s) if s else 1 for s in self.leaf_shapes]
-        leaf_off = np.concatenate([[0], np.cumsum(sizes)])
-        total = int(leaf_off[-1])
+        total = sum(math.prod(s) if s else 1 for s in self.leaf_shapes)
+
         def shard_region_rows(n_elems: int) -> int:
             if n_elems == 0:
                 return 0
@@ -206,22 +205,15 @@ class ShardPlan:
         row_start = tuple(int(x) for x in
                           np.concatenate([[0], np.cumsum(rows)])[:-1])
         total_rows = int(sum(rows))
-        pack_idx = np.full(total_rows * WIRE_LANES, total, np.int32)
-        unpack_idx = np.empty(total, np.int32)
+        segments: List[List[Tuple[int, int, int]]] = [
+            [] for _ in self.leaf_shapes]
         slice_offsets: List[Tuple[int, ...]] = []
         for j, shard in enumerate(self.shards):
             base = row_start[j] * WIRE_LANES
             off = 0
             offs = []
             for sl in shard.slices:
-                shape = self.leaf_shapes[sl.leaf]
-                row_elems = math.prod(shape[1:]) if len(shape) > 1 else 1
-                canon0 = int(leaf_off[sl.leaf]) + sl.start * row_elems
-                span = np.arange(canon0, canon0 + sl.size, dtype=np.int32)
-                pack_idx[base + off:base + off + sl.size] = span
-                unpack_idx[span] = np.arange(base + off,
-                                             base + off + sl.size,
-                                             dtype=np.int32)
+                segments[sl.leaf].append((sl.start, base + off, sl.size))
                 offs.append(off)
                 off += sl.size
             slice_offsets.append(tuple(offs))
@@ -229,15 +221,16 @@ class ShardPlan:
                           total_rows=total_rows,
                           shard_row_start=row_start, shard_rows=rows,
                           slice_offsets=tuple(slice_offsets),
-                          pack_idx=jnp.asarray(pack_idx),
-                          unpack_idx=jnp.asarray(unpack_idx))
+                          leaf_segments=tuple(
+                              tuple((w, n) for _, w, n in sorted(segs))
+                              for segs in segments))
 
     def pack(self, tree: Tree, dtype=None) -> jax.Array:
         """Tree -> one (total_rows, 512) wire buffer.
 
-        One concatenate (canonical flat order) + one precomputed gather
-        (wire order, zero-padded shard regions).  Jittable; inside a jit
-        the whole thing fuses into a single pass over the data.
+        The slices in wire order plus each shard region's zero padding,
+        concatenated once.  Jittable; inside a jit the whole thing fuses
+        into a single pass over the data.
         """
         layout = self.wire_layout(dtype)
         leaves = jax.tree_util.tree_leaves(tree)
@@ -253,32 +246,39 @@ class ShardPlan:
                 raise ValueError(f"leaf {i}: shape {tuple(x.shape)} does "
                                  f"not match plan shape {shape}")
         WIRE.packs += 1
-        WIRE.gathers += 1
-        flats = [x.reshape(-1).astype(layout.dtype) for x in leaves]
-        flats.append(jnp.zeros((1,), layout.dtype))   # padding slot
-        if len(flats) > 2:
+        if len(leaves) > 1:
             WIRE.leaf_concats += 1
-        flat = jnp.concatenate(flats)
-        wire = jnp.take(flat, layout.pack_idx, axis=0)
-        return wire.reshape(layout.total_rows, WIRE_LANES)
+        flats = [x.reshape(-1).astype(layout.dtype) for x in leaves]
+        parts = []
+        for shard, rows in zip(self.shards, layout.shard_rows):
+            for sl in shard.slices:
+                flat = flats[sl.leaf]
+                if sl.whole:
+                    parts.append(flat)
+                else:  # a leading-axis range: contiguous in row-major
+                    first = sl.start * math.prod(self.leaf_shapes[sl.leaf][1:])
+                    parts.append(flat[first:first + sl.size])
+            pad = rows * WIRE_LANES - shard.size
+            if pad:
+                parts.append(jnp.zeros((pad,), layout.dtype))
+        return jnp.concatenate(parts).reshape(layout.total_rows, WIRE_LANES)
 
     def unpack(self, wire: jax.Array, dtype=None) -> Tree:
-        """Inverse of ``pack``: one gather + per-leaf slice views."""
+        """Inverse of ``pack``: per-leaf slices of the flat wire."""
         layout = self.wire_layout(dtype)
         if wire.shape != (layout.total_rows, WIRE_LANES):
             raise ValueError(
                 f"wire buffer {wire.shape} does not match layout "
                 f"({layout.total_rows}, {WIRE_LANES})")
         WIRE.unpacks += 1
-        WIRE.gathers += 1
-        flat = jnp.take(wire.reshape(-1), layout.unpack_idx, axis=0)
+        flat = wire.reshape(-1)
         leaves = []
-        off = 0
         dtypes = self.leaf_dtypes or (jnp.float32,) * len(self.leaf_shapes)
-        for shape, dt in zip(self.leaf_shapes, dtypes):
-            size = math.prod(shape) if shape else 1
-            leaves.append(flat[off:off + size].reshape(shape).astype(dt))
-            off += size
+        for shape, dt, segs in zip(self.leaf_shapes, dtypes,
+                                   layout.leaf_segments):
+            pieces = [flat[w:w + n] for w, n in segs]
+            leaf = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+            leaves.append(leaf.reshape(shape).astype(dt))
         return self.treedef.unflatten(leaves)
 
     def shard_wire(self, wire: jax.Array, shard: int, dtype=None) -> jax.Array:

@@ -94,6 +94,7 @@ fallback and rebuild.
 
 from __future__ import annotations
 
+import collections.abc
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -117,6 +118,23 @@ from repro.wireformat import WIRE_LANES
 
 Params = Any
 Grads = Any
+
+
+class _ShardRegions(collections.abc.Sequence):
+    """The shard regions of one packed wire, each sliced when a shard
+    reads it.  Slicing all S up front would hold a second copy of the
+    whole gradient on the device for the length of the push."""
+
+    def __init__(self, plan: ShardPlan, wire: jax.Array):
+        self._plan, self._wire = plan, wire
+
+    def __len__(self) -> int:
+        return self._plan.n_shards
+
+    def __getitem__(self, j: int) -> jax.Array:
+        if not 0 <= j < self._plan.n_shards:
+            raise IndexError(j)
+        return self._plan.shard_wire(self._wire, j)
 
 
 class _ShardState:
@@ -611,7 +629,7 @@ class ShardedParameterServer(ParameterServerProtocol):
                 raise ValueError(
                     f"wire buffer {wire.shape} does not match layout "
                     f"({layout.total_rows}, {WIRE_LANES})")
-            shard_bufs = plan.shard_wires(wire)
+            shard_bufs = _ShardRegions(plan, wire)
         # Stale-epoch pushes skip wire compression: the per-(worker,
         # shard) error-feedback buffers were reset at the swap and are
         # shaped for the NEW plan — a lossless transition-window push

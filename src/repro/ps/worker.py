@@ -74,6 +74,8 @@ class PSWorker(threading.Thread):
         self.reconnect = reconnect
         self.reconnects = 0
         self.iterations_done = 0
+        #: JAX platform the step's output landed on (set by the first step)
+        self.platform = ""
         self.failure: Optional[BaseException] = None
         self._abort = threading.Event()
 
@@ -158,6 +160,8 @@ class PSWorker(threading.Thread):
                     grads, aux = self.step_fn(params, next(self.batches))
                     grads = _block(grads)
                     compute = time.monotonic() - t0
+                    if not self.platform:
+                        self.platform = _platform(grads)
                     if self.speed_factor > 1.0:
                         # The sleep IS the emulated (slower-device)
                         # compute, so the compute_step span includes it.
@@ -194,6 +198,12 @@ class PSWorker(threading.Thread):
 def _block(tree: Any) -> Any:
     import jax
     return jax.block_until_ready(tree)
+
+
+def _platform(tree: Any) -> str:
+    import jax
+    leaf = jax.tree_util.tree_leaves(tree)[0]
+    return next(iter(leaf.devices())).platform
 
 
 def run_cluster(server: ParameterServerProtocol, workers: list[PSWorker],

@@ -201,8 +201,6 @@ def model_flops(cfg, shape) -> float:
 def extract(compiled, lowered_text: Optional[str], cfg, shape,
             mesh_label: str) -> RooflineTerms:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
 

@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.launch.runtime import pin_child_platform
 from repro.obs.trace import TRACE
 from repro.serve.batching import BatchQueue, DecodeRequest
 from repro.serve.replica import ParamSubscriber, Refresher
@@ -166,6 +167,7 @@ class ReplicaResult:
     span_s: float = 0.0             # first submit -> last completion
     error: Optional[str] = None
     exitcode: Optional[int] = None
+    platform: str = ""              # the JAX backend the replica ran on
 
 
 class ReplicaWorker:
@@ -354,8 +356,10 @@ def _replica_main(task: Dict[str, Any], address, replica_id: int,
     """Entry point of one spawned serving replica process."""
     result = ReplicaResult(replica_id)
     try:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        pin_child_platform()
         import jax
+
+        result.platform = jax.default_backend()
 
         from repro.configs import get_config, get_smoke_config
         from repro.data.synthetic import DataConfig, MarkovLM
@@ -414,6 +418,7 @@ def _replica_main(task: Dict[str, Any], address, replica_id: int,
                 prompt_len=task["prompt_len"],
                 pace_s=task.get("request_every_ms", 0.0) / 1e3,
                 start_at_version=task.get("start_at_version", 0))
+            result.platform = jax.default_backend()
         finally:
             refresher.stop()
             if tracer is not None:
@@ -556,6 +561,7 @@ def aggregate_serve(results: Sequence[ReplicaResult]) -> Dict[str, Any]:
         "version_max": max(versions, default=-1),
         "legal_fraction": (sum(r.legal_fraction for r in results)
                            / len(results)) if results else 0.0,
+        "platforms": sorted({r.platform for r in results if r.platform}),
     }
 
 

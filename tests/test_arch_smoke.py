@@ -102,6 +102,7 @@ def test_full_config_param_count(arch):
     n = registry.count_params(cfg)
     expected = {
         "h2o-danube-1.8b": (1.4e9, 2.3e9),
+        "h2o-danube-1.8b-chip": (0.65e9, 0.8e9),
         "qwen1.5-110b": (95e9, 125e9),
         "qwen1.5-32b": (28e9, 38e9),
         "mistral-large-123b": (110e9, 135e9),

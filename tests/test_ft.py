@@ -517,7 +517,9 @@ def test_chaos_dssp_server_sigkill_resumes_and_recovers(tmp_path):
         "wire": {"format": "packed", "delta_pull": True},
         "sync": {"mode": "dssp"},
         "transport": {"kind": "tcp"},
-        "ft": {"snapshot_every_s": 0.3, "dir": str(ckpt), "resume": True,
+        # The cadence must put a snapshot between the first pushes and
+        # the kill; the jitted apply makes those pushes milliseconds apart.
+        "ft": {"snapshot_every_s": 0.01, "dir": str(ckpt), "resume": True,
                "reconnect_tries": 10, "reconnect_base_s": 0.1,
                "reconnect_max_s": 2.0, "fault_kill_server_round": 10,
                "fault_seed": 7},
@@ -614,7 +616,8 @@ def test_chaos_reshard_sigkill_mid_migration_resumes_untorn(tmp_path):
         "wire": {"format": "packed", "delta_pull": True},
         "sync": {"mode": "dssp"},
         "transport": {"kind": "tcp"},
-        "ft": {"snapshot_every_s": 0.3, "dir": str(ckpt), "resume": True,
+        # a snapshot must land between the first pushes and the kill
+        "ft": {"snapshot_every_s": 0.01, "dir": str(ckpt), "resume": True,
                "reconnect_tries": 10, "reconnect_base_s": 0.1,
                "reconnect_max_s": 2.0, "reshard_shards": 3,
                "reshard_round": 8, "fault_kill_mid_reshard": True,

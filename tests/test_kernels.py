@@ -363,3 +363,16 @@ def test_registry_resolved_uses_live_backend():
     assert registry.resolved("attention", "auto") \
         is interface.resolve("auto", "attention", tpu=want_tpu)
     assert registry.resolved("ssm_scan", "pallas") is KernelType.PALLAS
+
+
+def test_registry_pallas_runs_xla_inside_a_sharded_step():
+    """GSPMD cannot partition a Mosaic kernel: under axis rules with a
+    mesh every PALLAS pick resolves to the op's off-TPU formulation."""
+    from repro.launch.mesh import make_mesh
+    from repro.models.sharding import rules_for_mesh, use_rules
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with use_rules(rules_for_mesh(mesh)):
+        for op in interface.OPS:
+            assert registry.resolved(op, "pallas") \
+                is interface.resolve("auto", op, tpu=False)
+    assert registry.resolved("rmsnorm", "pallas") is KernelType.PALLAS

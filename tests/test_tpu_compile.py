@@ -1,0 +1,135 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+blocks that break the (8, 128) tiling, unaligned dynamic slices, too
+much VMEM.  Here each kernel is lowered and compiled by the TPU compiler
+for a ``v5e:2x2`` topology that is described, not attached, at the
+widths the chip runs: the ``h2o-danube-1.8b-chip`` worker step and its
+packed server buffers, and ``ssm_scan`` at Jamba's d_inner / d_state.
+Nothing executes; a test passes when the compiled program holds the
+kernel as a ``tpu_custom_call``.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU compiler library, and the test
+workers all import this file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import flash_attention as fa
+from repro.kernels import fused_compress as fc
+from repro.kernels import fused_update as fu
+from repro.kernels import residual_rmsnorm as rrn
+from repro.kernels import rmsnorm as rn
+from repro.kernels import ssm_scan as scan
+from repro.models import registry
+from repro.wireformat import WIRE_LANES, WIRE_ROWS
+
+CHIP = get_config("h2o-danube-1.8b-chip")
+JAMBA = get_config("jamba-v0.1-52b")
+SEQ = 2048
+BATCH = 4
+SHARDS = 2   # the larger shard region of a 2-4 shard server
+
+
+def _shard_rows() -> int:
+    """Rows of the larger packed shard region of the chip config."""
+    per_shard = -(-registry.count_params(CHIP) // SHARDS)
+    rows = -(-per_shard // WIRE_LANES)
+    return -(-rows // WIRE_ROWS) * WIRE_ROWS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def test_flash_attention_compiles_at_chip_widths(one_chip):
+    hd = CHIP.resolved_head_dim
+    q = ((BATCH, SEQ, CHIP.n_heads, hd), jnp.bfloat16)
+    kv = ((BATCH, SEQ, CHIP.n_kv_heads, hd), jnp.bfloat16)
+    _compile(one_chip, functools.partial(
+        fa.flash_attention_fwd, causal=True, window=CHIP.sliding_window),
+        q, kv, kv)
+
+
+@pytest.mark.parametrize("rows", [BATCH * SEQ, SEQ - 1])  # 2047: % 8 != 0
+def test_rmsnorm_compiles_for_any_row_count(one_chip, rows):
+    _compile(one_chip, rn.rmsnorm, ((rows, CHIP.d_model), jnp.bfloat16),
+             ((CHIP.d_model,), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("rows", [BATCH * SEQ, SEQ - 1])
+def test_residual_rmsnorm_compiles_for_any_row_count(one_chip, rows):
+    x = ((rows, CHIP.d_model), jnp.bfloat16)
+    _compile(one_chip, rrn.residual_rmsnorm, x, x,
+             ((CHIP.d_model,), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_fused_update_compiles_at_shard_size(one_chip, dtype):
+    buf = ((_shard_rows(), WIRE_LANES), dtype)
+    _compile(one_chip, functools.partial(fu.fused_update, lr=0.01,
+                                         beta=0.9), buf, buf, buf)
+
+
+def test_fused_update_batched_compiles_at_shard_size(one_chip):
+    rows = _shard_rows()
+    buf = ((rows, WIRE_LANES), jnp.bfloat16)
+    _compile(one_chip, functools.partial(
+        fu.fused_update_batched, lr=0.01, beta=0.9,
+        scales=(1.0, 0.5, 0.25, 0.125)),
+        buf, buf, ((4, rows, WIRE_LANES), jnp.bfloat16))
+
+
+def test_fused_int8_ef_compiles_at_shard_size(one_chip):
+    rows = _shard_rows()
+    _compile(one_chip, fc.fused_int8_ef,
+             ((rows, WIRE_LANES), jnp.bfloat16),
+             ((rows, WIRE_LANES), jnp.float32))
+
+
+def test_ssm_scan_compiles_at_jamba_widths(one_chip):
+    di, ds = JAMBA.expand * JAMBA.d_model, JAMBA.d_state
+    seq = ((1, SEQ, di), jnp.bfloat16)
+    bc = ((1, SEQ, ds), jnp.bfloat16)
+    _compile(one_chip, scan.ssm_scan, seq, seq, ((di, ds), jnp.float32),
+             bc, bc, ((1, di, ds), jnp.float32))
