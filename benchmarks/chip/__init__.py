@@ -1,0 +1,3 @@
+"""The chip benchmark: one entry point (``run.py``), data files that
+define its cells, and the yardstick (reference, peaks, trace reduction)
+that later changes to the program are measured against."""
