@@ -649,18 +649,22 @@ class ThreadedPSSession(TrainingSession):
 
             return lambda: _tree_step
 
+        from repro.obs.device import phase
         plan = self.server.plan
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def _packed_step(wire_p, wire_g_prev, batch):
-            p = plan.unpack(wire_p)
+            with phase("wire"):
+                p = plan.unpack(wire_p)
             (loss, _), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(p, batch)
             # Write the packed grads INTO the donated buffer: the
             # output aliases wire_g_prev's memory.  A plain `return
             # plan.pack(...)` would leave wire_g_prev unread, and jit's
             # keep_unused=False prunes unread args before donation.
-            return wire_g_prev.at[:].set(plan.pack(grads)), {"loss": loss}
+            with phase("wire"):
+                wire_g = wire_g_prev.at[:].set(plan.pack(grads))
+            return wire_g, {"loss": loss}
 
         self.worker_step = _packed_step
 

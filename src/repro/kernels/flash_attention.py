@@ -151,5 +151,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, d), jnp.float32),       # accumulator
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qh, kh, vh)
     return out.transpose(0, 2, 1, 3)

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.obs.trace import TRACE
 from repro.perfcount import WIRE
 from repro.wireformat import WIRE_LANES as _LANES
 from repro.wireformat import WIRE_ROWS as _ROWS
@@ -83,8 +82,6 @@ def fused_int8_ef(g: jax.Array, err: jax.Array, *,
     if rows == 0:
         return g, err
     WIRE.pallas_calls += 1
-    if TRACE.enabled:
-        TRACE.instant("kernel_launch", args={"kernel": "fused_int8_ef"})
     spec = pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _int8_ef_kernel,
@@ -94,6 +91,7 @@ def fused_int8_ef(g: jax.Array, err: jax.Array, *,
         out_shape=(jax.ShapeDtypeStruct((rows, _LANES), g.dtype),
                    jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)),
         interpret=interpret,
+        name="fused_int8_ef",
     )(g, err)
 
 
@@ -133,8 +131,6 @@ def fused_topk_ef(g: jax.Array, err: jax.Array, *, fraction: float = 0.05,
     if rows == 0:
         return g, err
     WIRE.pallas_calls += 1
-    if TRACE.enabled:
-        TRACE.instant("kernel_launch", args={"kernel": "fused_topk_ef"})
     spec = pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_topk_ef_kernel, fraction=fraction),
@@ -144,4 +140,5 @@ def fused_topk_ef(g: jax.Array, err: jax.Array, *, fraction: float = 0.05,
         out_shape=(jax.ShapeDtypeStruct((rows, _LANES), g.dtype),
                    jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)),
         interpret=interpret,
+        name="fused_topk_ef",
     )(g, err)

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.obs.trace import TRACE
 from repro.perfcount import WIRE
 from repro.wireformat import WIRE_LANES as _LANES
 from repro.wireformat import WIRE_ROWS as _ROWS
@@ -57,8 +56,6 @@ def fused_update(p: jax.Array, m: jax.Array, g: jax.Array, *,
     copy in device memory.
     """
     WIRE.pallas_calls += 1
-    if TRACE.enabled:
-        TRACE.instant("kernel_launch", args={"kernel": "fused_update"})
     return _fused_update(p, m, g, lr, scale, beta=beta, interpret=interpret)
 
 
@@ -182,8 +179,6 @@ def fused_update_batched(p: jax.Array, m: jax.Array, gs: jax.Array, *,
         return fused_update(p, m, gs[0], lr=lr, beta=beta,
                             scale=scales[0], interpret=interpret)
     WIRE.pallas_calls += 1
-    if TRACE.enabled:
-        TRACE.instant("kernel_launch", args={"kernel": "fused_update_batched"})
     return _fused_update_batched(p, m, gs, lr, tuple(scales), beta=beta,
                                  interpret=interpret)
 
@@ -222,6 +217,7 @@ def _fused_update_batched(p, m, gs, lr, scales, *, beta: float,
         out_shape=(jax.ShapeDtypeStruct((rows, _LANES), p.dtype),
                    jax.ShapeDtypeStruct((rows, _LANES), m.dtype)),
         interpret=interpret,
+        name="fused_momentum_apply_batched",
     )(scalars, p2, m2, g2)
     po = po.reshape(-1)[:n].reshape(orig_shape)
     mo = mo.reshape(-1)[:n].reshape(orig_shape)

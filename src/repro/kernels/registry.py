@@ -46,6 +46,7 @@ from repro.kernels import residual_rmsnorm as _rrn
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssm_scan as _scan
 from repro.kernels.interface import AUTO, KernelType, resolve
+from repro.obs.device import phase
 
 
 def on_tpu() -> bool:
@@ -101,10 +102,11 @@ def _attention_pallas_fwd(q, k, v, causal, window, block_q, block_k):
 
 def _attention_pallas_bwd(causal, window, block_q, block_k, res, dout):
     q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _ref.flash_attention_ref(
-            q_, k_, v_, causal=causal, window=window), q, k, v)
-    return vjp(dout)
+    with phase("attn_bwd"):
+        _, vjp = jax.vjp(
+            lambda q_, k_, v_: _ref.flash_attention_ref(
+                q_, k_, v_, causal=causal, window=window), q, k, v)
+        return vjp(dout)
 
 
 _attention_pallas.defvjp(_attention_pallas_fwd, _attention_pallas_bwd)
@@ -145,15 +147,17 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     PALLAS: the flash kernel (online softmax, (block_q, lk) working
     set); falls back to XLA when no ``_BLOCKS`` entry divides lq and
-    lk.  XLA: the quadratic masked formulation.
+    lk.  XLA: the quadratic masked formulation.  Its ops carry the
+    ``attn_fwd`` device phase (its backward, ``attn_bwd``).
     """
     kt = resolved("attention", kernels)
-    if kt is KernelType.PALLAS:
-        bq = _pick_block(q.shape[1])
-        bk = _pick_block(k.shape[1])
-        if bq is not None and bk is not None:
-            return _attention_pallas(q, k, v, causal, window, bq, bk)
-    return _attention_xla(q, k, v, causal=causal, window=window)
+    with phase("attn_fwd"):
+        if kt is KernelType.PALLAS:
+            bq = _pick_block(q.shape[1])
+            bk = _pick_block(k.shape[1])
+            if bq is not None and bk is not None:
+                return _attention_pallas(q, k, v, causal, window, bq, bk)
+        return _attention_xla(q, k, v, causal=causal, window=window)
 
 
 # ================================================================= rmsnorm

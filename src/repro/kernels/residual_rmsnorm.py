@@ -73,6 +73,7 @@ def residual_rmsnorm(x: jax.Array, res: jax.Array, weight: jax.Array, *,
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
         ],
         interpret=interpret,
+        name="residual_rmsnorm",
     )(x2, r2, weight)
     return (summed[:rows].reshape(orig_shape),
             normed[:rows].reshape(orig_shape))

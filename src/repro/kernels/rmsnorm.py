@@ -51,6 +51,7 @@ def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, weight)
     return out[:rows].reshape(orig_shape)
 

@@ -129,5 +129,6 @@ def ssm_scan(u: jax.Array, delta: jax.Array, a: jax.Array,
             pltpu.VMEM((ds, bd), jnp.float32),   # carried state, transposed
         ],
         interpret=interpret,
+        name="ssm_scan",
     )(u, delta, a.T, bmat, cmat, h0.transpose(0, 2, 1))
     return y[:, :l], h_last.transpose(0, 2, 1)

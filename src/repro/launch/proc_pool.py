@@ -126,6 +126,7 @@ def _worker_main(task: Dict[str, Any], address, worker_id: int,
         from repro.configs import get_config, get_smoke_config
         from repro.data.synthetic import DataConfig, batches
         from repro.models import registry
+        from repro.perfcount import WIRE
         from repro.ps.sharded.plan import build_shard_plan
         from repro.transport import connect
         from repro.wireformat import WIRE_LANES, FrameError
@@ -284,6 +285,7 @@ def _worker_main(task: Dict[str, Any], address, worker_id: int,
                         # and the resident buffer mutates in place next
                         # pull).
                         wire_p = jnp.array(wire_host)
+                        WIRE.h2d_bytes += wire_host.nbytes
                     else:
                         wire_np = client.pull_packed()
                         if wire_np is None:
@@ -304,6 +306,8 @@ def _worker_main(task: Dict[str, Any], address, worker_id: int,
                             if wire_np is None:
                                 break
                         wire_p = jnp.asarray(wire_np)
+                        WIRE.h2d_bytes += wire_np.nbytes
+                    WIRE.pulls += 1
                     batch = {k: jnp.asarray(v)
                              for k, v in next(stream).items()}
                     t_tr = tracer.now() if tracer is not None else 0.0
@@ -311,14 +315,19 @@ def _worker_main(task: Dict[str, Any], address, worker_id: int,
                     wire_g, loss = packed_step(wire_p, wire_g, batch)
                     loss = float(jax.block_until_ready(loss))
                     compute = time.monotonic() - t0
-                    if slowdown > 1.0:
-                        # The sleep IS the emulated slower device, so
-                        # the compute_step span includes it.
-                        time.sleep(compute * (slowdown - 1.0))
                     if tracer is not None:
                         tracer.span("compute_step", t_tr,
                                     worker=worker_id, clock=it,
                                     args={"loss": loss})
+                    if slowdown > 1.0:
+                        # The sleep IS the emulated slower device: its
+                        # own span, which summarize counts as compute.
+                        t_sleep = tracer.now() if tracer is not None \
+                            else 0.0
+                        time.sleep(compute * (slowdown - 1.0))
+                        if tracer is not None:
+                            tracer.span("straggler_sleep", t_sleep,
+                                        worker=worker_id, clock=it)
                     client.record_loss(it, loss)
                     if not client.push_packed(np.asarray(wire_g),
                                               clock=it):

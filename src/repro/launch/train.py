@@ -32,6 +32,7 @@ from repro.data.synthetic import DataConfig, batches, loss_floor
 from repro.launch.runtime import enable_compile_cache
 from repro.models import registry
 from repro.models.sharding import use_rules
+from repro.obs.device import phase
 from repro.obs.trace import TRACE
 from repro.optim import make_optimizer
 from repro.optim.compression import make_compressor
@@ -115,15 +116,16 @@ class Trainer:
                     loss_fn, has_aux=True)(params, batch)
                 if compressor.name != "none":
                     grads, err = compressor.apply(grads, err)
-                if use_pipeline:
-                    grads, valid, pipeline = dssp_spmd.push_pop(
-                        pipeline, grads, delay)
-                    staleness, lr_scale = delay, valid
-                else:
-                    staleness, lr_scale = 0, 1.0
-                params, opt_state = opt.update(
-                    grads, opt_state, params, staleness=staleness,
-                    lr_scale=lr_scale)
+                with phase("optimizer"):
+                    if use_pipeline:
+                        grads, valid, pipeline = dssp_spmd.push_pop(
+                            pipeline, grads, delay)
+                        staleness, lr_scale = delay, valid
+                    else:
+                        staleness, lr_scale = 0, 1.0
+                    params, opt_state = opt.update(
+                        grads, opt_state, params, staleness=staleness,
+                        lr_scale=lr_scale)
             return params, opt_state, pipeline, err, loss
 
         return jax.jit(step, donate_argnums=(0, 1, 2, 3))
@@ -152,7 +154,10 @@ class Trainer:
         it = batches(self.cfg, self.data_cfg, start_step=self.step_idx)
         end = self.step_idx + n_steps
         while self.step_idx < end:
+            t_tr = TRACE.now() if TRACE.enabled else 0.0
             batch = {k: jnp.asarray(v) for k, v in next(it).items()}
+            if TRACE.enabled:
+                TRACE.span("batch", t_tr, worker=0, clock=self.step_idx)
             if self.sync == "dssp":
                 delay = self.controller.delay()
             elif self.sync == "ssp":
@@ -165,9 +170,14 @@ class Trainer:
              self.err_state, loss) = self._jit_step(
                 self.params, self.opt_state, self.pipeline,
                 self.err_state, batch, jnp.int32(delay))
+            if TRACE.enabled:
+                TRACE.span("dispatch", t_tr, worker=0, clock=self.step_idx)
+            t_wait = TRACE.now() if TRACE.enabled else 0.0
             loss = jax.block_until_ready(loss)
             dt = time.monotonic() - t0
             if TRACE.enabled:
+                TRACE.span("device_wait", t_wait, worker=0,
+                           clock=self.step_idx)
                 TRACE.span("compute_step", t_tr, worker=0,
                            clock=self.step_idx,
                            args={"loss": float(loss), "delay": int(delay)})

@@ -46,8 +46,9 @@ def summarize(events: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
                       and int(e.get("worker", -1)) >= 0})
     wait_s = sum(float(e["dur"]) for e in spans
                  if e.get("name") == "gate_wait")
+    # A straggler's emulated sleep is compute it stands in for.
     busy_s = sum(float(e["dur"]) for e in spans
-                 if e.get("name") == "compute_step")
+                 if e.get("name") in ("compute_step", "straggler_sleep"))
     worker_seconds = wall * max(len(workers), 1)
     wait_fraction = (wait_s / worker_seconds) if worker_seconds > 0 else 0.0
 

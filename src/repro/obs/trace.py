@@ -50,7 +50,9 @@ import collections
 import itertools
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+from repro import perfcount
 
 #: Ring capacity when ``enable`` is not given one.  At ~100 bytes per
 #: event tuple this bounds a recorder around a few MB.
@@ -60,12 +62,19 @@ DEFAULT_CAPACITY = 65536
 EVENT_NAMES = (
     "push",              # span: one gated push, server side
     "gate_wait",         # span: time blocked in the Algorithm-1 gate
-    "apply",             # span: one optimizer apply (tree or fused)
+    "apply",             # span: one optimizer apply (tree or fused);
+                         #       times the host enqueue, not the device
     "coalesce_flush",    # span: one batched fused_update_batched launch
-    "pull",              # span: full-snapshot pull
+    "pull",              # span: full-snapshot pull (host enqueue)
     "pull_delta",        # span: version-delta pull
-    "kernel_launch",     # instant: one pallas_call dispatch
-    "compute_step",      # span: one worker forward/backward iteration
+    "batch",             # span: the next batch made and put on the device
+    "compute_step",      # span: one worker forward/backward iteration,
+                         #       from its batch to the gradient on hand
+    "dispatch",          # span: the jitted step call until it returns
+                         #       (inside compute_step)
+    "device_wait",       # span: blocked on the step's result
+                         #       (inside compute_step)
+    "straggler_sleep",   # span: a slowed worker's emulated extra compute
     "dssp_decision",     # instant: Algorithm-1/2 gate decision (DSSP)
     "frame_tx",          # instant: one encoded transport frame
     "frame_rx",          # instant: one decoded transport frame
@@ -73,6 +82,8 @@ EVENT_NAMES = (
     "snapshot",          # span: one whole server checkpoint (repro.ft)
     "snapshot_shard",    # span: one shard's state grab UNDER its lock —
                          #       the only pause a snapshot imposes
+    "reshard",           # span: one live reshard, swap to replay
+    "reshard_shard",     # span: one old shard retired under its lock
     "reconnect",         # span: a client's backoff reconnect loop
     "failover",          # span: server restart-and-resume from a snapshot
     "fault",             # instant: one injected FaultPlan event
@@ -82,12 +93,39 @@ EVENT_NAMES = (
                          #       SSP gate until a fresh refresh landed
 )
 
+#: Device phases: the ``jax.named_scope`` names the jitted code puts on
+#: its ops (``repro.obs.device.phase``), so a device trace can say which
+#: part of the program each device-second belongs to.
+PHASES = (
+    "wire",       # packed-wire glue: unpack/pack in the step, pull concat
+    "attn_fwd",   # the attention kernel, forward and remat recompute
+    "attn_bwd",   # the attention backward
+    "optimizer",  # the SPMD step's optimizer update and delay ring
+    "apply",      # the server's fused momentum apply
+)
+
+#: Jitted programs that are one phase as a whole, by function name: an
+#: op of theirs belongs to the phase without a scope inside (a scope
+#: would rename the apply kernel's custom call).
+PHASE_PROGRAMS = {
+    "_fused_update": "apply",
+    "_fused_update_batched": "apply",
+    "apply_region": "apply",
+    "wire_concat": "wire",
+}
+
+#: What a fold of device ops by phase reports besides ``PHASES``: the
+#: rest of a differentiated step's forward and backward (told apart by
+#: the ``transpose(`` JAX puts on the backward's op path), and ops under
+#: no phase at all.
+FOLD_PHASES = PHASES + ("fwd", "bwd", "unscoped")
+
 
 class TraceRecorder:
     """Bounded, process-local, thread-tolerant event ring."""
 
     __slots__ = ("enabled", "source", "capacity", "_events", "_seq",
-                 "_wall0", "_mono0", "_lock")
+                 "_wall0", "_mono0", "_lock", "_hooks", "_counters")
 
     def __init__(self) -> None:
         self.enabled = False
@@ -99,6 +137,8 @@ class TraceRecorder:
         self._wall0 = 0.0
         self._mono0 = 0.0
         self._lock = threading.Lock()
+        self._hooks: List[Callable[[str], None]] = []
+        self._counters: List[Optional[Dict]] = [None, None]
 
     # -- lifecycle -------------------------------------------------------
     def enable(self, source: str = "server",
@@ -111,13 +151,38 @@ class TraceRecorder:
             self._seq = itertools.count()
             self._wall0 = time.time()
             self._mono0 = time.perf_counter()
+            self._counters = [perfcount.snapshot_all(), None]
             self.enabled = True
+        for hook in list(self._hooks):
+            hook("enable")
 
     def disable(self) -> None:
         """Stop recording and drop anything not yet drained."""
         with self._lock:
             self.enabled = False
             self._events.clear()
+            if self._counters[0] is not None and self._counters[1] is None:
+                self._counters[1] = perfcount.snapshot_all()
+        for hook in list(self._hooks):
+            hook("disable")
+
+    def add_hook(self, hook: Callable[[str], None]) -> None:
+        """Call ``hook("enable")`` / ``hook("disable")`` each time the
+        recorder is armed or stopped (once per distinct hook)."""
+        if hook not in self._hooks:
+            self._hooks.append(hook)
+
+    def window_counters(self) -> Dict[str, Dict[str, int]]:
+        """The ``repro.perfcount`` counters' deltas over the last armed
+        interval: ``enable`` to ``disable``, or to now while armed;
+        ``{}`` if the recorder was never armed."""
+        before, after = self._counters
+        if before is None:
+            return {}
+        after = after if after is not None else perfcount.snapshot_all()
+        return {group: {k: v - before[group].get(k, 0)
+                        for k, v in now.items()}
+                for group, now in after.items()}
 
     # -- recording (the hot path) ----------------------------------------
     def now(self) -> float:

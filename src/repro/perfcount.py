@@ -21,7 +21,11 @@ Instrumented sites:
   * ``apply_launches_saved`` — contributions folded into an already-
     counted ``fused_update_batched`` launch by the coalescing window,
   * ``delta_bytes_tx`` / ``full_pull_bytes_avoided`` — version-delta
-    pull accounting (``pull_delta`` on either server).
+    pull accounting (``pull_delta`` on either server),
+  * ``pulls`` / ``pull_assemble_bytes`` / ``h2d_bytes`` — the device
+    copies a worker's pull costs: the pulls workers made, the bytes of
+    the sharded server's shard concat (on a cache miss), and the bytes
+    of the host buffers a delta or process-transport pull uploads.
 
 Counters are plain ints bumped under the GIL — cheap enough to stay on
 permanently, precise enough for the single-threaded benchmark and test
@@ -78,6 +82,14 @@ class HotPathCounters(_CounterBase):
     reshard_parked: int = 0
     reshard_replayed: int = 0
     reshard_translated: int = 0
+    #: Parameter pulls a worker made (threaded or process worker).
+    pulls: int = 0
+    #: Bytes ``ShardedParameterServer.pull_packed`` wrote assembling
+    #: the shard buffers into one wire on a cache miss (0 on a hit).
+    pull_assemble_bytes: int = 0
+    #: Bytes of host wire buffers uploaded to the device by a pull: the
+    #: delta puller's patched buffer, a process worker's pulled wire.
+    h2d_bytes: int = 0
 
 
 #: Process-global counters — reset + snapshot around the region of

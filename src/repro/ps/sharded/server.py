@@ -95,6 +95,7 @@ fallback and rebuild.
 from __future__ import annotations
 
 import collections.abc
+import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -120,6 +121,20 @@ Params = Any
 Grads = Any
 
 
+@jax.jit
+def wire_concat(*bufs: jax.Array) -> jax.Array:
+    """The shard buffers as one wire, in one program named for its
+    device phase (``repro.obs.trace.PHASE_PROGRAMS``)."""
+    return jnp.concatenate(bufs)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def apply_region(wire: jax.Array, start, rows: int) -> jax.Array:
+    """Rows ``[start, start + rows)`` of a packed wire: one shard's
+    gradient region, cut in a program of the ``apply`` phase."""
+    return jax.lax.dynamic_slice_in_dim(wire, start, rows)
+
+
 class _ShardRegions(collections.abc.Sequence):
     """The shard regions of one packed wire, each sliced when a shard
     reads it.  Slicing all S up front would hold a second copy of the
@@ -134,7 +149,9 @@ class _ShardRegions(collections.abc.Sequence):
     def __getitem__(self, j: int) -> jax.Array:
         if not 0 <= j < self._plan.n_shards:
             raise IndexError(j)
-        return self._plan.shard_wire(self._wire, j)
+        layout = self._plan.wire_layout()
+        return apply_region(self._wire, layout.shard_row_start[j],
+                            layout.shard_rows[j])
 
 
 class _ShardState:
@@ -436,7 +453,11 @@ class ShardedParameterServer(ParameterServerProtocol):
                                args={"packed": True, "cached": True})
                 return wire
         bufs = [b for b in snaps if b.shape[0]]
-        wire = bufs[0] if len(bufs) == 1 else jnp.concatenate(bufs)
+        if len(bufs) == 1:
+            wire = bufs[0]
+        else:
+            wire = wire_concat(*bufs)
+            WIRE.pull_assemble_bytes += wire.nbytes
         with self._snap_lock:
             # A slower concurrent pull may finish its concat AFTER a
             # fresher one: install only if this snapshot DOMINATES the

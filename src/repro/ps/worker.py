@@ -35,6 +35,7 @@ from typing import Any, Callable, Iterator, Optional
 from repro._compat import warn_legacy
 from repro.api.protocol import ParameterServerProtocol
 from repro.obs.trace import TRACE
+from repro.perfcount import WIRE
 
 StepFn = Callable[[Any, Any], Any]  # (params, batch) -> (grads, aux)
 
@@ -132,6 +133,7 @@ class PSWorker(threading.Thread):
             # jnp.array COPIES (asarray may alias on CPU, and the host
             # buffer mutates in place on the next pull)
             state["wire"] = jnp.array(state["host"])
+            WIRE.h2d_bytes += state["host"].nbytes
             return state["wire"]
 
         return pull
@@ -155,20 +157,37 @@ class PSWorker(threading.Thread):
                     break
                 try:
                     params = pull(self.worker_id)
+                    WIRE.pulls += 1
                     t_tr = TRACE.now() if TRACE.enabled else 0.0
                     t0 = time.monotonic()
-                    grads, aux = self.step_fn(params, next(self.batches))
+                    batch = next(self.batches)
+                    if TRACE.enabled:
+                        TRACE.span("batch", t_tr, worker=self.worker_id,
+                                   clock=it)
+                    t_call = TRACE.now() if TRACE.enabled else 0.0
+                    grads, aux = self.step_fn(params, batch)
+                    if TRACE.enabled:
+                        TRACE.span("dispatch", t_call,
+                                   worker=self.worker_id, clock=it)
+                    t_wait = TRACE.now() if TRACE.enabled else 0.0
                     grads = _block(grads)
                     compute = time.monotonic() - t0
+                    if TRACE.enabled:
+                        TRACE.span("device_wait", t_wait,
+                                   worker=self.worker_id, clock=it)
+                        TRACE.span("compute_step", t_tr,
+                                   worker=self.worker_id, clock=it)
                     if not self.platform:
                         self.platform = _platform(grads)
                     if self.speed_factor > 1.0:
                         # The sleep IS the emulated (slower-device)
-                        # compute, so the compute_step span includes it.
+                        # compute: its own span, which summarize counts
+                        # as compute.
+                        t_sleep = TRACE.now() if TRACE.enabled else 0.0
                         time.sleep(compute * (self.speed_factor - 1.0))
-                    if TRACE.enabled:
-                        TRACE.span("compute_step", t_tr,
-                                   worker=self.worker_id, clock=it)
+                        if TRACE.enabled:
+                            TRACE.span("straggler_sleep", t_sleep,
+                                       worker=self.worker_id, clock=it)
                     if self.loss_from_aux is not None:
                         self.server.record_loss(it,
                                                 self.loss_from_aux(aux))

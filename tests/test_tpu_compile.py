@@ -15,7 +15,9 @@ workers all import this file.
 """
 
 import functools
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -133,3 +135,65 @@ def test_ssm_scan_compiles_at_jamba_widths(one_chip):
     bc = ((1, SEQ, ds), jnp.bfloat16)
     _compile(one_chip, scan.ssm_scan, seq, seq, ((di, ds), jnp.float32),
              bc, bc, ((1, di, ds), jnp.float32))
+
+
+# ------------------------------------------------ kernel names on the chip
+_NAMED = {
+    "flash_attention_fwd": (functools.partial(
+        fa.flash_attention_fwd, causal=True, window=CHIP.sliding_window),
+        [((1, 256, CHIP.n_heads, CHIP.resolved_head_dim), jnp.bfloat16),
+         ((1, 256, CHIP.n_kv_heads, CHIP.resolved_head_dim), jnp.bfloat16),
+         ((1, 256, CHIP.n_kv_heads, CHIP.resolved_head_dim),
+          jnp.bfloat16)]),
+    "rmsnorm": (rn.rmsnorm, [((64, CHIP.d_model), jnp.bfloat16),
+                             ((CHIP.d_model,), jnp.bfloat16)]),
+    "residual_rmsnorm": (rrn.residual_rmsnorm,
+                         [((64, CHIP.d_model), jnp.bfloat16)] * 2
+                         + [((CHIP.d_model,), jnp.bfloat16)]),
+    "fused_momentum_apply_batched": (functools.partial(
+        fu.fused_update_batched, lr=0.01, scales=(1.0, 0.5)),
+        [((64, WIRE_LANES), jnp.bfloat16)] * 2
+        + [((2, 64, WIRE_LANES), jnp.bfloat16)]),
+    "fused_int8_ef": (fc.fused_int8_ef, [((64, WIRE_LANES), jnp.bfloat16),
+                                         ((64, WIRE_LANES), jnp.float32)]),
+    "fused_topk_ef": (fc.fused_topk_ef, [((64, WIRE_LANES), jnp.bfloat16),
+                                         ((64, WIRE_LANES), jnp.float32)]),
+    "ssm_scan": (scan.ssm_scan, [
+        ((1, 256, 256), jnp.bfloat16), ((1, 256, 256), jnp.bfloat16),
+        ((256, 16), jnp.float32), ((1, 256, 16), jnp.bfloat16),
+        ((1, 256, 16), jnp.bfloat16), ((1, 256, 16), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NAMED))
+def test_kernel_custom_call_is_named(one_chip, name):
+    """A device trace names each kernel's custom call by its ``name=``,
+    whatever program the kernel is compiled into."""
+    fn, shapes = _NAMED[name]
+    text = _compile(one_chip, fn, *shapes)
+    assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
+
+
+def test_apply_kernel_named_by_its_program_on_the_recorded_trace(one_chip):
+    """The apply kernel keeps the name of its program, ``_fused_update``
+    (what the benchmark's apply reader matches), and its compiled
+    instruction heads equal those of the ops a chip trace recorded, which
+    is what the benchmark joins op paths on."""
+    from repro.obs.device import module_op_paths, op_head
+    buf = ((378880, WIRE_LANES), jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (buf, buf, buf)]
+    compiled = fu._fused_update.lower(
+        *args, 0.003, 1.0, beta=0.9, interpret=False).compile()
+    heads = module_op_paths(compiled.as_text(), "jit__fused_update")
+    excerpt = os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmarks", "chip", "testdata",
+                           "trace_excerpt.json")
+    with open(excerpt) as f:
+        recorded = {e["name"] for e in json.load(f)["events"]
+                    if e["name"].startswith(
+                        ("%_fused_update.1 = (bf16[378880,",
+                         "%maximum_bitcast_fusion = f32[1,2]"))}
+    assert any(n.startswith("%_fused_update") for n in recorded)
+    for name in recorded:
+        assert heads[op_head(name)].startswith("jit(_fused_update)"), name
