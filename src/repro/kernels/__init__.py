@@ -6,7 +6,9 @@ mode on CPU against the ref.py jnp oracles; native lowering on TPU).
                    selected by the ``model.kernels`` spec string
   interface        the jax-free half of the registry: KernelType enum,
                    op/variant tables, spec-string parsing
-  flash_attention  causal / sliding-window / GQA, online softmax in VMEM
+  flash_attention  causal / sliding-window / GQA, online softmax in VMEM;
+                   its backward recomputes P block by block (no lq x lk
+                   array in HBM)
   rmsnorm          fused single-pass RMSNorm
   residual_rmsnorm fused residual-add + RMSNorm (pre-norm block glue)
   ssm_scan         selective scan with VMEM-resident state carry

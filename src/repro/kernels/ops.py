@@ -5,9 +5,9 @@ execute in ``interpret=True`` mode (same code path, CPU semantics), and
 ``flash_attention`` additionally drops to the jnp reference when the
 sequence lengths do not divide its block size — callers never see a
 TPU-only error.  On TPU the same calls compile natively.  The
-flash-attention wrapper adds a ``jax.custom_vjp`` whose backward
-recomputes through the jnp reference — forward-pass memory wins are the
-kernel's contribution, the bwd kernel is future work (DESIGN.md §7).
+flash-attention wrapper adds a ``jax.custom_vjp`` whose backward is the
+registry's (``registry.attention_bwd``): the flash backward kernel where
+its blocks tile the shapes, else the jnp reference's vjp.
 
 The worker-step ops (attention / rmsnorm / residual_rmsnorm / ssm_scan)
 have moved behind the enum-dispatched ``repro.kernels.registry``; the
@@ -27,6 +27,7 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_compress as _fc
 from repro.kernels import fused_update as _fu
 from repro.kernels import ref as _ref
+from repro.kernels import registry as _registry
 from repro.kernels import rmsnorm as _rn
 
 
@@ -53,15 +54,12 @@ def flash_attention(q, k, v, causal: bool = True,
 
 def _fa_fwd(q, k, v, causal, window, block):
     out = flash_attention(q, k, v, causal, window, block)
-    return out, (q, k, v)
+    return out, (q, k, v, out)
 
 
 def _fa_bwd(causal, window, block, res, dout):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _ref.flash_attention_ref(
-            q_, k_, v_, causal=causal, window=window), q, k, v)
-    return vjp(dout)
+    return _registry.attention_bwd(*res, dout, causal=causal,
+                                   window=window, interpret=not on_tpu())
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

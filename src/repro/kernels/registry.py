@@ -8,9 +8,10 @@ by the validated ``model.kernels`` spec string:
     variant           what runs
     ----------------  -------------------------------------------------
     PALLAS            the Pallas kernel (native on TPU, interpret=True
-                      everywhere else), wrapped in ``jax.custom_vjp``
-                      whose backward recomputes through the matching
-                      ``kernels/ref.py`` oracle
+                      everywhere else), wrapped in ``jax.custom_vjp``;
+                      attention's backward is the flash backward kernel
+                      (``attention_bwd``), the others recompute through
+                      the matching ``kernels/ref.py`` oracle
     XLA               the jnp reference formulation (native autodiff) —
                       bit-identical to the oracle by construction
     XLA_ASSOCIATIVE   ssm_scan only: the chunked associative-scan
@@ -47,6 +48,7 @@ from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssm_scan as _scan
 from repro.kernels.interface import AUTO, KernelType, resolve
 from repro.obs.device import phase
+from repro.perfcount import KERNELS
 
 
 def on_tpu() -> bool:
@@ -87,29 +89,48 @@ def _pick_block(n: int) -> Optional[int]:
     return None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _attention_pallas(q, k, v, causal: bool, window: Optional[int],
-                      block_q: int, block_k: int):
+                      block_q: int, block_k: int, interpret: bool):
     return _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
                                    block_q=block_q, block_k=block_k,
-                                   interpret=not on_tpu())
+                                   interpret=interpret)
 
 
-def _attention_pallas_fwd(q, k, v, causal, window, block_q, block_k):
-    return _attention_pallas(q, k, v, causal, window, block_q, block_k), \
-        (q, k, v)
+def _attention_pallas_fwd(q, k, v, causal, window, block_q, block_k,
+                          interpret):
+    o = _attention_pallas(q, k, v, causal, window, block_q, block_k,
+                          interpret)
+    return o, (q, k, v, o)
 
 
-def _attention_pallas_bwd(causal, window, block_q, block_k, res, dout):
-    q, k, v = res
+def _attention_pallas_bwd(causal, window, block_q, block_k, interpret,
+                          res, dout):
     with phase("attn_bwd"):
+        return attention_bwd(*res, dout, causal=causal, window=window,
+                             interpret=interpret)
+
+
+_attention_pallas.defvjp(_attention_pallas_fwd, _attention_pallas_bwd)
+
+
+def attention_bwd(q, k, v, o, dout, *, causal: bool,
+                  window: Optional[int], interpret: bool):
+    """(dq, dk, dv) of attention output ``o`` under cotangent ``dout``:
+    the flash backward kernel where ``flash_attention.bwd_blocks``
+    covers the shapes, else ``jax.vjp`` of the ``ref.py`` oracle.  Each
+    trace of either path bumps its ``perfcount.KERNELS`` counter."""
+    blocks = _fa.bwd_blocks(q.shape, k.shape, q.dtype)
+    if blocks is None:
+        KERNELS.attn_bwd_oracle += 1
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _ref.flash_attention_ref(
                 q_, k_, v_, causal=causal, window=window), q, k, v)
         return vjp(dout)
-
-
-_attention_pallas.defvjp(_attention_pallas_fwd, _attention_pallas_bwd)
+    KERNELS.attn_bwd_flash += 1
+    return _fa.flash_attention_bwd(q, k, v, o, dout, causal=causal,
+                                   window=window, block_q=blocks[0],
+                                   block_k=blocks[1], interpret=interpret)
 
 
 def _attention_xla(q, k, v, *, causal: bool, window: Optional[int]):
@@ -156,7 +177,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             bq = _pick_block(q.shape[1])
             bk = _pick_block(k.shape[1])
             if bq is not None and bk is not None:
-                return _attention_pallas(q, k, v, causal, window, bq, bk)
+                return _attention_pallas(q, k, v, causal, window, bq, bk,
+                                         not on_tpu())
         return _attention_xla(q, k, v, causal=causal, window=window)
 
 

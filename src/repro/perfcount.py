@@ -120,8 +120,26 @@ class TransportCounters(_CounterBase):
 TRANSPORT = TransportCounters()
 
 
+@dataclasses.dataclass
+class KernelPathCounters(_CounterBase):
+    """Which backward each traced attention step compiled: bumped once
+    per trace of ``registry.attention_bwd`` (not per execution), by the
+    path its shapes chose."""
+
+    #: the flash backward kernels (``flash_attention_bwd``)
+    attn_bwd_flash: int = 0
+    #: ``jax.vjp`` of the ``ref.py`` oracle (shapes the kernel does not
+    #: cover)
+    attn_bwd_oracle: int = 0
+
+
+#: Process-global kernel-path counters (see ``repro.kernels.registry``).
+KERNELS = KernelPathCounters()
+
+
 def snapshot_all() -> Dict[str, Dict[str, int]]:
     """One combined view of every process-global counter group —
     ``session.metrics()`` and the obs metrics snapshots both read this
     instead of enumerating the globals themselves."""
-    return {"wire": WIRE.snapshot(), "transport": TRANSPORT.snapshot()}
+    return {"wire": WIRE.snapshot(), "transport": TRANSPORT.snapshot(),
+            "kernels": KERNELS.snapshot()}

@@ -93,6 +93,62 @@ def test_flash_attention_compiles_at_chip_widths(one_chip):
         q, kv, kv)
 
 
+def _attention_bwd(batch, seq):
+    """The flash backward at chip widths and its argument shapes
+    (q, k, v, o, do), with the blocks it picks for them."""
+    hd = CHIP.resolved_head_dim
+    q = ((batch, seq, CHIP.n_heads, hd), jnp.bfloat16)
+    kv = ((batch, seq, CHIP.n_kv_heads, hd), jnp.bfloat16)
+    bq, bk = fa.bwd_blocks(q[0], kv[0], jnp.bfloat16)
+    return (functools.partial(fa.flash_attention_bwd, causal=True,
+                              window=CHIP.sliding_window, block_q=bq,
+                              block_k=bk), [q, kv, kv, q, q])
+
+
+def test_flash_attention_bwd_compiles_at_chip_widths(one_chip):
+    fn, shapes = _attention_bwd(BATCH, SEQ)
+    assert (fn.keywords["block_q"], fn.keywords["block_k"]) == (512, 512)
+    _compile(one_chip, fn, *shapes)
+
+
+def test_attention_grad_keeps_forward_reader_to_forward_calls(
+        one_chip, monkeypatch):
+    """``jax.grad`` of a remat'd attention call (the registry's custom_vjp
+    with interpret off) at chip widths: the benchmark's forward reader
+    matches exactly the two forward calls (primal and recompute) and
+    none of the backward's, whose outputs are tuples."""
+    import importlib.util
+    bench = os.path.join(os.path.dirname(__file__), os.pardir,
+                         "benchmarks")
+    monkeypatch.syspath_prepend(bench)
+    spec = importlib.util.spec_from_file_location(
+        "attn_fwd_roofline",
+        os.path.join(bench, "chip", "metrics", "attn_fwd_roofline.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    with open(os.path.join(bench, "chip", "configs",
+                           "danube-spmd.json")) as f:
+        pattern = reader.pattern(json.load(f), SEQ)
+
+    from repro.kernels.registry import _attention_pallas
+
+    def loss(q, k, v):
+        attn = jax.checkpoint(lambda *xs: _attention_pallas(
+            *xs, True, CHIP.sliding_window, 128, 128, False))
+        return jnp.sum(jnp.square(attn(q, k, v).astype(jnp.float32)))
+
+    _, shapes = _attention_bwd(1, SEQ)
+    text = _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    *shapes[:3])
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    matched = [ln for ln in calls if re.search(pattern, ln)]
+    assert len(matched) == 2
+    assert all("flash_attention_fwd" in ln for ln in matched)
+    assert len(calls) == 4
+    assert any("%flash_attention_bwd" in ln for ln in calls)
+    assert any("%flash_attention_stats" in ln for ln in calls)
+
+
 @pytest.mark.parametrize("rows", [BATCH * SEQ, SEQ - 1])  # 2047: % 8 != 0
 def test_rmsnorm_compiles_for_any_row_count(one_chip, rows):
     _compile(one_chip, rn.rmsnorm, ((rows, CHIP.d_model), jnp.bfloat16),
@@ -145,6 +201,8 @@ _NAMED = {
          ((1, 256, CHIP.n_kv_heads, CHIP.resolved_head_dim), jnp.bfloat16),
          ((1, 256, CHIP.n_kv_heads, CHIP.resolved_head_dim),
           jnp.bfloat16)]),
+    "flash_attention_bwd": _attention_bwd(1, 256),
+    "flash_attention_stats": _attention_bwd(1, 256),
     "rmsnorm": (rn.rmsnorm, [((64, CHIP.d_model), jnp.bfloat16),
                              ((CHIP.d_model,), jnp.bfloat16)]),
     "residual_rmsnorm": (rrn.residual_rmsnorm,
