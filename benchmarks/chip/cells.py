@@ -7,13 +7,20 @@ counters.  Set-up builds the one session, drives it from the seed
 through its first ``CHECK_STEPS`` steps with readings taken in between,
 and hands that same session to the measured window.  After the window
 the program is freed and the reference replays those first steps.
+
+The model is the configuration's family: ``families/<family>.py``,
+named by the file's ``family`` key (``dense`` where it names none),
+which gives the sizes, the weights, the float32 reference and the
+counts the readers divide by.
 """
 
 from __future__ import annotations
 
 import gc
+import importlib.util
 import threading
 import time
+from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -23,6 +30,7 @@ import numpy as np
 from chip import feed as feedlib
 from chip import reference as ref
 
+HERE = Path(__file__).resolve().parent
 #: Steps (rounds, on the parameter server: one push of every worker)
 #: the reference follows.
 CHECK_STEPS = 3
@@ -37,30 +45,50 @@ STALL_S = 600.0
 TINY_GRAD = 1e-3
 
 
-def model_sizes(cfg: Dict) -> Dict[str, Any]:
-    keys = ("hidden_size", "intermediate_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "num_hidden_layers",
-            "vocab_size", "sliding_window", "rope_theta", "rms_norm_eps")
-    return {k: cfg[k] for k in keys}
+#: The family of a configuration file that names none.
+DEFAULT_FAMILY = "dense"
+_FAMILIES: Dict[Path, Any] = {}
 
 
-def check_program_config(cfg: Dict) -> Any:
+def load_family(name: str, here: Path = HERE):
+    """The model family ``families/<name>.py`` under ``here``, loaded
+    once a process.  It gives ``sizes(cfg)`` (the file's sizes the cell
+    and the readers use; ``vocab_size`` among them, below which the feed
+    draws tokens), ``program_sizes(program_config)`` (those the
+    registry's ``ModelConfig`` states, by the same keys),
+    ``init_params(m, key, dtype, *, eager)`` (the weights in the
+    program's tree), ``loss_and_grad(m, params, tokens, labels, prec,
+    with_grad, half)`` (the float32 reference, ``prec='fp8'`` the
+    control, ``half`` the half-batch fault), ``param_count(m)``,
+    ``train_flops_per_token(m, seq)``, and ``SMOKE``: the registry
+    ``arch`` whose smoke configuration its ``config`` sizes are, with
+    ``param_count`` and ``train_flops_per_token`` ({seq: FLOPs}) worked
+    out by hand at them."""
+    path = (here / "families" / f"{name}.py").resolve()
+    if path not in _FAMILIES:
+        spec = importlib.util.spec_from_file_location(
+            f"chip_family_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
+
+
+def family_of(cfg: Dict, here: Path = HERE):
+    """The family the configuration file names."""
+    return load_family(cfg.get("family", DEFAULT_FAMILY), here)
+
+
+def check_program_config(cfg: Dict, family) -> Any:
     """The program's registry entry for the configuration, after
-    checking that it has the file's sizes."""
+    checking that it has the file's sizes (those the registry states)."""
     from repro.configs import get_config, get_smoke_config
     prog = cfg["program"]
     c = (get_smoke_config(prog["arch"]) if prog.get("smoke")
          else get_config(prog["arch"]))
-    m = model_sizes(cfg)
-    have = {"hidden_size": c.d_model, "intermediate_size": c.d_ff,
-            "num_attention_heads": c.n_heads,
-            "num_key_value_heads": c.n_kv_heads,
-            "head_dim": c.resolved_head_dim,
-            "num_hidden_layers": c.n_layers, "vocab_size": c.vocab_size,
-            "sliding_window": c.sliding_window,
-            "rope_theta": c.rope_theta, "torch_dtype": c.dtype}
-    want = dict(m, torch_dtype=cfg["torch_dtype"])
-    bad = {k: (have[k], want[k]) for k in have if have[k] != want[k]}
+    have = dict(family.program_sizes(c), torch_dtype=c.dtype)
+    want = dict(family.sizes(cfg), torch_dtype=cfg["torch_dtype"])
+    bad = {k: (have[k], want.get(k)) for k in have if have[k] != want.get(k)}
     if bad:
         raise ValueError(f"{prog['arch']} does not have the sizes of the "
                          f"configuration file: {bad} (program, file)")
@@ -123,11 +151,13 @@ class CompileCounter:
 
 
 class Cell:
-    """What both engines share: sizes, seeds, the window's counters."""
+    """What both engines share: the family and its sizes, seeds, the
+    window's counters."""
 
-    def __init__(self, cfg: Dict, traffic: Dict, seed: int):
+    def __init__(self, cfg: Dict, traffic: Dict, seed: int, family=None):
         self.cfg, self.traffic = cfg, traffic
-        self.m = model_sizes(cfg)
+        self.family = family or family_of(cfg)
+        self.m = self.family.sizes(cfg)
         self.seeds = feedlib.sub_seeds(seed)
         self.seq = int(traffic["seq_len"])
         self.batch = int(traffic["batch"])
@@ -173,7 +203,7 @@ class PSCell(Cell):
                               delta_pull=c["wire"]["delta_pull"]))
 
     def weights(self):
-        return ref.init_params(self.m, jax.random.PRNGKey(
+        return self.family.init_params(self.m, jax.random.PRNGKey(
             self.seeds["weights"]), self.store_dtype)
 
     # -- set-up --------------------------------------------------------
@@ -388,9 +418,8 @@ def ps_replay(cell: "PSCell", order_of: Callable, prec: str = "f32",
         for w in range(cell.workers):
             b = feedlib.tokens(cell.chain, k - 1,
                                feedlib.worker_rows(w, cell.batch))
-            loss, g = ref.loss_and_grad(cell.m, p, b["tokens"],
-                                        b["labels"], prec=prec,
-                                        half=half)
+            loss, g = cell.family.loss_and_grad(
+                cell.m, p, b["tokens"], b["labels"], prec=prec, half=half)
             losses[w].append(loss)
             grads.append([np.asarray(x) for x in
                           jax.tree_util.tree_leaves(g)])
@@ -461,8 +490,8 @@ class SpmdCell(Cell):
 
     def weights(self):
         # The trainer makes its own weights, from a fixed key, op by op.
-        return ref.init_params(self.m, jax.random.PRNGKey(0),
-                               self.store_dtype, eager=True)
+        return self.family.init_params(self.m, jax.random.PRNGKey(0),
+                                       self.store_dtype, eager=True)
 
     def counters(self) -> Dict[str, Any]:
         return {"t": time.monotonic(),
@@ -526,9 +555,9 @@ def spmd_replay(cell: "SpmdCell", delays: List[int], grad1_step: int,
         b = feedlib.tokens(cell.chain, t, rows)
         d = int(delays[t])
         need = t + 1 < CHECK_STEPS       # applied within the steps
-        loss, g = ref.loss_and_grad(cell.m, p, b["tokens"], b["labels"],
-                                    prec=prec, with_grad=need,
-                                    half=half)
+        loss, g = cell.family.loss_and_grad(
+            cell.m, p, b["tokens"], b["labels"], prec=prec,
+            with_grad=need, half=half)
         losses.append(loss)
         if need:
             ring[t % depth] = [np.asarray(x) for x in

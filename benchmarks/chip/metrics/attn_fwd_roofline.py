@@ -1,7 +1,8 @@
 """Share of its roofline the flash-attention forward kernel
 (``kernels/flash_attention.py``) reaches: the least time the chip could
 take for the calls in the window (FLOPs and bytes from shapes) over the
-summed device time of those calls.  Names its bound."""
+summed device time of those calls.  Names its bound.  A model without
+the sizes of that attention (``peaks.ATTN_KEYS``) gives nothing."""
 
 from chip import peaks, tracefold
 
@@ -16,6 +17,8 @@ def pattern(m, seq: int) -> str:
 
 
 def read(ctx):
+    if not peaks.has_attn(ctx["model"]):
+        return None
     n, secs = tracefold.matching(
         ctx["ops"], pattern(ctx["model"], int(ctx["traffic"]["seq_len"])))
     if n == 0 or secs <= 0:

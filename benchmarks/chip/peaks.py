@@ -1,5 +1,6 @@
 """Peak rates of the chips the benchmark runs on, and the operations and
-bytes each measured piece of work needs, computed from its shapes.
+bytes each measured kernel needs, computed from its shapes.  A model's
+parameter and FLOP counts are its family's (``families/``).
 
 Every roofline share and utilization the benchmark reports divides by
 these.  A device kind that is not in ``PEAKS`` is an error: a share of
@@ -37,31 +38,13 @@ def attended_pairs(seq: int, window: int | None) -> int:
     return w * (w + 1) // 2 + (seq - w) * w
 
 
-def matmul_params(m: Dict) -> int:
-    """Parameters that take part in a matmul per token: attention
-    projections, the SwiGLU MLP and the unembedding (the embedding is a
-    gather)."""
-    d, hq, hkv = m["hidden_size"], m["num_attention_heads"], \
-        m["num_key_value_heads"]
-    hd, f = m["head_dim"], m["intermediate_size"]
-    per_layer = d * hq * hd * 2 + d * hkv * hd * 2 + 3 * d * f
-    return m["num_hidden_layers"] * per_layer + m["vocab_size"] * d
+#: The sizes the attention costs read.  A family whose sizes lack one
+#: has attention of another shape, which these costs do not describe.
+ATTN_KEYS = ("num_attention_heads", "num_key_value_heads", "head_dim")
 
 
-def param_count(m: Dict) -> int:
-    """Every parameter: the matmul weights, the embedding, the norms."""
-    d, n = m["hidden_size"], m["num_hidden_layers"]
-    return matmul_params(m) + m["vocab_size"] * d + (2 * n + 1) * d
-
-
-def train_flops_per_token(m: Dict, seq: int) -> float:
-    """Model FLOPs per trained token, forward and backward (3x forward),
-    nothing recomputed counted, causal attention counted by the pairs it
-    attends."""
-    attn_fwd = 4.0 * m["num_attention_heads"] * m["head_dim"] * \
-        attended_pairs(seq, m.get("sliding_window")) / seq
-    return 3.0 * (2.0 * matmul_params(m)
-                  + m["num_hidden_layers"] * attn_fwd)
+def has_attn(m: Dict) -> bool:
+    return all(k in m for k in ATTN_KEYS)
 
 
 def attn_fwd_cost(batch: int, seq: int, m: Dict,

@@ -5,7 +5,8 @@
         --seed 7 --seconds 30 --trace 0
 
 One process loads the cell's configuration and traffic files (found by
-name under ``configs/`` and ``traffic/``), builds the program's session,
+name under ``configs/`` and ``traffic/``) and the model family the
+configuration names (``families/``), builds the program's session,
 warms it up and checks its first steps against the float32 reference's
 inputs, then measures for ``--seconds``.  ``--trace 0`` reports the
 cell's end-to-end metrics; ``--trace 1`` traces a window with the
@@ -190,15 +191,17 @@ def main(argv=None) -> int:
 
 
 def run_cell(bench, work, cfg, traffic, limits, args, devs,
-             require_tpu: bool = True):
-    """Set-up, window, reference; returns the result object."""
+             require_tpu: bool = True, here: Path = HERE):
+    """Set-up, window, reference; returns the result object.  The
+    family and the readers are found under ``here``."""
     from chip import cells, peaks
     n_chips = int(work["chips"])
     kind = devs[0].device_kind
     pk = peaks.peak(kind) if require_tpu else None
-    cells.check_program_config(cfg)
+    family = cells.family_of(cfg, here)
+    cells.check_program_config(cfg, family)
     engine = cells.ENGINES[cfg["program"]["engine"]]
-    cell = engine(cfg, traffic, args.seed)
+    cell = engine(cfg, traffic, args.seed, family)
     compiles = cells.CompileCounter()
     log(f"{work['name']}: {engine.engine} seed {args.seed} "
         f"sub-seeds {cell.seeds}")
@@ -232,14 +235,15 @@ def run_cell(bench, work, cfg, traffic, limits, args, devs,
                   for c in checks.values())
 
     if args.trace:
-        ctx.update(model=cell.m, traffic=traffic, config=cfg,
-                   engine=engine.engine, peak=pk, n_chips=n_chips,
-                   counters=nums, memory_peak_bytes=peak_bytes)
+        ctx.update(model=cell.m, family=family, traffic=traffic,
+                   config=cfg, engine=engine.engine, peak=pk,
+                   n_chips=n_chips, counters=nums,
+                   memory_peak_bytes=peak_bytes)
         metrics = {}
         for m in bench["per_layer"]:
             if not applies(m, work["name"]):
                 continue
-            got = load_reader(m["name"])(ctx)
+            got = load_reader(m["name"], here)(ctx)
             if got is None:
                 continue
             entry = got if isinstance(got, dict) else {"value": got}

@@ -1,13 +1,22 @@
 """The peaks table and the operation and byte counts, against counts
-worked out by hand."""
+worked out by hand: the kernels' here, each model family's from its
+``SMOKE`` sizes."""
 
+import jax
 import pytest
 
-from chip import peaks
+from chip import cells, peaks
+from chip.conftest import FAMILIES
 
-TINY = dict(hidden_size=8, intermediate_size=12, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=2, num_hidden_layers=3,
-            vocab_size=10, sliding_window=None)
+#: One causal attention of 4 query and 2 KV heads of width 2.
+ATTN = dict(num_attention_heads=4, num_key_value_heads=2, head_dim=2,
+            sliding_window=None)
+
+
+@pytest.fixture(params=FAMILIES)
+def family(request):
+    fam = cells.load_family(request.param)
+    return fam, fam.sizes(fam.SMOKE["config"])
 
 
 def test_unknown_device_kind_is_an_error():
@@ -25,25 +34,35 @@ def test_attended_pairs(seq, window, pairs):
     assert peaks.attended_pairs(seq, window) == pairs
 
 
-def test_matmul_and_all_params():
-    # per layer: q and o 8*4*2 each, k and v 8*2*2 each, MLP 3*8*12
-    per_layer = 64 + 64 + 32 + 32 + 288
-    assert peaks.matmul_params(TINY) == 3 * per_layer + 10 * 8
-    # plus the embedding and 2 norms a layer and the final one
-    assert peaks.param_count(TINY) == 3 * per_layer + 80 + 80 + 7 * 8
+def test_param_count(family):
+    fam, m = family
+    assert fam.param_count(m) == fam.SMOKE["param_count"]
 
 
-def test_train_flops_per_token():
-    seq = 4
-    matmul = 3 * 480 + 80
-    # QK and PV: 2 * 2 flops per pair per head dim, 10 pairs over 4 tokens
-    attn = 4 * 4 * 2 * 10 / 4
-    assert peaks.train_flops_per_token(TINY, seq) == pytest.approx(
-        3 * (2 * matmul + 3 * attn))
+def test_param_count_is_the_programs_tree(family):
+    """Every parameter the program makes at the smoke size (whose
+    vocabulary needs no padding rows) is counted once."""
+    from repro.configs import get_smoke_config
+    from repro.models import registry
+    fam, m = family
+    tree = jax.eval_shape(
+        lambda k: registry.init_params(
+            get_smoke_config(fam.SMOKE["arch"]), k),
+        jax.random.PRNGKey(0))
+    assert fam.param_count(m) == sum(
+        x.size for x in jax.tree_util.tree_leaves(tree))
+
+
+def test_train_flops_per_token(family):
+    fam, m = family
+    for seq, flops in fam.SMOKE["train_flops_per_token"].items():
+        assert fam.train_flops_per_token(m, seq) == pytest.approx(flops)
 
 
 def test_attn_fwd_and_apply_costs():
-    flops, nbytes = peaks.attn_fwd_cost(2, 4, TINY, 2)
+    assert peaks.has_attn(ATTN)
+    assert not peaks.has_attn({"num_attention_heads": 4})
+    flops, nbytes = peaks.attn_fwd_cost(2, 4, ATTN, 2)
     assert flops == 4 * 2 * 4 * 2 * 10
     # q and o: 4 heads, k and v: 2 heads; 2 rows of 4 tokens, 2 dims
     assert nbytes == 2 * 2 * 4 * 2 * (2 * 4 + 2 * 2)

@@ -139,7 +139,7 @@ def _danube():
     from chip import cells
     from chip.conftest import ROOT
     _, _, cfg, _, _ = run.load_cell(ROOT, "danube-ps.solo")
-    return cells.model_sizes(cfg)
+    return cells.family_of(cfg).sizes(cfg)
 
 
 def test_attn_bwd_roofline_by_hand():

@@ -1,7 +1,8 @@
 """The float32 reference against the program at smoke size on the CPU,
-so that it is shown right before it judges the chip: the model's loss
-and gradient (``registry.loss_fn`` with its XLA kernels), the server's
-apply (``kops.fused_update``), the trainer's AdamW and its weights."""
+so that it is shown right before it judges the chip: each model
+family's loss and gradient (``registry.loss_fn`` with its XLA kernels)
+and weights, the server's apply (``kops.fused_update``) and the
+trainer's AdamW."""
 
 import dataclasses
 
@@ -12,19 +13,23 @@ import pytest
 
 from chip import cells
 from chip import reference as ref
-from chip.conftest import SMOKE_MODEL
+from chip.conftest import FAMILIES
 
 
-@pytest.fixture(scope="module")
-def program_cfg():
+@pytest.fixture(scope="module", params=FAMILIES)
+def family(request):
+    """(family module, its smoke sizes, the program's smoke config)."""
     from repro.configs import get_smoke_config
-    return get_smoke_config("h2o-danube-1.8b-chip")
+    fam = cells.load_family(request.param)
+    return (fam, fam.sizes(fam.SMOKE["config"]),
+            get_smoke_config(fam.SMOKE["arch"]))
 
 
-def test_weights_are_the_programs(program_cfg):
+def test_weights_are_the_programs(family):
     from repro.models import registry
+    fam, m, program_cfg = family
     key = jax.random.PRNGKey(3)
-    mine = ref.init_params(SMOKE_MODEL, key, jnp.float32, eager=True)
+    mine = fam.init_params(m, key, jnp.float32, eager=True)
     theirs = registry.init_params(program_cfg, key)
     assert jax.tree_util.tree_structure(mine) == \
         jax.tree_util.tree_structure(theirs)
@@ -33,27 +38,28 @@ def test_weights_are_the_programs(program_cfg):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_loss_and_gradient_match_the_program(program_cfg):
+def test_loss_and_gradient_match_the_program(family):
     from repro.models import registry
-    params = ref.init_params(SMOKE_MODEL, jax.random.PRNGKey(0),
-                             jnp.float32)
+    fam, m, program_cfg = family
+    params = fam.init_params(m, jax.random.PRNGKey(0), jnp.float32)
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
-    # longer than the window (16): the sliding mask is exercised
-    toks = jax.random.randint(k1, (2, 40), 0, 256)
-    labs = jax.random.randint(k2, (2, 40), 0, 256)
+    # longer than a smoke window (16): a sliding mask is exercised
+    vocab = m["vocab_size"]
+    toks = jax.random.randint(k1, (2, 40), 0, vocab)
+    labs = jax.random.randint(k2, (2, 40), 0, vocab)
     lfn = registry.loss_fn(dataclasses.replace(program_cfg, kernels="xla"))
     with jax.default_matmul_precision("highest"):
         (want, _), g_want = jax.value_and_grad(lfn, has_aux=True)(
             params, {"tokens": toks, "labels": labs})
-    got, g_got = ref.loss_and_grad(SMOKE_MODEL, params, toks, labs)
+    got, g_got = fam.loss_and_grad(m, params, toks, labs)
     assert got == pytest.approx(float(want), rel=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(g_got),
                     jax.tree_util.tree_leaves(g_want)):
         scale = float(jnp.max(jnp.abs(b)))
         assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * scale
     # the control rounds every matmul operand to float8: visibly off
-    low, _ = ref.loss_and_grad(SMOKE_MODEL, params, toks, labs,
-                               prec="fp8", with_grad=False)
+    low, _ = fam.loss_and_grad(m, params, toks, labs, prec="fp8",
+                               with_grad=False)
     assert abs(low - got) > 1e-3
 
 

@@ -94,9 +94,10 @@ def test_kernel_readers_find_their_kernels(recorded, metric):
     from chip.conftest import ROOT
     _, work, cfg, traffic, _ = run.load_cell(ROOT, "danube-ps.solo")
     from chip import cells
+    family = cells.family_of(cfg)
     ctx = {"ops": ops, "engine": "ps-threads", "config": cfg,
-           "model": cells.model_sizes(cfg), "traffic": traffic,
-           "peak": peaks.peak("TPU v5 lite")}
+           "model": family.sizes(cfg), "family": family,
+           "traffic": traffic, "peak": peaks.peak("TPU v5 lite")}
     got = run.load_reader(metric)(ctx)
     assert got is not None, f"{metric} found no kernel in the trace"
     assert 0 < got["value"] <= 100 and got["bound"] in ("compute",
