@@ -656,7 +656,7 @@ class ThreadedPSSession(TrainingSession):
         def _packed_step(wire_p, wire_g_prev, batch):
             with phase("wire"):
                 p = plan.unpack(wire_p)
-            (loss, _), grads = jax.value_and_grad(
+            (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(p, batch)
             # Write the packed grads INTO the donated buffer: the
             # output aliases wire_g_prev's memory.  A plain `return
@@ -664,7 +664,10 @@ class ThreadedPSSession(TrainingSession):
             # keep_unused=False prunes unread args before donation.
             with phase("wire"):
                 wire_g = wire_g_prev.at[:].set(plan.pack(grads))
-            return wire_g, {"loss": loss}
+            aux = {"loss": loss}
+            if "counts" in metrics:  # the model's perfcount groups
+                aux["counts"] = metrics["counts"]
+            return wire_g, aux
 
         self.worker_step = _packed_step
 

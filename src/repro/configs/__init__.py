@@ -20,6 +20,8 @@ _ARCH_MODULES = (
     "whisper_tiny",
     "chameleon_34b",
     "jamba_v01_52b",
+    "moonlight_16b_a3b",
+    "moonlight_16b_a3b_chip",
 )
 
 # CLI ids use dashes (match the assignment listing)
@@ -35,6 +37,8 @@ _ALIASES = {
     "whisper-tiny": "whisper_tiny",
     "chameleon-34b": "chameleon_34b",
     "jamba-v0.1-52b": "jamba_v01_52b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "moonlight-16b-a3b-chip": "moonlight_16b_a3b_chip",
 }
 
 

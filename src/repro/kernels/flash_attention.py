@@ -8,6 +8,10 @@ and both matmuls hit the MXU with 128-aligned dims.  Online softmax
 (running max m, normalizer l, accumulator acc in VMEM scratch) carries
 across the innermost grid dimension, which TPU executes sequentially.
 
+q and k share one head width and v may have another (multi-head latent
+attention attends with q/k of 192 and v of 128): the output and its
+gradient take v's width.
+
 Grid: (batch, q_heads, lq/block_q, lk/block_k) — the kv-block axis is
 innermost; GQA maps q-head h to kv-head h // (hq // hkv) in the K/V
 index_map (no materialized head broadcast).  The wrapper moves heads
@@ -58,9 +62,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     """One (q-block, k-block) step of online-softmax attention.
 
     Refs (VMEM):
-      q_ref (block_q, d), k_ref/v_ref (block_k, d), o_ref (block_q, d)
-      m_ref/l_ref (block_q, 128) f32 scratch (lane-replicated),
-      acc_ref (block_q, d) f32 scratch
+      q_ref (block_q, d), k_ref (block_k, d), v_ref (block_k, dv),
+      o_ref (block_q, dv), m_ref/l_ref (block_q, 128) f32 scratch
+      (lane-replicated), acc_ref (block_q, dv) f32 scratch
     """
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -126,9 +130,9 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         window: Optional[int] = None,
                         block_q: int = 128, block_k: int = 128,
                         interpret: bool = False) -> jax.Array:
-    """q (b, lq, hq, d); k/v (b, lk, hkv, d) -> (b, lq, hq, d)."""
+    """q/k (b, lq|lk, hq|hkv, d); v (b, lk, hkv, dv) -> (b, lq, hq, dv)."""
     b, lq, hq, d = q.shape
-    lk, hkv = k.shape[1], k.shape[2]
+    lk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     if hq % hkv:
         raise ValueError("hq must be a multiple of hkv")
     group = hq // hkv
@@ -153,16 +157,16 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((None, None, block_k, d),
                          lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-            pl.BlockSpec((None, None, block_k, d),
+            pl.BlockSpec((None, None, block_k, dv),
                          lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, block_q, d),
+        out_specs=pl.BlockSpec((None, None, block_q, dv),
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, lq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, lq, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # normalizer l
-            pltpu.VMEM((block_q, d), jnp.float32),       # accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),      # accumulator
         ],
         interpret=interpret,
         name="flash_attention_fwd",
@@ -187,29 +191,34 @@ def _bwd_block(n: int) -> Optional[int]:
 
 
 def _bwd_vmem_bytes(block_q: int, block_k: int, lq: int, group: int,
-                    d: int, itemsize: int) -> int:
-    """VMEM of the fused backward: double-buffered blocks, the group's
-    (lq, d) dq slab (float32 accumulator and its output), and the
-    (block_k, block_q) float32 tiles in flight."""
+                    d: int, itemsize: int, dv: Optional[int] = None) -> int:
+    """VMEM of the fused backward: double-buffered blocks (q, k of width
+    d; v, dO of width dv), the group's (lq, d) dq slab (float32
+    accumulator and its output), and the (block_k, block_q) float32
+    tiles in flight."""
     dl = -(-d // _LANES) * _LANES
-    ins = 4 * (block_q + block_k) * dl * itemsize + 4 * 8 * block_q * 4
-    outs = 2 * (group * lq * dl * itemsize + 2 * block_k * dl * itemsize)
-    scratch = group * lq * dl * 4 + 2 * block_k * dl * 4
+    dvl = dl if dv is None else -(-dv // _LANES) * _LANES
+    ins = (2 * (block_q + block_k) * (dl + dvl) * itemsize
+           + 4 * 8 * block_q * 4)
+    outs = 2 * (group * lq * dl + block_k * (dl + dvl)) * itemsize
+    scratch = group * lq * dl * 4 + block_k * (dl + dvl) * 4
     return ins + outs + scratch + 6 * block_q * block_k * 4
 
 
-def bwd_blocks(q_shape, k_shape, dtype) -> Optional[Tuple[int, int]]:
-    """(block_q, block_k) of ``flash_attention_bwd`` for these shapes, or
-    None where it does not cover them: a length no backward block
-    tiles, or a group's (lq, d) dq slab past the VMEM budget."""
+def bwd_blocks(q_shape, k_shape, dtype,
+               dv: Optional[int] = None) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) of ``flash_attention_bwd`` for these shapes (v
+    ``dv`` wide, default q's width), or None where it does not cover
+    them: a length no backward block tiles, or a group's (lq, d) dq
+    slab past the VMEM budget."""
     _, lq, hq, d = q_shape
     lk, hkv = k_shape[1], k_shape[2]
     bq, bk = _bwd_block(lq), _bwd_block(lk)
     if bq is None or bk is None or hq % hkv:
         return None
     itemsize = jnp.dtype(dtype).itemsize
-    if _bwd_vmem_bytes(bq, bk, lq, hq // hkv, d,
-                       itemsize) > _BWD_VMEM_BUDGET:
+    if _bwd_vmem_bytes(bq, bk, lq, hq // hkv, d, itemsize,
+                       dv) > _BWD_VMEM_BUDGET:
         return None
     return bq, bk
 
@@ -371,7 +380,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Gradients (dq, dk, dv) of ``flash_attention_fwd``'s output ``o``
     under its cotangent ``do``; shapes and end-aligned positions as the
-    forward.  Blocks from ``bwd_blocks``.
+    forward (v, o and do ``dv`` wide).  Blocks from ``bwd_blocks``.
 
     Two kernels, neither with a single (b, hq, lq, d) output:
     ``flash_attention_stats`` (grid (b, hq, lq/block_q, lk/block_k))
@@ -382,7 +391,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     repeats its neighbour's block index.
     """
     b, lq, hq, d = q.shape
-    lk, hkv = k.shape[1], k.shape[2]
+    lk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     if lq % block_q or lk % block_k:
         raise ValueError(f"seq lens ({lq},{lk}) must divide blocks "
@@ -415,6 +424,8 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
 
     row = pl.BlockSpec((None, None, block_q, d),
                        lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    vrow = pl.BlockSpec((None, None, block_q, dv),
+                        lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     stat = pl.BlockSpec((None, None, 1, block_q),
                         lambda bi, hi, qi, ki: (bi, hi, 0, qi))
     stats_shape = jax.ShapeDtypeStruct((b, hq, 1, lq), jnp.float32)
@@ -422,7 +433,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         functools.partial(_stats_kernel, scale=scale, **geom),
         grid=(b, hq, nq, nk),
         in_specs=[row, pl.BlockSpec((None, None, block_k, d), stats_k),
-                  row, row],
+                  vrow, vrow],
         out_specs=[stat, stat],
         out_shape=[stats_shape, stats_shape],
         scratch_shapes=[pltpu.VMEM((1, block_q), jnp.float32),
@@ -444,24 +455,26 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         return (bi, hk, ki, 0)
 
     qspec = pl.BlockSpec((None, None, block_q, d), q_block)
-    kvspec = pl.BlockSpec((None, None, block_k, d), kv_block)
+    dospec = pl.BlockSpec((None, None, block_q, dv), q_block)
+    kspec = pl.BlockSpec((None, None, block_k, d), kv_block)
+    vspec = pl.BlockSpec((None, None, block_k, dv), kv_block)
     sspec = pl.BlockSpec((None, None, 1, block_q), q_stat)
     vmem = _bwd_vmem_bytes(block_q, block_k, lq, group, d,
-                           jnp.dtype(q.dtype).itemsize)
-    dq, dk, dv = pl.pallas_call(
+                           jnp.dtype(q.dtype).itemsize, dv)
+    dq, dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, **geom),
         grid=(b, hkv, nk, group, nq),
-        in_specs=[qspec, kvspec, kvspec, qspec, sspec, sspec],
+        in_specs=[qspec, kspec, vspec, dospec, sspec, sspec],
         out_specs=[
             pl.BlockSpec((None, group, lq, d),
                          lambda bi, hk, ki, gi, qi: (bi, hk, 0, 0)),
-            kvspec, kvspec],
+            kspec, vspec],
         out_shape=[jax.ShapeDtypeStruct((b, hq, lq, d), q.dtype),
                    jax.ShapeDtypeStruct((b, hkv, lk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, hkv, lk, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b, hkv, lk, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((group, lq, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary", "arbitrary"),
@@ -470,4 +483,4 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         interpret=interpret,
         name="flash_attention_bwd",
     )(qh, kh, vh, doh, lse, delta)
-    return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk, dv))
+    return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk, dv_))

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True,
                         window: Optional[int] = None) -> jax.Array:
-    """q (b, lq, hq, d); k/v (b, lk, hkv, d); GQA broadcast; f32 softmax.
+    """q (b, lq, hq, d); k (b, lk, hkv, d); v (b, lk, hkv, dv) ->
+    (b, lq, hq, dv); GQA broadcast; f32 softmax, scores over sqrt(d).
 
     Positions are aligned at the END: query i sits at absolute position
     lk - lq + i (standard for self-attention lq == lk and for decode

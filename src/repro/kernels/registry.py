@@ -120,7 +120,7 @@ def attention_bwd(q, k, v, o, dout, *, causal: bool,
     the flash backward kernel where ``flash_attention.bwd_blocks``
     covers the shapes, else ``jax.vjp`` of the ``ref.py`` oracle.  Each
     trace of either path bumps its ``perfcount.KERNELS`` counter."""
-    blocks = _fa.bwd_blocks(q.shape, k.shape, q.dtype)
+    blocks = _fa.bwd_blocks(q.shape, k.shape, q.dtype, v.shape[-1])
     if blocks is None:
         KERNELS.attn_bwd_oracle += 1
         _, vjp = jax.vjp(
@@ -163,8 +163,9 @@ def _attention_xla(q, k, v, *, causal: bool, window: Optional[int]):
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               kernels: str = AUTO) -> jax.Array:
-    """q (b, lq, hq, d); k/v (b, lk, hkv, d); GQA broadcast; positions
-    END-aligned (query i at absolute position lk - lq + i).
+    """q (b, lq, hq, d); k (b, lk, hkv, d); v (b, lk, hkv, dv) ->
+    (b, lq, hq, dv); GQA broadcast; scores scaled by 1/sqrt(d);
+    positions END-aligned (query i at absolute position lk - lq + i).
 
     PALLAS: the flash kernel (online softmax, (block_q, lk) working
     set); falls back to XLA when no ``_BLOCKS`` entry divides lq and

@@ -15,6 +15,20 @@ class MoEConfig:
     capacity_factor: float = 1.25
     every: int = 1            # MoE on every k-th layer (Jamba: 2)
     aux_loss_weight: float = 0.01
+    # "softmax": softmax scores, top-k renormalised, capacity dispatch
+    # (moe.moe_block).  "sigmoid_noaux": DeepSeek-V3's sigmoid scores,
+    # top-k picked on scores + a selection-only bias, no aux loss, and
+    # the dropless held-expert layer (moe.held_moe_block).
+    router: str = "softmax"
+    routed_scale: float = 1.0   # multiplies the routed experts' weights
+    # the routed experts this chip holds, [held_start, held_start + held)
+    # of n_experts (0 -> all); the router still scores all n_experts
+    held: int = 0
+    held_start: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,6 +42,14 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0                     # 0 -> d_model // n_heads
+    # multi-head latent attention (DeepSeek-V2/V3), on when
+    # kv_lora_rank > 0: k/v come from a kv_lora_rank-wide latent; q/k
+    # heads are qk_nope_head_dim + qk_rope_head_dim wide, v heads
+    # v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # attention
     qkv_bias: bool = False                # qwen1.5
     qk_norm: bool = False                 # chameleon
@@ -36,6 +58,7 @@ class ModelConfig:
     use_rope: bool = True                 # jamba/whisper: no rotary
     # MoE
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0                # leading dense layers (d_ff wide)
     # hybrid (jamba): attention on layers where i % attn_period == attn_offset
     attn_period: int = 0
     attn_offset: int = 0
@@ -49,6 +72,7 @@ class ModelConfig:
     n_encoder_layers: int = 0
     # norm / glue
     norm: str = "rmsnorm"                 # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6
     act: str = "silu"                     # silu (SwiGLU) | gelu
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -109,6 +133,22 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // max(1, self.n_kv_heads)
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a q/k head: the rope and no-rope parts under MLA."""
+        if self.mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.resolved_head_dim
+
+    @property
+    def n_scanned_layers(self) -> int:
+        """Layers of the uniform stack, after the leading dense ones."""
+        return self.n_layers - self.first_k_dense
+
     def is_attention_layer(self, i: int) -> bool:
         """Hybrid interleave (Jamba 1:7 -> attn_period=8)."""
         if self.attn_period <= 0:
@@ -116,8 +156,8 @@ class ModelConfig:
         return i % self.attn_period == self.attn_offset
 
     def is_moe_layer(self, i: int) -> bool:
-        return self.moe is not None and (i % self.moe.every
-                                         == self.moe.every - 1)
+        return (self.moe is not None and i >= self.first_k_dense
+                and (i % self.moe.every == self.moe.every - 1))
 
     def param_count(self) -> int:
         """Total parameters (embedding included), exact per family."""

@@ -47,7 +47,8 @@ def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
 
 def apply_norm(cfg: ModelConfig, x: jax.Array, w: Dict[str, jax.Array]) -> jax.Array:
     if cfg.norm == "rmsnorm":
-        return rms_norm(x, w["scale"], kernels=cfg.kernels)
+        return rms_norm(x, w["scale"], eps=cfg.rms_norm_eps,
+                        kernels=cfg.kernels)
     return layer_norm(x, w["scale"], w["bias"])
 
 
@@ -60,7 +61,8 @@ def residual_apply_norm(cfg: ModelConfig, delta: jax.Array, x: jax.Array,
     VMEM pass on the Pallas variant); layernorm keeps the unfused form.
     """
     if cfg.norm == "rmsnorm":
-        return K.residual_rmsnorm(delta, x, w["scale"], kernels=cfg.kernels)
+        return K.residual_rmsnorm(delta, x, w["scale"],
+                                  eps=cfg.rms_norm_eps, kernels=cfg.kernels)
     s = x + delta
     return s, layer_norm(s, w["scale"], w["bias"])
 
@@ -175,8 +177,9 @@ def attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
               ) -> jax.Array:
     """Grouped-query attention.
 
-    q (b, lq, hq, d); k/v (b, lk, hkv, d); mask (lq, lk) or (b, lq, lk).
-    Returns (b, lq, hq, d).  ``causal_structure`` = (causal, window)
+    q (b, lq, hq, d); k (b, lk, hkv, d); v (b, lk, hkv, dv) (dv = d
+    but for latent attention); mask (lq, lk) or (b, lq, lk).  Returns
+    (b, lq, hq, dv).  ``causal_structure`` = (causal, window)
     asserts that ``mask`` is exactly ``causal_window_mask(lq, lk,
     q_offset=lk-lq, window=window)`` — the structured form the kernel
     registry can dispatch on.
@@ -200,11 +203,11 @@ def attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         variant, which the sharded paths do not use).
     """
     b, lq, hq, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     g = hq // hkv
     if lq == 1:
         out = _attention_grouped(q.reshape(b, lq, hkv, g, d), k, v, mask)
-        return out.reshape(b, lq, hq, d)
+        return out.reshape(b, lq, hq, dv)
 
     if causal_structure is not None and not _has_mesh() and mask.ndim == 2:
         causal, window = causal_structure
@@ -218,7 +221,7 @@ def attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         qg = shard(q, "batch", "seq", None, None).reshape(b, lq, hkv, g, d)
         out = _attention_grouped(qg, k, v, mask,
                                  pin=("batch", None, None, "seq", None))
-        return shard(out.reshape(b, lq, hq, d), "batch", "seq", None, None)
+        return shard(out.reshape(b, lq, hq, dv), "batch", "seq", None, None)
 
     # TP mode: k/v gathered over l BEFORE the head broadcast -- otherwise
     # GSPMD hits "involuntary full rematerialization" resharding the
@@ -244,7 +247,7 @@ def attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         return (), _attention_heads(qc, k, v, mc)
 
     _, out = jax.lax.scan(body, (), (q_chunks, m_chunks))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, lq, hq, d)
+    return out.transpose(1, 0, 2, 3, 4).reshape(b, lq, hq, dv)
 
 
 def _sp_mode() -> bool:
@@ -308,8 +311,10 @@ def attention_block(cfg: ModelConfig, x: jax.Array, w: Dict[str, Any],
         k = k + w["bk"]
         v = v + w["bv"]
     if cfg.qk_norm:
-        q = rms_norm(q, w["q_norm"], kernels=cfg.kernels)
-        k = rms_norm(k, w["k_norm"], kernels=cfg.kernels)
+        q = rms_norm(q, w["q_norm"], eps=cfg.rms_norm_eps,
+                     kernels=cfg.kernels)
+        k = rms_norm(k, w["k_norm"], eps=cfg.rms_norm_eps,
+                     kernels=cfg.kernels)
     if cfg.use_rope:
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
@@ -347,22 +352,37 @@ def decode_attention_block(cfg: ModelConfig, x: jax.Array, w: Dict[str, Any],
     plus per-(token, head) scales ('k_scale'/'v_scale', (b, S, hkv)).
     """
     b = x.shape[0]
-    s_max = cache["k"].shape[1]
     q = jnp.einsum("bld,dhk->blhk", x, w["wq"])
     k = jnp.einsum("bld,dhk->blhk", x, w["wk"])
     v = jnp.einsum("bld,dhk->blhk", x, w["wv"])
     if cfg.qkv_bias:
         q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
     if cfg.qk_norm:
-        q = rms_norm(q, w["q_norm"], kernels=cfg.kernels)
-        k = rms_norm(k, w["k_norm"], kernels=cfg.kernels)
+        q = rms_norm(q, w["q_norm"], eps=cfg.rms_norm_eps,
+                     kernels=cfg.kernels)
+        k = rms_norm(k, w["k_norm"], eps=cfg.rms_norm_eps,
+                     kernels=cfg.kernels)
     if cfg.use_rope:
         pos = jnp.full((b, 1), index, jnp.int32)
         cos, sin = rotary_embedding(pos, cfg.resolved_head_dim,
                                     cfg.rope_theta)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
+    out, new_cache = cache_attention(cfg, q, k, v, cache, index)
+    out = jnp.einsum("blhk,hkd->bld", out, w["wo"])
+    out = shard(out, "batch", None, None)
+    return out, new_cache
 
+
+def cache_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array,
+                    v: jax.Array, cache: Dict[str, jax.Array],
+                    index: jax.Array,
+                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One decode token's attention over the KV cache after writing its
+    post-rotary k, v into slot ``index`` (mod the cache length): q (b,
+    1, hq, d), k (b, 1, hkv, d), v (b, 1, hkv, dv) -> ((b, 1, hq, dv),
+    the updated cache)."""
+    s_max = cache["k"].shape[1]
     slot = index % s_max                      # ring slot (SWA caches)
     quantized = "k_scale" in cache
     if quantized:
@@ -378,8 +398,8 @@ def decode_attention_block(cfg: ModelConfig, x: jax.Array, w: Dict[str, Any],
                      "v": shard(cv, "batch", "cache_seq", None, None),
                      "k_scale": shard(cks, "batch", "cache_seq", None),
                      "v_scale": shard(cvs, "batch", "cache_seq", None)}
-        ck = dequantize_kv(new_cache["k"], new_cache["k_scale"], x.dtype)
-        cv = dequantize_kv(new_cache["v"], new_cache["v_scale"], x.dtype)
+        ck = dequantize_kv(new_cache["k"], new_cache["k_scale"], q.dtype)
+        cv = dequantize_kv(new_cache["v"], new_cache["v_scale"], q.dtype)
     else:
         ck = jax.lax.dynamic_update_slice(
             cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
@@ -404,10 +424,7 @@ def decode_attention_block(cfg: ModelConfig, x: jax.Array, w: Dict[str, Any],
     # pin the head dim of the tiny (b, 1, hq, hd) activation so the wo
     # projection psums a ~200 KB partial instead of all-gathering the
     # full multi-GB wo weight (measured on mistral decode, §Perf it.11)
-    out = shard(out, "batch", None, "heads", None)
-    out = jnp.einsum("blhk,hkd->bld", out, w["wo"])
-    out = shard(out, "batch", None, None)
-    return out, new_cache
+    return shard(out, "batch", None, "heads", None), new_cache
 
 
 # ----------------------------------------------------------------- MLP

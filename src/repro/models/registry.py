@@ -155,7 +155,7 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
             n_moe_layers = (cfg.n_layers // period) * sum(
                 1 for j in range(period)
                 if j % m.every == m.every - 1)
-        inactive = n_moe_layers * (m.n_experts - m.top_k) * per_expert
+        inactive = n_moe_layers * (m.n_held - m.top_k) * per_expert
         total -= max(0, inactive)
     return total
 

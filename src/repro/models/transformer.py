@@ -1,6 +1,7 @@
-"""Dense decoder-only transformer (llama / qwen / mistral / chameleon
-families) with scan-over-layers, GQA(+SWA) attention, optional QKV bias
-and qk-norm, SwiGLU MLP, and an optional MoE FFN (see moe.py).
+"""Dense decoder-only transformer (llama / qwen / mistral / chameleon /
+deepseek-v3 families) with scan-over-layers, GQA(+SWA) or latent
+attention (mla.py), optional QKV bias and qk-norm, SwiGLU MLP, an
+optional MoE FFN (see moe.py) and optional leading dense layers.
 
 Three entry points per model:
   ``forward``        (b, l) tokens -> (b, l, v) logits        [train/prefill]
@@ -17,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as moe_lib
 from repro.models.config import ModelConfig
 from repro.models.params import ParamDef
@@ -78,23 +80,32 @@ def norm_defs(cfg: ModelConfig, n: int) -> Dict[str, ParamDef]:
     return defs
 
 
-def param_defs(cfg: ModelConfig) -> Dict[str, Any]:
-    n = cfg.n_layers
-    layer: Dict[str, Any] = {
-        "attn": attn_defs(cfg, n),
+def _block_defs(cfg: ModelConfig, n: int, moe: bool) -> Dict[str, Any]:
+    block: Dict[str, Any] = {
+        "attn": mla.mla_defs(cfg, n) if cfg.mla else attn_defs(cfg, n),
         "attn_norm": norm_defs(cfg, n),
         "mlp_norm": norm_defs(cfg, n),
     }
-    if cfg.moe is not None:
-        layer["moe"] = moe_lib.moe_defs(cfg, n)
+    if moe:
+        block["moe"] = moe_lib.moe_defs(cfg, n)
     else:
-        layer["mlp"] = mlp_defs(cfg, n)
+        block["mlp"] = mlp_defs(cfg, n)
+    return block
+
+
+def param_defs(cfg: ModelConfig) -> Dict[str, Any]:
+    """``layers`` stacks the uniform blocks; a config with leading dense
+    layers (``first_k_dense``) stacks those, with a ``d_ff`` MLP, under
+    ``dense_layers``."""
     defs: Dict[str, Any] = {
         "embed": ParamDef((cfg.padded_vocab, cfg.d_model), ("model", "fsdp"),
                           init="embed", fan_in_dims=(1,)),
         "final_norm": _unstack_norm(cfg),
-        "layers": layer,
+        "layers": _block_defs(cfg, cfg.n_scanned_layers,
+                              cfg.moe is not None),
     }
+    if cfg.first_k_dense:
+        defs["dense_layers"] = _block_defs(cfg, cfg.first_k_dense, False)
     if not cfg.tie_embeddings:
         defs["unembed"] = ParamDef((cfg.padded_vocab, cfg.d_model),
                                    ("model", "fsdp"), fan_in_dims=(1,))
@@ -110,64 +121,99 @@ def _unstack_norm(cfg: ModelConfig) -> Dict[str, ParamDef]:
 
 
 # --------------------------------------------------------------- layer body
+def _ffn(cfg: ModelConfig, h: jax.Array, w: Dict[str, Any],
+         ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The block's MLP or expert layer: (out, aux) where aux holds the
+    ``aux_loss`` and, for the held-expert layer, its counts."""
+    if "moe" not in w:
+        return L.mlp_block(cfg, h, w["mlp"]), {
+            "aux_loss": jnp.zeros((), jnp.float32)}
+    if cfg.moe.router == "sigmoid_noaux":
+        out, counts = moe_lib.held_moe_block(cfg, h, w["moe"])
+        return out, dict(counts, aux_loss=jnp.zeros((), jnp.float32))
+    out, aux = moe_lib.moe_block(cfg, h, w["moe"])
+    return out, {"aux_loss": aux}
+
+
 def _layer(cfg: ModelConfig, x: jax.Array, w: Dict[str, Any],
            cos: jax.Array, sin: jax.Array, mask: jax.Array,
            collect_kv: bool = False):
-    """Pre-norm residual block. Returns (x, aux_loss[, (k, v)])."""
+    """Pre-norm residual block. Returns (x, aux[, (k, v)]), aux as
+    ``_ffn`` gives it."""
     # pin the carry layout: without this GSPMD propagates whatever layout
     # the embed gather preferred into the scan carry and re-shards every
     # dot (measured: 671 MB activation all-gathers per layer, §Perf it.1)
     x = shard(x, "batch", "seq", None)
     h = L.apply_norm(cfg, x, w["attn_norm"])
-    att = L.attention_block(cfg, h, w["attn"], cos, sin, mask,
-                            collect_kv=collect_kv)
+    attend = mla.attention_block if cfg.mla else L.attention_block
+    att = attend(cfg, h, w["attn"], cos, sin, mask, collect_kv=collect_kv)
     kv = None
     if collect_kv:
         att, kv = att
     # fused residual-add + norm (registry residual_rmsnorm): one pass
     # produces the updated stream AND its normed view for the MLP
     x, h = L.residual_apply_norm(cfg, att, x, w["mlp_norm"])
-    if "moe" in w:
-        out, aux = moe_lib.moe_block(cfg, h, w["moe"])
-    else:
-        out, aux = L.mlp_block(cfg, h, w["mlp"]), jnp.zeros((), jnp.float32)
+    out, aux = _ffn(cfg, h, w)
     if collect_kv:
         return x + out, aux, kv
     return x + out, aux
 
 
+def _stacks(params: Dict[str, Any]):
+    """The stacked layer subtrees in the order they run."""
+    return [params[k] for k in ("dense_layers", "layers") if k in params]
+
+
+def _sum_aux(auxs) -> Dict[str, jax.Array]:
+    """The stacks' aux dicts summed key by key."""
+    out: Dict[str, jax.Array] = {}
+    for aux in auxs:
+        for k, v in aux.items():
+            out[k] = out[k] + v if k in out else v
+    return out
+
+
 def _scan_layers(cfg: ModelConfig, x: jax.Array, layer_params: Any,
-                 body) -> Tuple[jax.Array, jax.Array]:
-    """lax.scan over stacked layer params with optional remat."""
+                 body) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """lax.scan over stacked layer params with optional remat; the aux
+    summed over the layers."""
     if cfg.remat == "full":
         body = jax.checkpoint(body)
+    x, auxs = jax.lax.scan(body, x, layer_params, unroll=cfg.scan_unroll)
+    return x, jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), auxs)
 
-    def step(carry, w):
-        y, aux = body(carry, w)
-        return y, aux
 
-    x, auxs = jax.lax.scan(step, x, layer_params,
-                           unroll=cfg.scan_unroll)
-    return x, jnp.sum(auxs)
+def _rope(cfg: ModelConfig, l: int) -> Tuple[jax.Array, jax.Array]:
+    dim = cfg.qk_rope_head_dim if cfg.mla else cfg.resolved_head_dim
+    return L.rotary_embedding(jnp.arange(l), dim, cfg.rope_theta)
 
 
 # --------------------------------------------------------------- forward
+def hidden(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
+           ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """tokens (b, l) -> the final-normed hidden states (b, l, d) and the
+    layers' aux summed over them (``_ffn``)."""
+    l = tokens.shape[1]
+    x = L.embed(tokens, params["embed"]).astype(jnp.dtype(cfg.dtype))
+    cos, sin = _rope(cfg, l)
+    mask = L.causal_window_mask(l, l, window=cfg.sliding_window)
+    body = functools.partial(_layer, cfg, cos=cos, sin=sin, mask=mask)
+    auxs = []
+    for stack in _stacks(params):
+        x, aux = _scan_layers(cfg, x, stack, lambda c, w: body(c, w))
+        auxs.append(aux)
+    return L.apply_norm(cfg, x, params["final_norm"]), _sum_aux(auxs)
+
+
 def forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
             ) -> Tuple[jax.Array, jax.Array]:
-    """Training forward. tokens (b, l) -> logits (b, l, v), aux."""
-    b, l = tokens.shape
-    x = L.embed(tokens, params["embed"]).astype(jnp.dtype(cfg.dtype))
-    positions = jnp.arange(l)
-    cos, sin = L.rotary_embedding(positions, cfg.resolved_head_dim,
-                                  cfg.rope_theta)
-    mask = L.causal_window_mask(l, l, window=cfg.sliding_window)
+    """Training forward. tokens (b, l) -> logits (b, l, v), aux loss."""
+    x, aux = hidden(cfg, params, tokens)
+    return L.unembed(x, _table(cfg, params), cfg.vocab_size), aux["aux_loss"]
 
-    body = functools.partial(_layer, cfg, cos=cos, sin=sin, mask=mask)
-    x, aux = _scan_layers(cfg, x, params["layers"],
-                          lambda c, w: body(c, w))
-    x = L.apply_norm(cfg, x, params["final_norm"])
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return L.unembed(x, table, cfg.vocab_size), aux
+
+def _table(cfg: ModelConfig, params: Dict[str, Any]) -> jax.Array:
+    return params["embed"] if cfg.tie_embeddings else params["unembed"]
 
 
 def forward_prefill(cfg: ModelConfig, params: Dict[str, Any],
@@ -180,9 +226,7 @@ def forward_prefill(cfg: ModelConfig, params: Dict[str, Any],
     the decode cache layout (n_layers, b, l, hkv, hd)."""
     b, l = tokens.shape
     x = L.embed(tokens, params["embed"]).astype(jnp.dtype(cfg.dtype))
-    positions = jnp.arange(l)
-    cos, sin = L.rotary_embedding(positions, cfg.resolved_head_dim,
-                                  cfg.rope_theta)
+    cos, sin = _rope(cfg, l)
     mask = L.causal_window_mask(l, l, window=cfg.sliding_window)
 
     quantized = cfg.kv_cache_dtype == "int8"
@@ -199,11 +243,13 @@ def forward_prefill(cfg: ModelConfig, params: Dict[str, Any],
 
     if cfg.remat == "full":
         body = jax.checkpoint(body)
-    x, kv_out = jax.lax.scan(body, x, params["layers"],
-                             unroll=cfg.scan_unroll)
+    outs = []
+    for stack in _stacks(params):
+        x, kv = jax.lax.scan(body, x, stack, unroll=cfg.scan_unroll)
+        outs.append(kv)
+    kv_out = tuple(jnp.concatenate(parts) for parts in zip(*outs))
     x = L.apply_norm(cfg, x[:, -1:], params["final_norm"])
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = L.unembed(x, table, cfg.vocab_size)
+    logits = L.unembed(x, _table(cfg, params), cfg.vocab_size)
     if quantized:
         kq, ks_, vq, vs_ = kv_out
         return logits, {"k": kq, "k_scale": ks_, "v": vq, "v_scale": vs_}
@@ -213,74 +259,88 @@ def forward_prefill(cfg: ModelConfig, params: Dict[str, Any],
 
 def loss_fn(cfg: ModelConfig, params: Dict[str, Any],
             batch: Dict[str, jax.Array]) -> Tuple[jax.Array, Dict[str, Any]]:
-    logits, aux = forward(cfg, params, batch["tokens"])
-    nll = L.cross_entropy(logits, batch["labels"])
+    """(loss, metrics): the NLL plus the weighted aux loss; metrics hold
+    ``loss`` (the NLL), ``aux_loss`` and, for a held-expert model,
+    ``counts``: ``{"moe": its routing counts summed over the layers}``,
+    by ``repro.perfcount`` group."""
+    x, aux = hidden(cfg, params, batch["tokens"])
+    nll = L.cross_entropy(L.unembed(x, _table(cfg, params), cfg.vocab_size),
+                          batch["labels"])
     weight = cfg.moe.aux_loss_weight if cfg.moe else 0.0
-    return nll + weight * aux, {"loss": nll, "aux_loss": aux}
+    metrics: Dict[str, Any] = {"loss": nll, "aux_loss": aux["aux_loss"]}
+    counts = {k: v for k, v in aux.items() if k != "aux_loss"}
+    if counts:
+        metrics["counts"] = {"moe": counts}
+    return nll + weight * aux["aux_loss"], metrics
 
 
 # --------------------------------------------------------------- serving
+def _cache_shapes(cfg: ModelConfig, batch: int, max_seq: int):
+    """(k shape, v shape) of the stacked per-layer cache.  SWA models cap
+    the ring at the window; latent attention keeps k and v per head."""
+    if cfg.sliding_window is not None:
+        max_seq = min(max_seq, cfg.sliding_window)
+    lead = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads)
+    if cfg.mla:
+        return lead + (cfg.qk_head_dim,), lead + (cfg.v_head_dim,)
+    return (lead + (cfg.resolved_head_dim,),) * 2
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                dtype: Optional[str] = None) -> Dict[str, jax.Array]:
     """Stacked per-layer KV cache. SWA models cap the ring at the window;
     ``cfg.kv_cache_dtype == 'int8'`` stores quantized K/V with
     per-(token, head) f32 scales (layers.quantize_kv)."""
-    if cfg.sliding_window is not None:
-        max_seq = min(max_seq, cfg.sliding_window)
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads,
-             cfg.resolved_head_dim)
+    kshape, vshape = _cache_shapes(cfg, batch, max_seq)
     if cfg.kv_cache_dtype == "int8":
-        sshape = shape[:-1]
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(sshape, jnp.float32),
-                "v_scale": jnp.zeros(sshape, jnp.float32)}
+        return {"k": jnp.zeros(kshape, jnp.int8),
+                "v": jnp.zeros(vshape, jnp.int8),
+                "k_scale": jnp.zeros(kshape[:-1], jnp.float32),
+                "v_scale": jnp.zeros(vshape[:-1], jnp.float32)}
     dt = jnp.dtype(dtype or cfg.dtype)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    return {"k": jnp.zeros(kshape, dt), "v": jnp.zeros(vshape, dt)}
 
 
 def cache_specs(cfg: ModelConfig, batch: int, max_seq: int, rules,
                 dtype: Optional[str] = None) -> Dict[str, Any]:
     from jax.sharding import PartitionSpec as P
-    if cfg.sliding_window is not None:
-        max_seq = min(max_seq, cfg.sliding_window)
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads,
-             cfg.resolved_head_dim)
     axes = (None, "batch", "cache_seq", None, None)
-    spec = P() if rules is None else rules.spec(axes, shape)
-    out = {"k": spec, "v": spec}
-    if cfg.kv_cache_dtype == "int8":
-        sspec = (P() if rules is None
-                 else rules.spec(axes[:-1], shape[:-1]))
-        out["k_scale"] = sspec
-        out["v_scale"] = sspec
+    out = {}
+    for name, shape in zip(("k", "v"), _cache_shapes(cfg, batch, max_seq)):
+        out[name] = P() if rules is None else rules.spec(axes, shape)
+        if cfg.kv_cache_dtype == "int8":
+            out[f"{name}_scale"] = (P() if rules is None
+                                    else rules.spec(axes[:-1], shape[:-1]))
     return out
 
 
 def forward_decode(cfg: ModelConfig, params: Dict[str, Any],
                    token: jax.Array, cache: Dict[str, jax.Array],
                    index: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step. token (b, 1); cache leaves (n_layers, ...)."""
+    """One decode step. token (b, 1); cache leaves (n_layers, ...), the
+    leading dense layers first."""
     x = L.embed(token, params["embed"]).astype(jnp.dtype(cfg.dtype))
     keys = sorted(cache)  # k, k_scale?, v, v_scale?
+    attend = mla.decode_block if cfg.mla else L.decode_attention_block
 
     def body(carry, xs):
         w = xs[0]
         layer_cache = dict(zip(keys, xs[1:]))
         h = L.apply_norm(cfg, carry, w["attn_norm"])
-        att, new_cache = L.decode_attention_block(
-            cfg, h, w["attn"], layer_cache, index)
+        att, new_cache = attend(cfg, h, w["attn"], layer_cache, index)
         y = carry + att
         h = L.apply_norm(cfg, y, w["mlp_norm"])
-        if "moe" in w:
-            out, _ = moe_lib.moe_block(cfg, h, w["moe"])
-        else:
-            out = L.mlp_block(cfg, h, w["mlp"])
+        out, _ = _ffn(cfg, h, w)
         return y + out, tuple(new_cache[k] for k in keys)
 
-    xs = (params["layers"],) + tuple(cache[k] for k in keys)
-    x, new_leaves = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
+    start, outs = 0, []
+    for stack in _stacks(params):
+        n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        xs = (stack,) + tuple(cache[k][start:start + n] for k in keys)
+        x, new_leaves = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
+        outs.append(new_leaves)
+        start += n
+    new_cache = {k: jnp.concatenate(parts)
+                 for k, parts in zip(keys, zip(*outs))}
     x = L.apply_norm(cfg, x, params["final_norm"])
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return (L.unembed(x, table, cfg.vocab_size),
-            dict(zip(keys, new_leaves)))
+    return L.unembed(x, _table(cfg, params), cfg.vocab_size), new_cache

@@ -91,7 +91,13 @@ def module_op_paths(hlo_text: str, module: str) -> Dict[str, str]:
     """Instruction (by ``op_head``) -> op path, for one module's text.  An instruction XLA made without an ``op_name`` (a
     layout copy, a buffer update) takes the path of the first operand
     that has one, else of the first instruction reading it, else its
-    program's, ``jit(<function>)``, from the module name."""
+    program's, ``jit(<function>)``, from the module name.
+
+    A custom call XLA names after itself (the ``ragged-dot-none``
+    kernels it expands ``jax.lax.ragged_dot`` into, whose first operands
+    are routing metadata) takes the first path in a device phase among
+    the instructions reading it, then among its operands from the last
+    (a backward's incoming gradient) to the first."""
     root = f"jit({module[4:]})" if module.startswith("jit_") else module
     entries = []
     for line in hlo_text.splitlines():
@@ -100,24 +106,36 @@ def module_op_paths(hlo_text: str, module: str) -> Dict[str, str]:
             continue
         op = _OP_NAME.search(line)
         path = op.group(1) if op and op.group(1).startswith("jit(") else None
+        by_xla = bool(op) and path is None and " custom-call(" in line
         entries.append((op_head(line), m.group(1).split(" ", 1)[0],
-                        path, _operands(m.group(1))))
-    known = {name: path for _, name, path, _ in entries if path}
+                        path, _operands(m.group(1)), by_xla))
+    known = {name: path for _, name, path, _, _ in entries if path}
     users: Dict[str, List[str]] = {}
-    for _, name, path, operands in entries:
+    for _, name, path, operands, by_xla in entries:
         for o in operands:
             users.setdefault(o, []).append(name)
-        if name not in known:
+        if name not in known and not by_xla:
             got = next((known[o] for o in operands if o in known), None)
             if got:
                 known[name] = got
-    for _, name, _, _ in reversed(entries):
+    for _, name, _, operands, by_xla in entries:
+        if by_xla:
+            near = users.get(name, []) + operands[::-1]
+            got = next((known[n] for n in near
+                        if _in_phase(known.get(n))), None)
+            if got:
+                known[name] = got
+    for _, name, _, _, _ in reversed(entries):
         if name not in known:
             got = next((known[u] for u in users.get(name, ())
                         if u in known), None)
             if got:
                 known[name] = got
-    return {key: known.get(name, root) for key, name, _, _ in entries}
+    return {key: known.get(name, root) for key, name, _, _, _ in entries}
+
+
+def _in_phase(path) -> bool:
+    return bool(path) and any(w in PHASES for w in re.findall(r"\w+", path))
 
 
 def op_paths() -> Dict[str, str]:

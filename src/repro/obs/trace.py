@@ -102,6 +102,8 @@ PHASES = (
     "attn_bwd",   # the attention backward
     "optimizer",  # the SPMD step's optimizer update and delay ring
     "apply",      # the server's fused momentum apply
+    "moe_route",    # the expert router: scores, top-k, sort, row gathers
+    "moe_experts",  # the held experts' grouped matmuls, fwd and bwd
 )
 
 #: Jitted programs that are one phase as a whole, by function name: an

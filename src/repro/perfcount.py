@@ -36,7 +36,7 @@ approximate).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Any, Dict, Mapping
 
 
 @dataclasses.dataclass
@@ -53,6 +53,12 @@ class _CounterBase:
 
     def delta(self, before: Dict[str, int]) -> Dict[str, int]:
         return {k: v - before.get(k, 0) for k, v in self.snapshot().items()}
+
+    def add(self, counts: Mapping[str, Any]) -> None:
+        """Bump each field by ``counts[field]`` (int-like)."""
+        for f in dataclasses.fields(self):
+            setattr(self, f.name,
+                    getattr(self, f.name) + int(counts[f.name]))
 
 
 @dataclasses.dataclass
@@ -137,9 +143,37 @@ class KernelPathCounters(_CounterBase):
 KERNELS = KernelPathCounters()
 
 
+@dataclasses.dataclass
+class MoECounters(_CounterBase):
+    """What the held-expert layers routed, summed over layers and steps:
+    the model's ``counts["moe"]``, which a worker step returns with its
+    loss (``add_counts``), so counting costs no device sync of its own."""
+
+    #: assignments the router sent to experts this chip holds
+    held_rows: int = 0
+    #: tokens the routers saw (one per token and expert layer)
+    tokens_routed: int = 0
+    #: the busiest held expert's rows, summed over layer-steps
+    held_rows_max: int = 0
+
+
+#: Process-global expert-routing counters (see ``add_counts``).
+MOE = MoECounters()
+
+#: Every process-global counter group by name.
+GROUPS: Dict[str, _CounterBase] = {"wire": WIRE, "transport": TRANSPORT,
+                                   "kernels": KERNELS, "moe": MOE}
+
+
+def add_counts(counts: Mapping[str, Mapping[str, Any]]) -> None:
+    """Bump the groups a model step counted, ``{group: {field: n}}`` on
+    the host (a training step's ``counts`` aux)."""
+    for name, group in counts.items():
+        GROUPS[name].add(group)
+
+
 def snapshot_all() -> Dict[str, Dict[str, int]]:
     """One combined view of every process-global counter group —
     ``session.metrics()`` and the obs metrics snapshots both read this
     instead of enumerating the globals themselves."""
-    return {"wire": WIRE.snapshot(), "transport": TRANSPORT.snapshot(),
-            "kernels": KERNELS.snapshot()}
+    return {name: c.snapshot() for name, c in GROUPS.items()}

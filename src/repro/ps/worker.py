@@ -35,7 +35,7 @@ from typing import Any, Callable, Iterator, Optional
 from repro._compat import warn_legacy
 from repro.api.protocol import ParameterServerProtocol
 from repro.obs.trace import TRACE
-from repro.perfcount import WIRE
+from repro.perfcount import WIRE, add_counts
 
 StepFn = Callable[[Any, Any], Any]  # (params, batch) -> (grads, aux)
 
@@ -188,6 +188,10 @@ class PSWorker(threading.Thread):
                         if TRACE.enabled:
                             TRACE.span("straggler_sleep", t_sleep,
                                        worker=self.worker_id, clock=it)
+                    if isinstance(aux, dict) and "counts" in aux:
+                        # one transfer brings the loss and the counts
+                        aux = _host(aux)
+                        add_counts(aux["counts"])
                     if self.loss_from_aux is not None:
                         self.server.record_loss(it,
                                                 self.loss_from_aux(aux))
@@ -217,6 +221,12 @@ class PSWorker(threading.Thread):
 def _block(tree: Any) -> Any:
     import jax
     return jax.block_until_ready(tree)
+
+
+def _host(tree: Any) -> Any:
+    """The step's small outputs on the host, in one transfer."""
+    import jax
+    return jax.device_get(tree)
 
 
 def _platform(tree: Any) -> str:

@@ -112,6 +112,8 @@ def test_full_config_param_count(arch):
         "whisper-tiny": (0.02e9, 0.08e9),
         "chameleon-34b": (30e9, 39e9),
         "jamba-v0.1-52b": (45e9, 60e9),
+        "moonlight-16b-a3b": (15.9e9, 16.0e9),
+        "moonlight-16b-a3b-chip": (0.568e9, 0.569e9),
     }[arch]
     assert expected[0] <= n <= expected[1], f"{arch}: {n/1e9:.2f}B params"
 

@@ -96,6 +96,48 @@ def test_flash_attention_bwd_matches_oracle_vjp(dtype, window, group, lq,
     _assert_close(got, vjp(do), dtype, bwd=True)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("group", [1, 2])
+def test_flash_attention_with_v_narrower_than_qk(dtype, group):
+    """Latent attention's shapes (q/k 96 wide, v 64, scores over
+    sqrt(96)): the forward, and the stats + backward kernels, against
+    the oracle and its vjp.  Tolerances as the equal-width tests."""
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (1, 128, 2 * group, 96)).astype(dtype)
+    k = jax.random.normal(ks[1], (1, 128, 2, 96)).astype(dtype)
+    v = jax.random.normal(ks[2], (1, 128, 2, 64)).astype(dtype)
+    do = jax.random.normal(ks[3], (1, 128, 2 * group, 64)).astype(dtype)
+    out = flash_attention_fwd(q, k, v, causal=True, block_q=64,
+                              block_k=64, interpret=True)
+    o, vjp = jax.vjp(lambda *xs: ref.flash_attention_ref(*xs, causal=True),
+                     q, k, v)
+    assert out.shape == o.shape == (1, 128, 2 * group, 64)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(o, np.float32), atol=tol, rtol=tol)
+    got = flash_attention_bwd(q, k, v, o, do, causal=True, block_q=64,
+                              block_k=64, interpret=True)
+    assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+    _assert_close(got, vjp(do), dtype, bwd=True)
+
+
+def test_registry_attention_grads_with_v_narrower_than_qk():
+    """Through the registry's custom_vjp (the flash backward, as the
+    counters show), gradients at v narrower than q/k match the oracle's."""
+    from repro.perfcount import KERNELS
+    ks = jax.random.split(jax.random.PRNGKey(10), 3)
+    q = jax.random.normal(ks[0], (1, 128, 4, 48))
+    k = jax.random.normal(ks[1], (1, 128, 4, 48))
+    v = jax.random.normal(ks[2], (1, 128, 4, 32))
+    before = KERNELS.snapshot()
+    g = jax.grad(lambda *xs: _sq(registry.attention(
+        *xs, kernels="pallas")), argnums=(0, 1, 2))(q, k, v)
+    assert KERNELS.delta(before)["attn_bwd_flash"] == 1
+    gw = jax.grad(lambda *xs: _sq(ref.flash_attention_ref(*xs)),
+                  argnums=(0, 1, 2))(q, k, v)
+    _assert_close(g, gw, jnp.float32, bwd=True)
+
+
 def test_attention_bwd_path_follows_the_shapes():
     """The backward takes the flash kernel where its blocks tile the
     sequence and the oracle's vjp where they do not (lq = 100), as the

@@ -382,7 +382,7 @@ def test_traced_run_collects_all_workers(transport, tmp_path):
     assert obs["dssp"]["threshold_extensions"] == m["credit_releases"]
     # session metrics carry the satellite enrichments
     assert "wait_fraction" in m and "perfcount" in m
-    assert set(m["perfcount"]) == {"wire", "transport", "kernels"}
+    assert set(m["perfcount"]) == {"wire", "transport", "kernels", "moe"}
 
     # the exported file is valid Chrome JSON and summarizes identically
     with open(trace_path) as f:
@@ -549,7 +549,7 @@ def test_perfcount_snapshot_all_and_base_class():
     WIRE.reset()
     TRANSPORT.reset()
     snap = snapshot_all()
-    assert set(snap) == {"wire", "transport", "kernels"}
+    assert set(snap) == {"wire", "transport", "kernels", "moe"}
     assert snap["wire"]["pallas_calls"] == 0
     WIRE.pallas_calls += 3
     TRANSPORT.frames_tx += 2

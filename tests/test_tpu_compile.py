@@ -36,6 +36,7 @@ from repro.wireformat import WIRE_LANES, WIRE_ROWS
 
 CHIP = get_config("h2o-danube-1.8b-chip")
 JAMBA = get_config("jamba-v0.1-52b")
+MOON = get_config("moonlight-16b-a3b-chip")
 SEQ = 2048
 BATCH = 4
 SHARDS = 2   # the larger shard region of a 2-4 shard server
@@ -147,6 +148,50 @@ def test_attention_grad_keeps_forward_reader_to_forward_calls(
     assert len(calls) == 4
     assert any("%flash_attention_bwd" in ln for ln in calls)
     assert any("%flash_attention_stats" in ln for ln in calls)
+
+
+def _grids(fn, *shapes):
+    """(grid, block shapes) of each pallas_call ``fn`` makes."""
+    jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(s, d)
+                                 for s, d in shapes])
+    return [(tuple(e.params["grid_mapping"].grid),
+             [tuple(getattr(b, "block_size", None) for b in m.block_shape)
+              for m in e.params["grid_mapping"].block_mappings])
+            for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+
+def test_flash_attention_grids_at_equal_widths_are_unchanged():
+    """With v as wide as q/k (the danube cells), the kernels keep the
+    grids and blocks they had before they took a narrower v: the forward
+    (b, hq, lq/128, lk/128) of 128-row blocks; the stats pass (b, hq,
+    4, 4) and the backward (b, hkv, 4, group, 4) of 512-row blocks."""
+    hd = CHIP.resolved_head_dim
+    fwd, bwd = _attention_bwd(BATCH, SEQ)
+    row = (None, None, 128, hd)
+    assert _grids(functools.partial(
+        fa.flash_attention_fwd, causal=True, window=CHIP.sliding_window),
+        *bwd[:3]) == [((BATCH, 32, 16, 16), [row] * 4)]
+    blk, stat = (None, None, 512, hd), (None, None, 1, 512)
+    assert _grids(fwd, *bwd) == [
+        ((BATCH, 32, 4, 4), [blk] * 4 + [stat] * 2),
+        ((BATCH, 8, 4, 4, 4), [blk] * 4 + [stat] * 2
+         + [(None, 4, SEQ, hd), blk, blk])]
+
+
+def test_flash_attention_compiles_at_latent_attention_widths(one_chip):
+    """Moonlight's attention as the chip runs it: 16 heads, q/k 192 wide
+    (128 + 64 rope), v 128, 2 x 8192 tokens, the forward and the
+    backward (whose 512-row blocks fit the VMEM budget)."""
+    dqk = MOON.qk_head_dim
+    q = ((2, 8192, MOON.n_heads, dqk), jnp.bfloat16)
+    v = ((2, 8192, MOON.n_heads, MOON.v_head_dim), jnp.bfloat16)
+    _compile(one_chip, functools.partial(fa.flash_attention_fwd,
+                                         causal=True), q, q, v)
+    blocks = fa.bwd_blocks(q[0], q[0], jnp.bfloat16, MOON.v_head_dim)
+    assert blocks == (512, 512)
+    _compile(one_chip, functools.partial(
+        fa.flash_attention_bwd, causal=True, block_q=512, block_k=512),
+        q, q, v, v, v)
 
 
 @pytest.mark.parametrize("rows", [BATCH * SEQ, SEQ - 1])  # 2047: % 8 != 0
