@@ -8,6 +8,15 @@ and both matmuls hit the MXU with 128-aligned dims.  Online softmax
 (running max m, normalizer l, accumulator acc in VMEM scratch) carries
 across the innermost grid dimension, which TPU executes sequentially.
 
+Forward (``flash_attention_fwd``): blocks up to 1024 come from the
+shapes and a VMEM budget (``fwd_blocks``; every length that is a
+multiple of 8 has one).  The K/V index maps are clamped to the k blocks
+a q block attends (``_k_range``), so a fully masked block costs a grid
+step but no copy; blocks partly masked build the mask, whole ones do
+not.  QK^T takes q and k in the inputs' dtype and PV takes P cast to
+v's dtype, both with float32 accumulation; m, l, acc and the final
+``acc / l`` are float32.
+
 q and k share one head width and v may have another (multi-head latent
 attention attends with q/k of 192 and v of 128): the output and its
 gradient take v's width.
@@ -55,19 +64,138 @@ NEG_INF = -1e30
 _LANES = 128    # lane width of the m / l scratch (values replicated)
 
 
+#: VMEM the kernels may plan for (v5e and v6e hold 128 MiB a core).
+_VMEM_BUDGET = 64 * 1024 * 1024
+
+
+def _lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-wide lane tiles."""
+    return -(-n // _LANES) * _LANES
+
+
+def _vmem_limit(nbytes: int) -> int:
+    """The scoped VMEM a kernel asks for: its plan and half again, at
+    least 32 MiB, at most the budget."""
+    return min(max(32 * 1024 * 1024, nbytes + nbytes // 2), _VMEM_BUDGET)
+
+
+def _spans(qi, ki, *, block_q, block_k, offset, causal, window):
+    """(needed, masked) of one (q-block, k-block) pair: whether any of
+    its pairs is attended, and whether some pair in it is not."""
+    q_first = qi * block_q + offset
+    q_last = q_first + block_q - 1
+    k_first = ki * block_k
+    k_last = k_first + block_k - 1
+    needed, whole = True, True
+    if causal:
+        needed = k_first <= q_last
+        whole = k_last <= q_first
+    if window is not None:
+        needed = jnp.logical_and(needed, k_last > q_first - window)
+        whole = jnp.logical_and(whole, k_first > q_last - window)
+    return needed, jnp.logical_not(whole)
+
+
+def _mask(s, qi, ki, *, k_axis, block_q, block_k, offset, causal, window):
+    """``s`` with the pairs not attended set to NEG_INF, and the mask;
+    k runs along ``k_axis`` of the tile (1 in the forward's (block_q,
+    block_k) tiles, 0 in the backward's transposed ones)."""
+    shape = s.shape
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    k_axis)
+    q_pos = qi * block_q + offset + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1 - k_axis)
+    mask = jnp.ones(shape, jnp.bool_)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= k_pos > q_pos - window
+    return jnp.where(mask, s, NEG_INF), mask
+
+
+def _dot_nt(a, b):
+    """a (m, d) . b (n, d)^T -> (m, n) float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _clamp(i, lo, hi):
+    return jnp.minimum(jnp.maximum(i, lo), hi)
+
+
+def _k_range(qi, *, block_q, block_k, offset, causal, window, nk):
+    """First and last k block that q block ``qi`` attends (queries
+    end-aligned: row i at position ``offset + i``).  An index map
+    clamped to this range repeats its neighbour's block index on the
+    steps it skips, so those steps copy nothing."""
+    lo = (jnp.maximum(qi * block_q + offset - window + 1, 0) // block_k
+          if window is not None else 0)
+    hi = ((qi * block_q + block_q - 1 + offset) // block_k
+          if causal else nk - 1)
+    return lo, jnp.minimum(jnp.maximum(hi, lo), nk - 1)
+
+
+def _k_index(group: int, nk: int, geom):
+    """Index map of a K or V block on a (b, hq, nq, nk) grid: query
+    head ``hi`` reads KV head ``hi // group``, k block ``ki`` clamped to
+    the range q block ``qi`` attends."""
+    def index(bi, hi, qi, ki):
+        return (bi, hi // group, _clamp(ki, *_k_range(qi, nk=nk, **geom)), 0)
+    return index
+
+
+# ================================================================= forward
+#: forward block candidates, largest first: every multiple of 8 has one.
+#: On a v5e, 1024 x 1024 blocks ran the danube and Moonlight forwards
+#: 1.7x faster than 512 x 512: fewer grid steps, each a fixed cost.
+_FWD_BLOCKS = (1024, 512, 256, 128, 64, 32, 16, 8)
+
+
+def _fwd_vmem_bytes(block_q: int, block_k: int, d: int, dv: int,
+                    itemsize: int) -> int:
+    """VMEM of the forward: double-buffered q, k (width d), v and o
+    (width dv) blocks, the m / l / acc scratch, and the (block_q,
+    block_k) float32 score, probability and position tiles in flight."""
+    dl, dvl = _lanes(d), _lanes(dv)
+    blocks = 2 * ((block_q + block_k) * dl + (block_k + block_q) * dvl)
+    scratch = block_q * (2 * _LANES + dvl) * 4
+    return blocks * itemsize + scratch + 5 * block_q * block_k * 4
+
+
+def fwd_blocks(q_shape, k_shape, dtype,
+               dv: Optional[int] = None) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) of ``flash_attention_fwd`` for these shapes (v
+    ``dv`` wide, default q's width): the largest candidates that tile
+    the lengths and fit the VMEM budget, or None where no candidate
+    tiles a length (one that is not a multiple of 8)."""
+    lq, d = q_shape[1], q_shape[3]
+    lk = k_shape[1]
+    dv = d if dv is None else dv
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = [(bq, bk)
+            for bq in _FWD_BLOCKS if lq % bq == 0
+            for bk in _FWD_BLOCKS if lk % bk == 0
+            if _fwd_vmem_bytes(bq, bk, d, dv, itemsize) <= _VMEM_BUDGET]
+    return fits[0] if fits else None
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale: float, block_q: int, block_k: int,
-                  causal: bool, window: Optional[int],
-                  lq: int, lk: int):
+                  scale: float, block_q: int, block_k: int, offset: int,
+                  causal: bool, window: Optional[int]):
     """One (q-block, k-block) step of online-softmax attention.
 
     Refs (VMEM):
       q_ref (block_q, d), k_ref (block_k, d), v_ref (block_k, dv),
       o_ref (block_q, dv), m_ref/l_ref (block_q, 128) f32 scratch
       (lane-replicated), acc_ref (block_q, dv) f32 scratch
+
+    Blocks with no attended pair do nothing (their K/V copy is clamped
+    away); only blocks with a pair not attended build the mask.
     """
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    geom = dict(block_q=block_q, block_k=block_k, offset=offset,
+                causal=causal, window=window)
 
     @pl.when(ki == 0)
     def _init():
@@ -75,49 +203,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # absolute positions (queries aligned at the end: pos = lk - lq + i)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0) + (lk - lq)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-
-    # skip fully-masked blocks (causal: k entirely in the future;
-    # window: k entirely before the window)
-    block_needed = True
-    if causal:
-        block_needed = (ki * block_k) <= (qi * block_q + block_q - 1
-                                          + (lk - lq))
-    if window is not None:
-        first_valid = qi * block_q + (lk - lq) - window + 1
-        block_needed = jnp.logical_and(
-            block_needed, (ki * block_k + block_k - 1) >= first_valid)
-
-    @pl.when(block_needed)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        mask = jnp.ones_like(s, dtype=jnp.bool_)
-        if causal:
-            mask &= k_pos <= q_pos
-        if window is not None:
-            mask &= k_pos > q_pos - window
-        s = jnp.where(mask, s, NEG_INF)
-
+    def update(masked: bool):
+        v = v_ref[...]
+        s = _dot_nt(q_ref[...], k_ref[...]) * scale             # (bq, bk)
+        if masked:
+            s, _ = _mask(s, qi, ki, k_axis=1, **geom)
         m_prev = m_ref[:, :1]                                    # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        v = v_ref[...].astype(jnp.float32)
-        acc_ref[...] = (acc_ref[...] * alpha
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    needed, masked = _spans(qi, ki, **geom)
+    pl.when(jnp.logical_and(needed, masked))(lambda: update(True))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(masked)))(
+        lambda: update(False))
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -130,7 +234,15 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         window: Optional[int] = None,
                         block_q: int = 128, block_k: int = 128,
                         interpret: bool = False) -> jax.Array:
-    """q/k (b, lq|lk, hq|hkv, d); v (b, lk, hkv, dv) -> (b, lq, hq, dv)."""
+    """q/k (b, lq|lk, hq|hkv, d); v (b, lk, hkv, dv) -> (b, lq, hq, dv).
+    The registry takes its blocks from ``fwd_blocks``.
+
+    Grid (b, hq, lq/block_q, lk/block_k); the K and V index maps are
+    clamped to the k blocks q block ``qi`` attends (``_k_range``), so a
+    step that skips a masked block copies nothing.  The init and
+    finalize steps stay on the grid's ends: the clamp removes copies,
+    not steps.
+    """
     b, lq, hq, d = q.shape
     lk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     if hq % hkv:
@@ -141,33 +253,39 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if lq % block_q or lk % block_k:
         raise ValueError(f"seq lens ({lq},{lk}) must divide blocks "
                          f"({block_q},{block_k})")
-    grid = (b, hq, lq // block_q, lk // block_k)
+    nk = lk // block_k
+    geom = dict(block_q=block_q, block_k=block_k, offset=lk - lq,
+                causal=causal, window=window)
+    kernel = functools.partial(_flash_kernel, scale=1.0 / math.sqrt(d),
+                               **geom)
 
-    kernel = functools.partial(
-        _flash_kernel, scale=1.0 / math.sqrt(d), block_q=block_q,
-        block_k=block_k, causal=causal, window=window, lq=lq, lk=lk)
+    def row(bi, hi, qi, ki):
+        return (bi, hi, qi, 0)
 
+    kv = _k_index(group, nk, geom)
     # heads ahead of the sequence: blocks tile (seq block, head_dim)
     qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    vmem = _fwd_vmem_bytes(block_q, block_k, d, dv,
+                           jnp.dtype(q.dtype).itemsize)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, hq, lq // block_q, nk),
         in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
-            pl.BlockSpec((None, None, block_k, dv),
-                         lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+            pl.BlockSpec((None, None, block_q, d), row),
+            pl.BlockSpec((None, None, block_k, d), kv),
+            pl.BlockSpec((None, None, block_k, dv), kv),
         ],
-        out_specs=pl.BlockSpec((None, None, block_q, dv),
-                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_specs=pl.BlockSpec((None, None, block_q, dv), row),
         out_shape=jax.ShapeDtypeStruct((b, hq, lq, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # normalizer l
             pltpu.VMEM((block_q, dv), jnp.float32),      # accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
         name="flash_attention_fwd",
     )(qh, kh, vh)
@@ -179,8 +297,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
 #: axis of the backward's (block_k, block_q) tiles and row statistics,
 #: so it is a multiple of 128 or the whole of lq (likewise block_k).
 _BWD_BLOCKS = (512, 256, 128)
-#: VMEM the backward may plan for (v5e and v6e hold 128 MiB a core).
-_BWD_VMEM_BUDGET = 64 * 1024 * 1024
 
 
 def _bwd_block(n: int) -> Optional[int]:
@@ -196,8 +312,8 @@ def _bwd_vmem_bytes(block_q: int, block_k: int, lq: int, group: int,
     d; v, dO of width dv), the group's (lq, d) dq slab (float32
     accumulator and its output), and the (block_k, block_q) float32
     tiles in flight."""
-    dl = -(-d // _LANES) * _LANES
-    dvl = dl if dv is None else -(-dv // _LANES) * _LANES
+    dl = _lanes(d)
+    dvl = dl if dv is None else _lanes(dv)
     ins = (2 * (block_q + block_k) * (dl + dvl) * itemsize
            + 4 * 8 * block_q * 4)
     outs = 2 * (group * lq * dl + block_k * (dl + dvl)) * itemsize
@@ -218,47 +334,9 @@ def bwd_blocks(q_shape, k_shape, dtype,
         return None
     itemsize = jnp.dtype(dtype).itemsize
     if _bwd_vmem_bytes(bq, bk, lq, hq // hkv, d, itemsize,
-                       dv) > _BWD_VMEM_BUDGET:
+                       dv) > _VMEM_BUDGET:
         return None
     return bq, bk
-
-
-def _spans(qi, ki, *, block_q, block_k, offset, causal, window):
-    """(needed, masked) of one (q-block, k-block) pair: whether any of
-    its pairs is attended, and whether some pair in it is not."""
-    q_first = qi * block_q + offset
-    q_last = q_first + block_q - 1
-    k_first = ki * block_k
-    k_last = k_first + block_k - 1
-    needed, whole = True, True
-    if causal:
-        needed = k_first <= q_last
-        whole = k_last <= q_first
-    if window is not None:
-        needed = jnp.logical_and(needed, k_last > q_first - window)
-        whole = jnp.logical_and(whole, k_first > q_last - window)
-    return needed, jnp.logical_not(whole)
-
-
-def _mask_t(s, qi, ki, *, block_q, block_k, offset, causal, window):
-    """``s`` (block_k, block_q) with the pairs not attended set to
-    NEG_INF, and the mask."""
-    shape = s.shape
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    q_pos = qi * block_q + offset + jax.lax.broadcasted_iota(
-        jnp.int32, shape, 1)
-    mask = jnp.ones(shape, jnp.bool_)
-    if causal:
-        mask &= k_pos <= q_pos
-    if window is not None:
-        mask &= k_pos > q_pos - window
-    return jnp.where(mask, s, NEG_INF), mask
-
-
-def _dot_nt(a, b):
-    """a (m, d) . b (n, d)^T -> (m, n) float32."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
 
 
 def _stats_kernel(q_ref, k_ref, o_ref, do_ref, lse_ref, delta_ref,
@@ -287,7 +365,7 @@ def _stats_kernel(q_ref, k_ref, o_ref, do_ref, lse_ref, delta_ref,
     def update(masked: bool):
         s = _dot_nt(k_ref[...], q_ref[...]) * scale           # (bk, bq)
         if masked:
-            s, mask = _mask_t(s, qi, ki, **geom)
+            s, mask = _mask(s, qi, ki, k_axis=0, **geom)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -341,7 +419,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
         s = _dot_nt(k, q) * scale                             # (bk, bq)
         if masked:
-            s, _ = _mask_t(s, qi, ki, **geom)
+            s, _ = _mask(s, qi, ki, k_axis=0, **geom)
         p = jnp.exp(s - lse_ref[...])
         dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
@@ -365,10 +443,6 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when((ki == pl.num_programs(2) - 1) & last_g & last_q)
     def _write_dq():
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
-
-
-def _clamp(i, lo, hi):
-    return jnp.minimum(jnp.maximum(i, lo), hi)
 
 
 def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -403,14 +477,6 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
                 causal=causal, window=window)
     qh, kh, vh, oh, doh = (x.transpose(0, 2, 1, 3) for x in (q, k, v, o, do))
 
-    def k_range(qi):
-        """First and last k block that q block ``qi`` attends."""
-        lo = (jnp.maximum(qi * block_q + offset - window + 1, 0) // block_k
-              if window is not None else 0)
-        hi = ((qi * block_q + block_q - 1 + offset) // block_k
-              if causal else nk - 1)
-        return lo, jnp.minimum(hi, nk - 1)
-
     def q_range(ki):
         """First and last q block that attends k block ``ki``."""
         lo = (jnp.maximum(ki * block_k - offset, 0) // block_q
@@ -418,9 +484,6 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         hi = (jnp.maximum(ki * block_k + block_k - 2 - offset + window, 0)
               // block_q if window is not None else nq - 1)
         return lo, jnp.minimum(hi, nq - 1)
-
-    def stats_k(bi, hi, qi, ki):
-        return (bi, hi // group, _clamp(ki, *k_range(qi)), 0)
 
     row = pl.BlockSpec((None, None, block_q, d),
                        lambda bi, hi, qi, ki: (bi, hi, qi, 0))
@@ -432,7 +495,8 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     lse, delta = pl.pallas_call(
         functools.partial(_stats_kernel, scale=scale, **geom),
         grid=(b, hq, nq, nk),
-        in_specs=[row, pl.BlockSpec((None, None, block_k, d), stats_k),
+        in_specs=[row, pl.BlockSpec((None, None, block_k, d),
+                               _k_index(group, nk, geom)),
                   vrow, vrow],
         out_specs=[stat, stat],
         out_shape=[stats_shape, stats_shape],
@@ -478,8 +542,7 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary", "arbitrary"),
-            vmem_limit_bytes=min(max(32 * 1024 * 1024, vmem + vmem // 2),
-                                 _BWD_VMEM_BUDGET)),
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
         name="flash_attention_bwd",
     )(qh, kh, vh, doh, lse, delta)

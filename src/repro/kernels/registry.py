@@ -26,10 +26,11 @@ variant: tests/test_kernels.py sweeps the full (op, variant, dtype)
 grid fwd AND bwd against them.
 
 Fallback behavior is part of the contract: the PALLAS attention variant
-requires a block size from ``_BLOCKS`` to divide both sequence lengths
-(the flash kernel's grid constraint) and otherwise falls back to the
-XLA formulation — never an error, never a silent wrong answer.  Inside
-a sharded step every PALLAS pick runs the XLA formulation (``resolved``).
+requires blocks from ``flash_attention.fwd_blocks`` to tile both
+sequence lengths (the flash kernel's grid constraint) and otherwise
+falls back to the XLA formulation — never an error, never a silent
+wrong answer.  Inside a sharded step every PALLAS pick runs the XLA
+formulation (``resolved``).
 """
 
 from __future__ import annotations
@@ -77,18 +78,6 @@ def _partitioned() -> bool:
 
 
 # ================================================================ attention
-#: candidate flash-attention block sizes, largest first; both lq and lk
-#: must be divisible by a candidate or PALLAS falls back to XLA.
-_BLOCKS = (128, 64, 32, 16, 8)
-
-
-def _pick_block(n: int) -> Optional[int]:
-    for b in _BLOCKS:
-        if n % b == 0:
-            return b
-    return None
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _attention_pallas(q, k, v, causal: bool, window: Optional[int],
                       block_q: int, block_k: int, interpret: bool):
@@ -168,18 +157,22 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     positions END-aligned (query i at absolute position lk - lq + i).
 
     PALLAS: the flash kernel (online softmax, (block_q, lk) working
-    set); falls back to XLA when no ``_BLOCKS`` entry divides lq and
-    lk.  XLA: the quadratic masked formulation.  Its ops carry the
-    ``attn_fwd`` device phase (its backward, ``attn_bwd``).
+    set) at the blocks ``flash_attention.fwd_blocks`` picks from the
+    shapes; falls back to XLA where it finds none (lq or lk not a
+    multiple of 8).  Each trace of either bumps its
+    ``perfcount.KERNELS`` counter.  XLA: the quadratic masked
+    formulation.  Its ops carry the ``attn_fwd`` device phase (its
+    backward, ``attn_bwd``).
     """
     kt = resolved("attention", kernels)
     with phase("attn_fwd"):
         if kt is KernelType.PALLAS:
-            bq = _pick_block(q.shape[1])
-            bk = _pick_block(k.shape[1])
-            if bq is not None and bk is not None:
-                return _attention_pallas(q, k, v, causal, window, bq, bk,
+            blocks = _fa.fwd_blocks(q.shape, k.shape, q.dtype, v.shape[-1])
+            if blocks is not None:
+                KERNELS.attn_fwd_flash += 1
+                return _attention_pallas(q, k, v, causal, window, *blocks,
                                          not on_tpu())
+            KERNELS.attn_fwd_xla += 1
         return _attention_xla(q, k, v, causal=causal, window=window)
 
 

@@ -128,10 +128,15 @@ TRANSPORT = TransportCounters()
 
 @dataclasses.dataclass
 class KernelPathCounters(_CounterBase):
-    """Which backward each traced attention step compiled: bumped once
-    per trace of ``registry.attention_bwd`` (not per execution), by the
-    path its shapes chose."""
+    """Which forward and backward each traced attention step compiled:
+    bumped once per trace of ``registry.attention``'s PALLAS pick and
+    of ``registry.attention_bwd`` (not per execution), by the path its
+    shapes chose."""
 
+    #: the flash forward kernel (``flash_attention_fwd``)
+    attn_fwd_flash: int = 0
+    #: the XLA formulation (lengths no forward block tiles)
+    attn_fwd_xla: int = 0
     #: the flash backward kernels (``flash_attention_bwd``)
     attn_bwd_flash: int = 0
     #: ``jax.vjp`` of the ``ref.py`` oracle (shapes the kernel does not

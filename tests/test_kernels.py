@@ -10,9 +10,10 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import interface, ops, ref, registry
 from repro.kernels.flash_attention import (flash_attention_bwd,
-                                           flash_attention_fwd)
+                                           flash_attention_fwd, fwd_blocks)
 from repro.kernels.fused_update import fused_update
 from repro.kernels.interface import KernelType
 from repro.kernels.rmsnorm import rmsnorm
@@ -21,14 +22,18 @@ from repro.kernels.rmsnorm import rmsnorm
 # ----------------------------------------------------------- flash attn
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal,window", [(True, None), (True, 32),
-                                           (False, None)])
+                                           (False, None), (True, 96)])
 @pytest.mark.parametrize("b,lq,lk,hq,hkv,d", [
     (1, 128, 128, 2, 2, 64),      # MHA square
     (2, 256, 256, 4, 2, 64),      # GQA
     (1, 128, 256, 4, 1, 128),     # MQA, lk > lq (suffix decode-ish)
+    (1, 64, 384, 4, 2, 64),       # lk > lq, whole k blocks clamped away
 ])
 def test_flash_attention_matches_ref(dtype, causal, window, b, lq, lk,
                                      hq, hkv, d):
+    """Blocks of 64: with lk > lq and under a window shorter (32) or
+    longer (96) than a block, whole k blocks lie outside a q block's
+    range and are skipped with their copies."""
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, lq, hq, d)).astype(dtype)
     k = jax.random.normal(ks[1], (b, lk, hkv, d)).astype(dtype)
@@ -121,6 +126,71 @@ def test_flash_attention_with_v_narrower_than_qk(dtype, group):
     _assert_close(got, vjp(do), dtype, bwd=True)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("lq,lk,window", [(64, 256, None), (128, 256, 24),
+                                          (128, 128, 80)])
+def test_flash_attention_forward_clamps_k_blocks_with_v_narrower(
+        dtype, lq, lk, window):
+    """The forward at v narrower than q/k where whole k blocks of 32
+    are clamped away (lk > lq; a window shorter and longer than a
+    block), against the oracle, tolerances as the equal-width tests."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (1, lq, 4, 96)).astype(dtype)
+    k = jax.random.normal(ks[1], (1, lk, 2, 96)).astype(dtype)
+    v = jax.random.normal(ks[2], (1, lk, 2, 64)).astype(dtype)
+    out = flash_attention_fwd(q, k, v, causal=True, window=window,
+                              block_q=32, block_k=32, interpret=True)
+    want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
+    assert out.shape == want.shape == (1, lq, 4, 64)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("lq,lk,bq,bk", [(128, 128, 32, 32),
+                                         (64, 384, 32, 64),
+                                         (256, 256, 64, 16),
+                                         (512, 512, 128, 128)])
+def test_clamped_k_range_is_the_attended_blocks(lq, lk, bq, bk):
+    """For every q block, the k range its K/V index maps are clamped to
+    is exactly the k blocks ``_spans`` finds needed: no attended block
+    is clamped away, and no block outside the range is needed."""
+    nq, nk = lq // bq, lk // bk
+    for causal, window in ((True, None), (False, None), (True, 8),
+                           (True, 40), (True, 200), (False, 24)):
+        geom = dict(block_q=bq, block_k=bk, offset=lk - lq, causal=causal,
+                    window=window)
+        for qi in range(nq):
+            lo, hi = fa._k_range(qi, nk=nk, **geom)
+            needed = [ki for ki in range(nk)
+                      if bool(fa._spans(qi, ki, **geom)[0])]
+            assert needed == list(range(int(lo), int(hi) + 1)), (
+                causal, window, qi)
+
+
+def test_fwd_blocks_at_chip_widths():
+    """The forward blocks the chip runs: danube (GQA 32/8 heads of 80 at
+    1 x 2048) and Moonlight (16 heads, q/k 192, v 128, 2 x 8192)."""
+    assert fwd_blocks((1, 2048, 32, 80), (1, 2048, 8, 80),
+                      jnp.bfloat16) == (1024, 1024)
+    assert fwd_blocks((2, 8192, 16, 192), (2, 8192, 16, 192),
+                      jnp.bfloat16, 128) == (1024, 1024)
+    assert fwd_blocks((1, 100, 2, 64), (1, 100, 2, 64), jnp.bfloat16) is None
+
+
+def test_fwd_blocks_cover_every_multiple_of_8():
+    """Every length that is a multiple of 8 gets blocks that tile it
+    (the kernel serves it); no other length does (the XLA fallback)."""
+    for n in range(1, 4097):
+        blocks = fwd_blocks((1, n, 4, 64), (1, n, 2, 64), jnp.bfloat16)
+        if n % 8:
+            assert blocks is None, n
+        else:
+            assert blocks is not None and all(
+                n % blk == 0 for blk in blocks), n
+
+
 def test_registry_attention_grads_with_v_narrower_than_qk():
     """Through the registry's custom_vjp (the flash backward, as the
     counters show), gradients at v narrower than q/k match the oracle's."""
@@ -156,6 +226,24 @@ def test_attention_bwd_path_follows_the_shapes():
         gw = jax.grad(lambda *xs: _sq(ref.flash_attention_ref(*xs)),
                       argnums=(0, 1, 2))(q, k, v)
         _assert_close(g, gw, jnp.float32, bwd=True)
+
+
+def test_attention_fwd_path_follows_the_shapes():
+    """The registry's PALLAS forward takes the flash kernel where its
+    blocks tile the sequence and the XLA formulation where they do not
+    (lq = 100), as the trace-time counters show; both match the
+    oracle."""
+    from repro.perfcount import KERNELS
+    for l, path in ((128, "attn_fwd_flash"), (100, "attn_fwd_xla")):
+        ks = jax.random.split(jax.random.PRNGKey(l), 3)
+        q = jax.random.normal(ks[0], (1, l, 4, 32))
+        k = jax.random.normal(ks[1], (1, l, 2, 32))
+        v = jax.random.normal(ks[2], (1, l, 2, 32))
+        before = KERNELS.snapshot()
+        out = registry.attention(q, k, v, kernels="pallas")
+        assert KERNELS.delta(before) == {
+            n: int(n == path) for n in before}, l
+        _assert_close(out, ref.flash_attention_ref(q, k, v), jnp.float32)
 
 
 @given(
@@ -334,8 +422,8 @@ def test_registry_attention_parity(dtype, variant, causal, window):
 
 
 def test_registry_attention_pallas_block_fallback():
-    """lq=100 divides no _BLOCKS entry: PALLAS must fall back to the XLA
-    formulation, never raise, and still match the oracle."""
+    """lq=100 is tiled by no forward block: PALLAS must fall back to the
+    XLA formulation, never raise, and still match the oracle."""
     ks = jax.random.split(jax.random.PRNGKey(12), 3)
     q = jax.random.normal(ks[0], (1, 100, 2, 64))
     k = jax.random.normal(ks[1], (1, 100, 2, 64))

@@ -85,13 +85,22 @@ def _compile(one_chip, fn, *shapes):
     return text
 
 
-def test_flash_attention_compiles_at_chip_widths(one_chip):
+def _attention_fwd(batch, seq):
+    """The flash forward at chip widths and its argument shapes (q, k,
+    v), with the blocks the registry picks for them."""
     hd = CHIP.resolved_head_dim
-    q = ((BATCH, SEQ, CHIP.n_heads, hd), jnp.bfloat16)
-    kv = ((BATCH, SEQ, CHIP.n_kv_heads, hd), jnp.bfloat16)
-    _compile(one_chip, functools.partial(
-        fa.flash_attention_fwd, causal=True, window=CHIP.sliding_window),
-        q, kv, kv)
+    q = ((batch, seq, CHIP.n_heads, hd), jnp.bfloat16)
+    kv = ((batch, seq, CHIP.n_kv_heads, hd), jnp.bfloat16)
+    bq, bk = fa.fwd_blocks(q[0], kv[0], jnp.bfloat16)
+    return (functools.partial(fa.flash_attention_fwd, causal=True,
+                              window=CHIP.sliding_window, block_q=bq,
+                              block_k=bk), [q, kv, kv])
+
+
+def test_flash_attention_compiles_at_chip_widths(one_chip):
+    fn, shapes = _attention_fwd(BATCH, SEQ)
+    assert (fn.keywords["block_q"], fn.keywords["block_k"]) == (1024, 1024)
+    _compile(one_chip, fn, *shapes)
 
 
 def _attention_bwd(batch, seq):
@@ -133,12 +142,14 @@ def test_attention_grad_keeps_forward_reader_to_forward_calls(
 
     from repro.kernels.registry import _attention_pallas
 
+    _, shapes = _attention_bwd(1, SEQ)
+    blocks = fa.fwd_blocks(shapes[0][0], shapes[1][0], jnp.bfloat16)
+
     def loss(q, k, v):
         attn = jax.checkpoint(lambda *xs: _attention_pallas(
-            *xs, True, CHIP.sliding_window, 128, 128, False))
+            *xs, True, CHIP.sliding_window, *blocks, False))
         return jnp.sum(jnp.square(attn(q, k, v).astype(jnp.float32)))
 
-    _, shapes = _attention_bwd(1, SEQ)
     text = _compile(one_chip, jax.grad(loss, argnums=(0, 1, 2)),
                     *shapes[:3])
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
@@ -161,17 +172,17 @@ def _grids(fn, *shapes):
 
 
 def test_flash_attention_grids_at_equal_widths_are_unchanged():
-    """With v as wide as q/k (the danube cells), the kernels keep the
-    grids and blocks they had before they took a narrower v: the forward
-    (b, hq, lq/128, lk/128) of 128-row blocks; the stats pass (b, hq,
-    4, 4) and the backward (b, hkv, 4, group, 4) of 512-row blocks."""
+    """With v as wide as q/k (the danube cells), the kernels run the
+    grids and blocks the registry picks at chip widths: the forward
+    (b, hq, lq/1024, lk/1024) of 1024-row blocks (``fwd_blocks``); the
+    stats pass (b, hq, 4, 4) and the backward (b, hkv, 4, group, 4) of
+    512-row blocks, as before they took a narrower v."""
     hd = CHIP.resolved_head_dim
     fwd, bwd = _attention_bwd(BATCH, SEQ)
-    row = (None, None, 128, hd)
-    assert _grids(functools.partial(
-        fa.flash_attention_fwd, causal=True, window=CHIP.sliding_window),
-        *bwd[:3]) == [((BATCH, 32, 16, 16), [row] * 4)]
     blk, stat = (None, None, 512, hd), (None, None, 1, 512)
+    fwd_fn, fwd_shapes = _attention_fwd(BATCH, SEQ)
+    assert _grids(fwd_fn, *fwd_shapes) == [
+        ((BATCH, 32, 2, 2), [(None, None, 1024, hd)] * 4)]
     assert _grids(fwd, *bwd) == [
         ((BATCH, 32, 4, 4), [blk] * 4 + [stat] * 2),
         ((BATCH, 8, 4, 4, 4), [blk] * 4 + [stat] * 2
@@ -181,12 +192,16 @@ def test_flash_attention_grids_at_equal_widths_are_unchanged():
 def test_flash_attention_compiles_at_latent_attention_widths(one_chip):
     """Moonlight's attention as the chip runs it: 16 heads, q/k 192 wide
     (128 + 64 rope), v 128, 2 x 8192 tokens, the forward and the
-    backward (whose 512-row blocks fit the VMEM budget)."""
+    backward (whose 512-row blocks fit the VMEM budget), each at the
+    blocks the registry picks (the forward's 1024)."""
     dqk = MOON.qk_head_dim
     q = ((2, 8192, MOON.n_heads, dqk), jnp.bfloat16)
     v = ((2, 8192, MOON.n_heads, MOON.v_head_dim), jnp.bfloat16)
-    _compile(one_chip, functools.partial(fa.flash_attention_fwd,
-                                         causal=True), q, q, v)
+    bq, bk = fa.fwd_blocks(q[0], q[0], jnp.bfloat16, MOON.v_head_dim)
+    assert (bq, bk) == (1024, 1024)
+    _compile(one_chip, functools.partial(
+        fa.flash_attention_fwd, causal=True, block_q=bq, block_k=bk),
+        q, q, v)
     blocks = fa.bwd_blocks(q[0], q[0], jnp.bfloat16, MOON.v_head_dim)
     assert blocks == (512, 512)
     _compile(one_chip, functools.partial(
