@@ -10,7 +10,7 @@ Three measurements, emitted as ``BENCH_obs.json``:
      with the recorder enabled (ring capacity bounds memory, so this is
      the rate at which a traced run can emit before dropping).
   3. **hotpath off/on** — the same externally-driven pull+push loop
-     against a packed mono server over the inproc endpoint, once with
+     against a one-shard server over the inproc endpoint, once with
      tracing off and once with it on; reports both ``perfcount``
      deltas.  The gate (``perf_gate.py --obs``) fails if the deltas
      differ — instrumentation must never add counted hot-path work —
@@ -28,7 +28,7 @@ import time
 import numpy as np
 
 from repro.api import (ModelSpec, OptimizerSpec, RunSpec, ServerSpec,
-                       SyncSpec, TransportSpec, WireSpec, build_session)
+                       SyncSpec, TransportSpec, build_session)
 from repro.obs.trace import TRACE
 from repro.perfcount import TRANSPORT, WIRE, snapshot_all
 from repro.wireformat import WIRE_LANES
@@ -63,7 +63,7 @@ def bench_enabled_drain(n_events: int) -> float:
 
 
 def _hotpath_once(n_rounds: int) -> dict:
-    """Drive pull+push rounds against a packed mono server over the
+    """Drive pull+push rounds against a one-shard server over the
     inproc endpoint; return the perfcount deltas for the loop."""
     params = {"w": np.arange(2048, dtype=np.float32),
               "b": np.ones(256, dtype=np.float32)}
@@ -71,8 +71,7 @@ def _hotpath_once(n_rounds: int) -> dict:
         model=ModelSpec(arch="custom"),
         optimizer=OptimizerSpec(lr=0.01),
         sync=SyncSpec(mode="asp"),
-        ps=ServerSpec(kind="mono", shards=0, workers=1, apply="packed"),
-        wire=WireSpec(format="packed"),
+        ps=ServerSpec(kind="sharded", shards=1, workers=1),
         transport=TransportSpec(kind="inproc", endpoint=True))
     session = build_session(spec, params=params,
                             external_workers=True).start()

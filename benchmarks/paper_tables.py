@@ -42,7 +42,6 @@ def _step_fn(classes):
         logp = jax.nn.log_softmax(logits)
         return -jnp.mean(jnp.take_along_axis(logp, y[:, None], 1))
 
-    @jax.jit
     def step(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return grads, {"loss": loss}
@@ -71,7 +70,7 @@ def _run_ps(policy_name: str, speed_factors: List[float], iters: int,
                       staleness=pol_kw.get("staleness", 1),
                       s_lower=pol_kw.get("s_lower", 0),
                       s_upper=pol_kw.get("s_upper", 3)),
-        ps=ServerSpec(kind="mono", shards=1, workers=n))
+        ps=ServerSpec(kind="sharded", shards=1, workers=n))
     step = _step_fn(classes)
     t0 = time.monotonic()
     with build_session(spec, params=params, step_fn=step,

@@ -81,7 +81,8 @@ def check(current: dict, previous: dict | None) -> list:
                 f"(full snapshot is {row['full_bytes_per_pull']:.0f})")
     if not current.get("derived", {}).get("target_met", False):
         failures.append("derived.target_met is false "
-                        "(packed vs tree_fused repack target missed)")
+                        "(the packed path repacked at the largest shard "
+                        "count)")
     if previous is not None:
         prev_rows = _rows_by_key(previous)
         for key in sorted(set(rows) & set(prev_rows)):

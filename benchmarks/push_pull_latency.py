@@ -1,15 +1,12 @@
-"""Push/pull hot-path microbenchmark: tree wire format vs packed.
+"""Push/pull hot-path microbenchmark on the packed wire.
 
 Measures the SERVER-side cost of one push / one pull through the
-sharded parameter server, per wire format:
+sharded parameter server:
 
-  * ``tree``        apply_mode=tree   — per-leaf optimizer step,
-  * ``tree_fused``  apply_mode=fused  — one kernel launch per shard but
-                    a ``pack_shard`` (concat) per shard per push,
   * ``packed``      push_packed       — the zero-repack path: the wire
                     buffer is sliced into per-shard views, no packing,
-  * ``*+int8``      the same with wire compression (per-leaf tree_map
-                    dispatches vs ONE fused launch per shard),
+  * ``packed+int8`` the same with fused wire compression (ONE launch
+                    per shard),
   * ``coalesced_W{N}``  N concurrent workers pushing into a coalescing
                     window of N: one ``fused_update_batched`` launch
                     per shard per ROUND instead of per push —
@@ -20,10 +17,8 @@ sharded parameter server, per wire format:
 
 Wall time on this container is interpret-mode dominated and mostly
 meaningless; the *event counts* (``repro.perfcount``) are
-backend-independent and are what the packed format eliminates:
-``repack_events`` = packs + unpacks + per-leaf concats per push.  The
-acceptance target (>= 2x lower per-push overhead at S=16 on the tail of
-small leaves) is checked on that metric.
+backend-independent and are what the packed format keeps at zero:
+``repack_events`` = packs + unpacks + per-leaf concats per push.
 
 Emits machine-readable ``BENCH_push_pull.json`` plus the standard
 ``name,us_per_call,derived`` CSV on stdout.  ``--smoke`` runs a tiny
@@ -69,54 +64,37 @@ def _grads_like(tree, seed: int):
         if p.shape else jnp.float32(rng.randn()), tree)
 
 
-def _session(params, n_shards: int, apply_mode: str,
-             wire_format: str = "tree", compression: str = "none",
+def _session(params, n_shards: int, compression: str = "none",
              workers: int = 1, coalesce: int = 1,
              coalesce_wait_ms=None, delta_pull: bool = False):
     """One externally-driven session per measured path: the spec picks
-    the wire/apply/compression combination, the bench pushes payloads
-    at the session's server directly."""
+    the compression and coalescing, the bench pushes payloads at the
+    session's server directly."""
     spec = RunSpec(
         model=ModelSpec(arch="custom"),
         optimizer=OptimizerSpec(name="momentum", lr=0.01, momentum=0.9),
         sync=SyncSpec(mode="asp"),
         ps=ServerSpec(kind="sharded", shards=n_shards, workers=workers,
-                      apply=apply_mode, coalesce=coalesce,
+                      coalesce=coalesce,
                       coalesce_wait_ms=coalesce_wait_ms),
-        wire=WireSpec(format=wire_format, compression=compression,
-                      delta_pull=delta_pull))
+        wire=WireSpec(compression=compression, delta_pull=delta_pull))
     return build_session(spec, params=params,
                          external_workers=True).start()
 
 
-def _block_tree(tree):
-    jax.block_until_ready(jax.tree_util.tree_leaves(tree))
-
-
 def bench_path(params, grads_seq, n_shards: int, path: str,
                n_pushes: int) -> Dict[str, object]:
-    compress = path.endswith("+int8")
-    base = path[:-5] if compress else path
-    if base == "packed":
-        session = _session(params, n_shards, "fused",
-                           wire_format="packed",
-                           compression="int8" if compress else "none")
-        server = session.server
-        payloads = [server.plan.pack(g) for g in grads_seq]
-    else:
-        session = _session(params, n_shards,
-                           "fused" if base == "tree_fused" else "tree",
-                           compression="int8" if compress else "none")
-        server = session.server
-        payloads = list(grads_seq)
-    push = server.push_packed if base == "packed" else server.push
-    pull = (server.pull_packed if base == "packed" else server.pull)
+    session = _session(params, n_shards,
+                       compression="int8" if path.endswith("+int8")
+                       else "none")
+    server = session.server
+    payloads = [server.plan.pack(g) for g in grads_seq]
+    push, pull = server.push_packed, server.pull_packed
 
     def block_server():
         # Drain device work without touching the counted wire APIs.
         for st in server.shards:
-            jax.block_until_ready(st._pieces if st._pieces is not None
-                                  else st._packed_p)
+            jax.block_until_ready(st._packed_p)
 
     push(0, payloads[0])                      # warm up compile caches
     pull(0)
@@ -137,8 +115,7 @@ def bench_path(params, grads_seq, n_shards: int, path: str,
         block_server()
         before = WIRE.snapshot()
         t0 = time.monotonic()
-        out = pull(0)
-        _block_tree(out)
+        jax.block_until_ready(pull(0))
         pull_wall += time.monotonic() - t0
         for k, v in WIRE.delta(before).items():
             pull_events[k] += v
@@ -170,7 +147,7 @@ def bench_coalesced(params, grads_seq, n_shards: int, workers: int,
     # A generous linger makes the round deterministic on loaded CI
     # runners: the flusher waits for all W contributors (they are all
     # pushing concurrently) instead of racing the scheduler.
-    session = _session(params, n_shards, "fused", wire_format="packed",
+    session = _session(params, n_shards,
                        workers=workers, coalesce=workers,
                        coalesce_wait_ms=5000.0 if workers > 1 else 0.0)
     server = session.server
@@ -212,8 +189,8 @@ def bench_delta(params, grads_seq, n_shards: int, workers: int,
                 n_pulls: int) -> Dict[str, object]:
     """W workers each advance one shard (w mod S), then one
     version-delta pull: bytes shipped vs the full snapshot."""
-    session = _session(params, n_shards, "fused", wire_format="packed",
-                       workers=workers, delta_pull=True)
+    session = _session(params, n_shards, workers=workers,
+                       delta_pull=True)
     server = session.server
     layout = server.plan.wire_layout()
     itemsize = jnp.dtype(layout.dtype).itemsize
@@ -269,8 +246,7 @@ def main() -> None:
     n_leaves = len(jax.tree_util.tree_leaves(params))
     grads_seq = [_grads_like(params, s) for s in range(2)]
 
-    paths = ["tree", "tree_fused", "packed", "tree_fused+int8",
-             "packed+int8"]
+    paths = ["packed", "packed+int8"]
     rows: List[Dict[str, object]] = []
     for s in shard_counts:
         for path in paths:
@@ -285,13 +261,11 @@ def main() -> None:
         rows.append(bench_delta(params, grads_seq, cd_shards, w,
                                 n_pushes))
 
-    # Derived acceptance metric: packed vs tree_fused repack overhead at
-    # the largest shard count.
+    # Derived acceptance metric: the packed path's repack events per
+    # push at the largest shard count.
     s_max = max(shard_counts)
     by = {r["path"]: r for r in rows if r["shards"] == s_max}
-    fused_ov = by["tree_fused"]["repack_events_per_push"]
     packed_ov = by["packed"]["repack_events_per_push"]
-    ratio = fused_ov / max(packed_ov, 1e-9)
     co_rows = [r for r in rows if r["path"].startswith("coalesced")]
     de_rows = [r for r in rows if r["path"].startswith("delta")]
     coalesced_ok = all(r["launches_per_round"] <= r["shards"] + 1e-6
@@ -308,12 +282,9 @@ def main() -> None:
         "rows": rows,
         "derived": {
             "s_max": s_max,
-            "repack_events_per_push_tree_fused": fused_ov,
             "repack_events_per_push_packed": packed_ov,
-            # null = packed path did zero repacks (ratio undefined/infinite);
-            # kept strict-JSON-parseable for downstream consumers.
-            "repack_overhead_ratio": (ratio if packed_ov > 0 else None),
-            "target_met": packed_ov == 0 or ratio >= 2.0,
+            # the zero-repack contract at the largest shard count
+            "target_met": packed_ov == 0,
             # coalescing contract: batched-apply launches per round
             # scale with shards, not shards x workers
             "coalesced_target_met": coalesced_ok,
@@ -344,9 +315,7 @@ def main() -> None:
                   f"repack={r['repack_events_per_push']:.1f}"
                   f";launches={r['pallas_calls_per_push']:.1f}")
     print(f"# packed repack events/push at S={s_max}: {packed_ov:.1f} "
-          f"(tree_fused: {fused_ov:.1f}, ratio "
-          f"{'inf' if packed_ov == 0 else f'{ratio:.1f}'}x, "
-          f"target >=2x: {report['derived']['target_met']})")
+          f"(target 0: {report['derived']['target_met']})")
     print(f"# coalesced launches/round <= shards: {coalesced_ok}; "
           f"delta bytes < full on partial advance: {delta_ok}")
     print(f"# wrote {args.out}")

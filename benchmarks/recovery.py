@@ -49,8 +49,7 @@ from repro.ft.snapshot import (
 )
 from repro.models import registry
 from repro.obs.trace import TRACE
-from repro.ps.server import ServerOptimizer
-from repro.ps.sharded import ShardedParameterServer
+from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
 from repro.transport import PSServerEndpoint, connect
 from repro.transport.tcp import TcpTransport
 
@@ -64,7 +63,7 @@ def build_server(arch: str, n_shards: int, n_workers: int):
         params,
         make_policy_factory("asp", n_workers=n_workers),
         lambda: ServerOptimizer(lr=0.05),
-        n_workers, n_shards, apply_mode="fused")
+        n_workers, n_shards)
 
 
 def bench_snapshot(server, rounds: int) -> dict:

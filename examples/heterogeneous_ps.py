@@ -2,9 +2,9 @@
 
 Four workers train one shared model through the parameter server; worker
 3 is 4x slower (the paper's GTX1060 next to GTX1080Ti).  Each paradigm
-runs the same jitted SGD steps — only the synchronization policy
-differs, and with ``repro.api`` a paradigm (or the server kind) is one
-spec field: the example builds every run through
+runs the same SGD steps — only the synchronization policy differs, and
+with ``repro.api`` a paradigm (or the shard count) is one spec field:
+the example builds every run through
 ``build_session(RunSpec(...))`` with a custom toy problem injected as
 build-time overrides.  Reported: updates applied, waiting time,
 staleness profile, final loss, plus the virtual-time Table-I
@@ -13,8 +13,8 @@ composition.
 Run:  PYTHONPATH=src python examples/heterogeneous_ps.py
       PYTHONPATH=src python examples/heterogeneous_ps.py --ps-shards 4
 
-With ``--ps-shards N > 1`` the same experiment runs through the
-partitioned ``ShardedParameterServer``: per-shard locks/versions and
+With ``--ps-shards N > 1`` the weights are partitioned across N shards
+of the ``ShardedParameterServer``: per-shard locks/versions and
 per-shard DSSP gating, so pushes to different shards proceed
 concurrently (ps/sharded/).
 """
@@ -55,10 +55,7 @@ def sync_spec(name: str, kw: dict) -> SyncSpec:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--ps-shards", type=int, default=1, metavar="N",
-                    help="partition the weights across N server shards "
-                         "(1 = the monolithic server)")
-    ap.add_argument("--ps-apply", default="tree",
-                    choices=["tree", "fused"])
+                    help="partition the weights across N server shards")
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (fewer iterations/pushes)")
     args = ap.parse_args()
@@ -73,7 +70,6 @@ def main() -> None:
         logp = jax.nn.log_softmax(bx @ params["w"] + params["b"])
         return -jnp.mean(jnp.take_along_axis(logp, by[:, None], 1))
 
-    @jax.jit
     def step(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return grads, {"loss": loss}
@@ -88,11 +84,7 @@ def main() -> None:
     speeds = [1.0, 1.0, 1.0, 4.0]
     print(f"4 workers, speed factors {speeds}, {iters} iterations each, "
           f"{n_shards} server shard(s)\n")
-    if n_shards > 1:
-        server_spec = ServerSpec(kind="sharded", shards=n_shards,
-                                 workers=4, apply=args.ps_apply)
-    else:
-        server_spec = ServerSpec(kind="mono", shards=1, workers=4)
+    server_spec = ServerSpec(kind="sharded", shards=n_shards, workers=4)
     runs = []
     shard_runs = []
     for name, kw in POLICIES:

@@ -1,25 +1,17 @@
 """``ParameterServerProtocol`` — the one server surface every engine,
 endpoint and worker codes against.
 
-``ParameterServer`` (monolithic) and ``ShardedParameterServer`` both
-inherit this base, so the transport endpoint, the PS workers and the
-process pool never branch on the server's concrete type: every server
-answers the full push/pull surface —
+``ShardedParameterServer`` implements it; the transport endpoint, the
+PS workers and the process pool code against this surface, which
+speaks the packed (rows, 512) wire format only —
 
-    pull / push                tree wire format (per-leaf pytrees)
     pull_packed / push_packed  packed (rows, 512) wire format
     pull_delta                 version-delta pull (changed shards only)
     pull_packed_shard /        per-shard packed regions (the unit the
     push_packed_shard          transport endpoints route on)
-    snapshot / shutdown        lifecycle
+    snapshot / shutdown        lifecycle (``snapshot`` is the pytree read)
     add_worker / remove_worker elastic membership
     record_loss / metrics      accounting
-
-The per-shard variants have a default single-shard implementation
-(shard 0 == the whole store), so the monolithic server is routable
-behind a per-shard endpoint without any adapter.  ``packed_wire``
-reports whether the packed surface is live for this instance (it
-depends on the constructor's apply mode, not the class).
 
 Import-light on purpose: this module must be importable before jax and
 without triggering the rest of ``repro.api`` machinery.
@@ -31,7 +23,6 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 Params = Any
-Grads = Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,77 +57,36 @@ class DeltaPull:
 
 
 class ParameterServerProtocol:
-    """Base class + default impls for the unified server surface.
+    """Base class of the server surface: the calls a server must
+    answer, and the lifecycle aliases every server shares."""
 
-    Subclasses must provide ``pull``, ``push``, ``stop``,
-    ``record_loss``, ``add_worker``, ``remove_worker`` and a
-    ``version`` counter; packed-mode subclasses additionally provide
-    ``pull_packed``/``push_packed`` (the per-shard defaults below then
-    come for free on single-shard servers).
-    """
-
-    #: concrete servers set this in __init__ ("tree"/"packed"/"fused")
-    apply_mode: str = "tree"
     stopped: bool = False
     version: int = 0
-
-    # ---------------------------------------------------- capabilities
-    @property
-    def packed_wire(self) -> bool:
-        """Does this instance hold a resident packed store (i.e. are
-        ``*_packed`` calls valid)?  The transport layer speaks packed
-        frames only and checks this instead of the concrete type."""
-        return self.apply_mode in ("packed", "fused")
-
-    #: plain attribute (not a property) so sharded subclasses can
-    #: assign their arity in __init__
     n_shards: int = 1
 
     def shard_versions(self) -> List[int]:
-        return [self.version]
-
-    # ------------------------------------------------------- tree wire
-    def pull(self, worker: int) -> Params:
-        raise NotImplementedError
-
-    def push(self, worker: int, grads: Grads) -> None:
         raise NotImplementedError
 
     # ----------------------------------------------------- packed wire
     def pull_packed(self, worker: int = -1):
-        raise NotImplementedError(
-            f"{type(self).__name__}(apply_mode={self.apply_mode!r}) has "
-            "no resident packed store")
+        raise NotImplementedError
 
     def push_packed(self, worker: int, wire) -> None:
-        raise NotImplementedError(
-            f"{type(self).__name__}(apply_mode={self.apply_mode!r}) has "
-            "no resident packed store")
+        raise NotImplementedError
 
     def pull_delta(self, worker: int,
                    versions: Optional[Sequence[int]]) -> DeltaPull:
         """Version-delta pull: the shards that advanced past the
         client's ``versions`` vector, or a full-snapshot fallback on a
-        vector mismatch.  Packed-mode servers override this; the base
-        raises like the other packed calls."""
-        raise NotImplementedError(
-            f"{type(self).__name__}(apply_mode={self.apply_mode!r}) has "
-            "no resident packed store")
+        vector mismatch."""
+        raise NotImplementedError
 
-    # ------------------------------------- per-shard (default: 1 shard)
+    # ------------------------------------------------------- per shard
     def pull_packed_shard(self, shard: int, worker: int = -1):
-        self._only_shard(shard)
-        return self.pull_packed(worker)
+        raise NotImplementedError
 
     def push_packed_shard(self, worker: int, shard: int, buf) -> None:
-        self._only_shard(shard)
-        self.push_packed(worker, buf)
-
-    def _only_shard(self, shard: int) -> None:
-        if shard != 0:
-            raise ValueError(
-                f"{type(self).__name__} is single-shard: shard must be "
-                f"0, got {shard}")
+        raise NotImplementedError
 
     # ------------------------------------------------------- lifecycle
     def stop(self) -> None:
@@ -149,8 +99,8 @@ class ParameterServerProtocol:
         self.stop()
 
     def snapshot(self) -> Params:
-        """A consistent pytree snapshot of the global weights."""
-        return self.pull(-1)
+        """A pytree snapshot of the global weights."""
+        raise NotImplementedError
 
     @property
     def params(self) -> Params:

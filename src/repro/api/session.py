@@ -18,7 +18,9 @@ processes and tooling that never trains.
 
 Build-time overrides (keyword arguments to ``build_session``) inject
 the pieces a spec cannot serialize: a custom parameter pytree, a custom
-jitted step, per-worker batch iterators, per-worker speed factors.
+pytree step ``(params, batch) -> (grads, aux)`` (the session wraps it
+in the packed step, ``repro.ps.step.packed_step``), per-worker batch
+iterators, per-worker speed factors.
 They exist for benchmarks and toy problems (``model.arch='custom'``);
 ordinary runs need none of them.
 
@@ -283,14 +285,10 @@ def _obs_snapshot_fn(server):
             "credit_releases": m.credit_releases,
             "perfcount": snapshot_all(),
         }
-        shards = getattr(server, "shards", None)
-        pol, trk = ((shards[0].policy, shards[0].tracker) if shards
-                    else (getattr(server, "policy", None),
-                          getattr(server, "tracker", None)))
-        if pol is not None:
-            bound = pol.effective_staleness_bound(trk)
-            out["effective_threshold"] = (None if bound == float("inf")
-                                          else float(bound))
+        shard = server.shards[0]
+        bound = shard.policy.effective_staleness_bound(shard.tracker)
+        out["effective_threshold"] = (None if bound == float("inf")
+                                      else float(bound))
         return out
 
     return snap
@@ -300,7 +298,7 @@ def _obs_snapshot_fn(server):
 # server builders
 # ===================================================================
 def _server_optimizer_factory(spec: RunSpec):
-    from repro.ps.server import ServerOptimizer
+    from repro.ps.sharded import ServerOptimizer
     opt = spec.optimizer
     damping = (False if opt.staleness_damping is None
                else opt.staleness_damping)
@@ -309,55 +307,30 @@ def _server_optimizer_factory(spec: RunSpec):
                                    staleness_damping=damping)
 
 
-def _coalesce_kwargs(spec: RunSpec) -> Dict[str, Any]:
-    wait = spec.ps.coalesce_wait_ms
-    return {"coalesce": spec.ps.coalesce,
-            "coalesce_wait": None if wait is None else wait / 1e3}
-
-
-def _compression_plan(spec: RunSpec):
-    """(tree_compressor, wire_compression, frame_compress) — where the
-    configured compression actually runs, per the transport/wire combo
-    (frame-level int8 shrinks real wire bytes and dequantizes on
-    receipt, so the server must not quantize again)."""
-    packed = spec.wire.format == "packed"
+def _wire_compression(spec: RunSpec):
+    """The compression the server runs on the packed push (None when
+    off, or when int8 rides the process-transport frames instead: they
+    shrink the real wire bytes and dequantize on receipt, so the server
+    must not quantize again)."""
     comp = spec.wire.compression
-    frame = ("int8" if spec.transport.kind != "inproc" and comp == "int8"
-             else "none")
-    if frame != "none" or comp == "none":
-        wire_compression = None
-    else:
-        wire_compression = comp if packed else None
-    tree_compressor = comp if (not packed and comp != "none"
-                               and frame == "none") else None
-    return tree_compressor, wire_compression, frame
-
-
-@register_server("mono")
-def _build_mono(spec: RunSpec, params):
-    from repro.ps.server import ParameterServer
-    policy = spec.sync.policy_factory(spec.ps.workers)()
-    return ParameterServer(
-        params, policy, _server_optimizer_factory(spec)(),
-        spec.ps.workers,
-        apply_mode="packed" if spec.ps.apply == "packed" else "tree",
-        **_coalesce_kwargs(spec))
+    if comp == "none" or (comp == "int8" and spec.transport.kind != "inproc"):
+        return None
+    return comp
 
 
 @register_server("sharded")
 def _build_sharded(spec: RunSpec, params):
-    from repro.optim.compression import make_compressor
     from repro.ps.sharded import ShardedParameterServer
-    tree_comp, wire_comp, _ = _compression_plan(spec)
+    wait = spec.ps.coalesce_wait_ms
     return ShardedParameterServer(
         params, spec.sync.policy_factory(spec.ps.workers),
         _server_optimizer_factory(spec),
         spec.ps.workers, spec.ps.shards,
-        gating=spec.ps.gating, apply_mode=spec.ps.apply,
-        compressor=make_compressor(tree_comp) if tree_comp else None,
-        wire_compression=wire_comp,
+        gating=spec.ps.gating,
+        wire_compression=_wire_compression(spec),
         topk_fraction=spec.wire.topk_fraction,
-        **_coalesce_kwargs(spec))
+        coalesce=spec.ps.coalesce,
+        coalesce_wait=None if wait is None else wait / 1e3)
 
 
 # ===================================================================
@@ -566,9 +539,9 @@ class ThreadedPSSession(TrainingSession):
     obs_rig = None
     ft_rig = None
     serve_results = None
-    #: The shared jitted packed-wire step ``(wire_p, wire_g, batch) ->
-    #: (wire_g, aux)`` once ``run`` built it (None on the tree wire or
-    #: with a ``step_fn`` override) — for inspecting what compiled.
+    #: The shared jitted packed step ``(wire_p, wire_g, batch) ->
+    #: (wire_g, aux)`` once ``run`` built it — for inspecting what
+    #: compiled.
     worker_step = None
     #: JAX platform of each worker's step output, after ``run``.
     worker_platforms: Tuple[str, ...] = ()
@@ -580,7 +553,7 @@ class ThreadedPSSession(TrainingSession):
         if self.spec.obs.trace:
             self.obs_rig = _ObsRig(self.spec.obs)
             self.obs_rig.start(_obs_snapshot_fn(self.server))
-        if self.verbose and self.spec.ps.kind == "sharded":
+        if self.verbose:
             print(self.server.plan.describe())
 
     def _run(self, steps: int) -> None:
@@ -593,14 +566,13 @@ class ThreadedPSSession(TrainingSession):
         w = spec.ps.workers
         iters = max(1, steps // w)
         speeds = _speed_factors(spec, self._ov.get("speed_factors"))
-        make_step = self._step_factory()
+        step = self._worker_step()
         batches = self._batches_factory()
         loss_from_aux = self._ov.get("loss_from_aux",
                                      _default_loss_from_aux)
         workers = [
-            PSWorker(i, self.server, make_step(), batches(i), iters,
+            PSWorker(i, self.server, step, batches(i), iters,
                      speed_factor=speeds[i],
-                     wire_format=spec.wire.format,
                      delta_pull=spec.wire.delta_pull,
                      loss_from_aux=loss_from_aux)
             for i in range(w)]
@@ -626,68 +598,19 @@ class ThreadedPSSession(TrainingSession):
                   f"max_stale={m.max_staleness}")
 
     # -- worker construction ------------------------------------------
-    def _step_factory(self):
-        """() -> step_fn per worker.  The packed path gives each worker
-        its own donated gradient wire buffer around one shared jit."""
-        step_fn = self._ov.get("step_fn")
-        if step_fn is not None:
-            return lambda: step_fn
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        from repro.models import registry
-        cfg, _ = _model_setup(self.spec)
-        loss_fn = registry.loss_fn(cfg)
-        if self.spec.wire.format == "tree":
-            @jax.jit
-            def _tree_step(p, batch):
-                (loss, _), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(p, batch)
-                return grads, {"loss": loss}
-
-            return lambda: _tree_step
-
-        from repro.obs.device import phase
-        plan = self.server.plan
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def _packed_step(wire_p, wire_g_prev, batch):
-            with phase("wire"):
-                p = plan.unpack(wire_p)
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(p, batch)
-            # Write the packed grads INTO the donated buffer: the
-            # output aliases wire_g_prev's memory.  A plain `return
-            # plan.pack(...)` would leave wire_g_prev unread, and jit's
-            # keep_unused=False prunes unread args before donation.
-            with phase("wire"):
-                wire_g = wire_g_prev.at[:].set(plan.pack(grads))
-            aux = {"loss": loss}
-            if "counts" in metrics:  # the model's perfcount groups
-                aux["counts"] = metrics["counts"]
-            return wire_g, aux
-
-        self.worker_step = _packed_step
-
-        def make_step():
-            # One gradient wire buffer per worker, donated back into
-            # the jit every iteration; the params buffer is the
-            # server's shared snapshot and must NOT be donated.
-            from repro.wireformat import WIRE_LANES
-            layout = plan.wire_layout()
-            state = {"g": jnp.zeros((layout.total_rows, WIRE_LANES),
-                                    layout.dtype)}
-
-            def step(wire_p, batch):
-                g, aux = _packed_step(wire_p, state["g"], batch)
-                state["g"] = g
-                return g, aux
-
-            return step
-
-        return make_step
+    def _worker_step(self):
+        """The packed step every worker runs: the ``step_fn`` override
+        (a pytree step) or the registry model's loss, around the
+        server's wire layout.  Each worker donates its own gradient
+        buffer into it."""
+        from repro.ps.step import loss_step, packed_step
+        tree_step = self._ov.get("step_fn")
+        if tree_step is None:
+            from repro.models import registry
+            cfg, _ = _model_setup(self.spec)
+            tree_step = loss_step(registry.loss_fn(cfg))
+        self.worker_step = packed_step(self.server.plan, tree_step)
+        return self.worker_step
 
     def _batches_factory(self):
         batches = self._ov.get("batches")
@@ -783,12 +706,6 @@ class TransportPSSession(TrainingSession):
         through the version-delta full-pull fallback on their next
         pull.  Returns False when the server is already at that arity."""
         self.start()
-        if not hasattr(self.server, "reshard"):
-            raise SpecError(
-                "live resharding migrates the sharded server's packed "
-                "stores — this spec builds "
-                f"ps.kind={self.spec.ps.kind!r}; set ps.kind='sharded' "
-                "with ps.apply='fused'")
         return bool(self.server.reshard(int(n_shards)))
 
     def _run(self, steps: int) -> None:

@@ -1,12 +1,11 @@
 """The declarative run specification: one frozen config tree per run.
 
 ``RunSpec`` describes *what* to train and *how* the distributed pieces
-fit together — model, data, optimizer, synchronization paradigm, server
-kind, wire format, transport — and validates the whole combination at
-construction time.  Invalid combinations (a tree wire over a process
-transport, a fused apply on the monolithic server, ASP on the SPMD
-pipeline, ...) raise ``SpecError`` with an actionable message instead
-of failing deep inside a worker thread.
+fit together — model, data, optimizer, synchronization paradigm, server,
+wire, transport — and validates the whole combination at construction
+time.  Invalid combinations (ASP on the SPMD pipeline, a process
+transport without a parameter server, ...) raise ``SpecError`` with an
+actionable message instead of failing deep inside a worker thread.
 
 The tree is plain data: ``to_dict``/``from_dict`` round-trip it
 bitwise, ``to_json``/``from_json`` wrap that for files, and
@@ -31,10 +30,10 @@ SPEC_VERSION = 1
 
 SYNC_MODES = ("bsp", "asp", "ssp", "dssp")
 ESTIMATORS = ("last", "ema", "median")
-SERVER_KINDS = ("none", "mono", "sharded")
-APPLY_MODES = ("tree", "fused", "packed")
+SERVER_KINDS = ("none", "sharded")
+APPLY_MODES = ("fused",)
 GATING_MODES = ("sharded", "global")
-WIRE_FORMATS = ("tree", "packed")
+WIRE_FORMATS = ("packed",)
 WIRE_COMPRESSIONS = ("none", "int8", "topk")
 TRANSPORT_KINDS = ("inproc", "tcp", "shmem")
 
@@ -52,9 +51,11 @@ def _require(cond: bool, msg: str) -> None:
         raise SpecError(msg)
 
 
-def _choice(value: str, field: str, choices) -> None:
+def _choice(value: str, field: str, choices, removed: str = "") -> None:
+    """``removed`` names what replaces the values earlier specs took."""
     _require(value in choices,
-             f"{field}={value!r} is not one of {list(choices)}")
+             f"{field}={value!r} is not one of {list(choices)}"
+             + (f"; {removed}" if removed else ""))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,16 +161,17 @@ class ServerSpec:
     """Where the global weights live.
 
     ``kind='none'``    SPMD delayed-gradient pipeline (no server).
-    ``kind='mono'``    monolithic ``ParameterServer`` (one lock);
-                       ``apply`` in {tree, packed}.
     ``kind='sharded'`` ``ShardedParameterServer`` with ``shards``
-                       partitions; ``apply`` in {tree, fused}.
+                       partitions: params and momentum resident in the
+                       packed wire layout, every push folded through the
+                       fused Pallas momentum apply (``apply='fused'``,
+                       the one apply there is).
     """
 
     kind: str = "none"
     shards: int = 0
     workers: int = 4
-    apply: str = "tree"
+    apply: str = "fused"
     gating: str = "sharded"
     straggler: float = 1.0
     #: Coalescing window: up to this many concurrent workers' packed
@@ -183,8 +185,12 @@ class ServerSpec:
     coalesce_wait_ms: Optional[float] = None
 
     def __post_init__(self):
-        _choice(self.kind, "ps.kind", SERVER_KINDS)
-        _choice(self.apply, "ps.apply", APPLY_MODES)
+        _choice(self.kind, "ps.kind", SERVER_KINDS,
+                "the monolithic server ('mono') was removed — use "
+                "ps.kind='sharded' with ps.shards=1")
+        _choice(self.apply, "ps.apply", APPLY_MODES,
+                "the 'tree' and 'packed' applies were removed — the "
+                "fused Pallas apply over the packed store replaces both")
         _choice(self.gating, "ps.gating", GATING_MODES)
         _require(self.workers >= 1, "ps.workers must be >= 1")
         _require(self.straggler >= 1.0,
@@ -200,50 +206,36 @@ class ServerSpec:
             _require(self.shards == 0,
                      "ps.kind='none' (SPMD pipeline) takes ps.shards=0; "
                      "to shard a parameter server use ps.kind='sharded'")
-            _require(self.apply == "tree",
-                     "ps.apply selects a server apply path; the SPMD "
-                     "pipeline (ps.kind='none') has none — leave it "
-                     "'tree'")
             _require(self.coalesce == 1,
                      "ps.coalesce batches server-side applies; the SPMD "
                      "pipeline (ps.kind='none') has no server — set "
-                     "ps.kind='mono'/'sharded' or leave ps.coalesce=1")
-        elif self.kind == "mono":
-            _require(self.shards in (0, 1),
-                     "the monolithic server is one shard by definition "
-                     f"(ps.shards={self.shards}); use ps.kind='sharded' "
-                     "to partition")
-            _require(self.apply != "fused",
-                     "ps.apply='fused' is the sharded server's batched "
-                     "apply; the monolithic server's packed path is "
-                     "ps.apply='packed' (or use ps.kind='sharded')")
-        else:  # sharded
+                     "ps.kind='sharded' or leave ps.coalesce=1")
+            _require(self.gating == "sharded",
+                     "ps.gating='global' gates the sharded server once "
+                     "per push; the SPMD pipeline (ps.kind='none') has "
+                     "no server to gate")
+        else:
             _require(self.shards >= 1,
                      "ps.kind='sharded' needs ps.shards >= 1")
-            _require(self.apply != "packed",
-                     "ps.apply='packed' is the monolithic server's "
-                     "resident-wire mode; the sharded equivalent is "
-                     "ps.apply='fused'")
-        _require(self.gating == "sharded" or self.kind == "sharded",
-                 "ps.gating='global' only applies to the sharded "
-                 "server (it is the monolithic gating semantics)")
 
 
 @dataclasses.dataclass(frozen=True)
 class WireSpec:
-    """Push/pull representation: per-leaf pytrees or the zero-repack
-    packed (rows, 512) buffer, plus gradient compression."""
+    """Push/pull representation: the packed (rows, 512) buffer (the one
+    format there is), plus gradient compression."""
 
-    format: str = "tree"
+    format: str = "packed"
     compression: str = "none"
     topk_fraction: float = 0.05
     #: Version-delta pulls: workers track the server's per-shard
     #: version vector and pull only the shard regions that advanced
-    #: (full-snapshot fallback on mismatch).  Packed wire only.
+    #: (full-snapshot fallback on mismatch).
     delta_pull: bool = False
 
     def __post_init__(self):
-        _choice(self.format, "wire.format", WIRE_FORMATS)
+        _choice(self.format, "wire.format", WIRE_FORMATS,
+                "the per-leaf 'tree' wire was removed — every push and "
+                "pull speaks the packed (rows, 512) buffer")
         _choice(self.compression, "wire.compression", WIRE_COMPRESSIONS)
         _require(0.0 < self.topk_fraction <= 1.0,
                  "wire.topk_fraction must be in (0, 1]")
@@ -484,28 +476,21 @@ class RunSpec:
 
     Cross-field rules (each raises ``SpecError`` at construction):
 
-    * process transports (tcp/shmem) and in-process endpoints carry the
-      packed wire format only — ``wire.format='tree'`` is rejected;
-    * the packed wire needs a packed-resident store — ``ps.apply`` must
-      be ``'packed'`` (mono) or ``'fused'`` (sharded);
     * the SPMD pipeline (``ps.kind='none'``) trains bsp/ssp/dssp only
-      (ASP exists in the PS layer) and has no packed wire;
-    * process transports need a parameter server and a registry arch
-      (spawned workers rebuild the model from its config name);
-    * compression needs an engine with a compression path (SPMD or the
-      sharded server);
-    * ``wire.delta_pull`` (version-delta pulls) and ``ps.coalesce > 1``
-      (batched server apply) ride the packed wire only — over the tree
-      wire both raise;
-    * ``ft`` snapshots capture the packed-resident store, so they need
-      a parameter server with ``ps.apply='fused'``/``'packed'``; the
+      (ASP exists in the PS layer) and has no PS workers, so no
+      process transport, endpoint or version-delta pull;
+    * ``ft`` snapshots capture a parameter server's packed store; the
       ``FaultPlan`` kills/drops cross a process boundary, so faults and
       worker reconnect need a process transport (and killing/restarting
       the server needs tcp — shmem segments die with their owner);
+    * live resharding resyncs clients through the delta-pull fallback
+      over the frame protocol: it needs the sharded server,
+      ``wire.delta_pull=true`` and a process transport;
     * ``serve.replicas > 0`` rides the delta-pull protocol: it needs a
-      parameter server, the packed wire with ``wire.delta_pull=true``,
-      and a registry arch (replicas rebuild the decode path from the
-      config name — ``'custom'`` cannot cross the spawn boundary).
+      parameter server, ``wire.delta_pull=true``, and a registry arch
+      (replicas rebuild the decode path from the config name —
+      ``'custom'`` cannot cross the spawn boundary);
+    * the parameter server steps SGD/momentum only.
     """
 
     model: ModelSpec = dataclasses.field(default_factory=ModelSpec)
@@ -528,11 +513,7 @@ class RunSpec:
             _require(ps.kind != "none",
                      "serve.replicas subscribe to a live parameter "
                      "server; the SPMD pipeline (ps.kind='none') has "
-                     "none — set ps.kind='mono'/'sharded'")
-            _require(wire.format == "packed",
-                     "serving replicas keep a resident packed buffer; "
-                     "set wire.format='packed' (and ps.apply='fused'/"
-                     "'packed')")
+                     "none — set ps.kind='sharded'")
             _require(wire.delta_pull,
                      "serving replicas refresh via version-delta pulls "
                      "(bytes proportional to change — the high-"
@@ -546,22 +527,15 @@ class RunSpec:
             _require(ps.kind != "none",
                      "ft snapshots checkpoint a parameter server's "
                      "packed store; the SPMD pipeline (ps.kind='none') "
-                     "has its own checkpointing — set ps.kind='mono'/"
-                     "'sharded'")
-            _require(ps.apply in ("fused", "packed"),
-                     "ft snapshots capture the packed-resident store; "
-                     "ps.apply='tree' keeps no packed buffers to "
-                     "snapshot — set ps.apply='fused' (sharded) or "
-                     "'packed' (mono)")
+                     "has its own checkpointing — set ps.kind='sharded'")
         if ft.reshards:
-            _require(ps.kind == "sharded" and ps.apply == "fused",
+            _require(ps.kind == "sharded",
                      "ft.reshard_* migrates packed regions between the "
-                     "sharded server's stores; set ps.kind='sharded' "
-                     "and ps.apply='fused'")
-            _require(wire.format == "packed" and wire.delta_pull,
+                     "sharded server's stores; set ps.kind='sharded'")
+            _require(wire.delta_pull,
                      "live resharding resyncs clients through the "
-                     "version-delta full-pull fallback; set wire."
-                     "format='packed' and wire.delta_pull=true")
+                     "version-delta full-pull fallback; set "
+                     "wire.delta_pull=true")
             _require(tp.kind in ("tcp", "shmem"),
                      "live resharding changes the wire layout under "
                      "running workers, which only the frame protocol "
@@ -588,49 +562,17 @@ class RunSpec:
             _require(sync.mode != "asp",
                      "sync.mode='asp' is not trainable on the SPMD "
                      "pipeline (ps.kind='none'); use a parameter server "
-                     "(ps.kind='mono'/'sharded')")
-            _require(wire.format == "tree",
-                     "wire.format='packed' is the parameter-server hot "
-                     "path; the SPMD pipeline has no wire — set "
-                     "ps.kind='mono'/'sharded' or wire.format='tree'")
+                     "(ps.kind='sharded')")
             _require(tp.kind == "inproc" and not tp.endpoint,
                      f"transport.kind={tp.kind!r} moves PS workers into "
                      "separate processes; the SPMD pipeline "
                      "(ps.kind='none') has no PS workers — set "
-                     "ps.kind='sharded' (or 'mono') to use a transport")
-        if wire.format == "packed":
-            _require(ps.apply in ("fused", "packed"),
-                     "wire.format='packed' needs a packed-resident "
-                     "store: ps.apply='packed' (mono) or 'fused' "
-                     "(sharded); ps.apply='tree' re-packs every push")
-        if wire.delta_pull:
-            _require(wire.format == "packed",
+                     "ps.kind='sharded' to use a transport")
+            _require(not wire.delta_pull,
                      "wire.delta_pull serves version-delta pulls of the "
-                     "packed snapshot; the tree wire has no per-shard "
-                     "version vector to diff against — set wire.format="
-                     "'packed' (and ps.apply='fused'/'packed')")
-        if ps.coalesce > 1:
-            _require(wire.format == "packed",
-                     "ps.coalesce batches packed wire buffers through "
-                     "one fused launch; the tree wire has nothing to "
-                     "stack — set wire.format='packed' (and ps.apply="
-                     "'fused'/'packed')")
-        if tp.serves_endpoint:
-            _require(wire.format == "packed",
-                     f"transport.kind={tp.kind!r} carries the packed "
-                     "frame protocol only — wire.format='tree' cannot "
-                     "cross a process boundary; set wire.format="
-                     "'packed' (and ps.apply='fused'/'packed')")
-        if tp.kind != "inproc":
-            _require(ps.kind != "none",
-                     "process transports live in the PS layer; set "
-                     "ps.kind='mono' or 'sharded'")
-        if wire.compression != "none":
-            _require(ps.kind != "mono",
-                     f"wire.compression={wire.compression!r} has no "
-                     "monolithic-server path; use ps.kind='sharded' "
-                     "(fused wire compression) or ps.kind='none' "
-                     "(worker-side error feedback)")
+                     "server's packed snapshot; the SPMD pipeline "
+                     "(ps.kind='none') has no server — set "
+                     "ps.kind='sharded'")
         if ps.kind != "none" and self.optimizer.name is not None:
             _require(self.optimizer.name in ("sgd", "momentum"),
                      f"optimizer.name={self.optimizer.name!r}: the "

@@ -2,9 +2,7 @@
 
 A snapshot is everything a restarted server needs to carry on mid-run:
 
-  * the resident packed per-shard parameter + momentum buffers
-    (``apply_mode='fused'`` on the sharded server, ``'packed'`` on the
-    monolithic one — tree mode has no resident store and is rejected),
+  * the resident packed per-shard parameter + momentum buffers,
   * the per-shard version vector (what version-delta pulls diff
     against: a restored server resumes *behind* any worker's last-seen
     vector, so the component-wise dominance rule in ``pull_delta``
@@ -141,14 +139,6 @@ def _restore_metrics(m, state: Dict[str, Any]) -> None:
 # ===================================================================
 # capture
 # ===================================================================
-def _require_packed(server) -> None:
-    if not getattr(server, "packed_wire", False):
-        raise ValueError(
-            "server snapshots capture the resident packed store; "
-            f"apply_mode={getattr(server, 'apply_mode', None)!r} has "
-            "none (use ps.apply='fused' or 'packed')")
-
-
 def snapshot_server(server) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """Capture ``(tree, extras)``: the array tree for the
     ``CheckpointManager`` plus the JSON-able bookkeeping.
@@ -160,7 +150,6 @@ def snapshot_server(server) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     version: exactly the per-shard consistency the partitioned server
     offers its own pulls.
     """
-    _require_packed(server)
     t0 = TRACE.now() if TRACE.enabled else 0.0
     for _attempt in range(8):
         out = _snapshot_once(server)
@@ -180,58 +169,40 @@ def _snapshot_once(server):
     """One capture attempt; ``None`` when a live reshard swapped the
     shard list mid-capture (the caller retries — mixing regions from
     two plans in one snapshot would be a torn, unrestorable state)."""
-    epoch0 = int(getattr(server, "reshard_epoch", 0))
+    epoch0 = int(server.reshard_epoch)
     tree: Dict[str, Any] = {}
     versions: List[int] = []
     shard_states: List[Dict[str, Any]] = []
-    shards = getattr(server, "shards", None)
-    if shards is not None:                       # ShardedParameterServer
-        kind = "sharded"
-        for st in shards:
-            with st.cond:
-                # Span starts AFTER acquisition: it measures the lock
-                # HOLD (the pause imposed on that shard's pushes), not
-                # time spent queueing behind an in-flight apply.
-                ts = TRACE.now() if TRACE.enabled else 0.0
-                p, m = st._packed_p, st._packed_m
-                version = st.version
-                trk = _tracker_state(st.tracker)
-                pol = capture_policy_state(st.policy)
-            if TRACE.enabled:
-                TRACE.span("snapshot_shard", ts, shard=st.index)
-            tree[f"shard{st.index:03d}"] = {"p": p, "m": m}
-            versions.append(version)
-            shard_states.append({"tracker": trk, "policy": pol})
-        gate = None
-        if server.gating == "global":
-            with server._gate_cond:
-                gate = {"tracker": _tracker_state(server._gate_tracker),
-                        "policy": capture_policy_state(server._gate_policy)}
-        with server._metrics_lock:
-            metrics = _metrics_state(server.metrics)
-        gating = server.gating
-    else:                                        # mono ParameterServer
-        kind = "mono"
-        with server._cond:
+    shards = server.shards
+    for st in shards:
+        with st.cond:
+            # Span starts AFTER acquisition: it measures the lock
+            # HOLD (the pause imposed on that shard's pushes), not
+            # time spent queueing behind an in-flight apply.
             ts = TRACE.now() if TRACE.enabled else 0.0
-            p, m = server._wire_p, server._wire_m
-            versions.append(server.version)
-            shard_states.append(
-                {"tracker": _tracker_state(server.tracker),
-                 "policy": capture_policy_state(server.policy)})
-            metrics = _metrics_state(server.metrics)
+            p, m = st._packed_p, st._packed_m
+            version = st.version
+            trk = _tracker_state(st.tracker)
+            pol = capture_policy_state(st.policy)
         if TRACE.enabled:
-            TRACE.span("snapshot_shard", ts, shard=0)
-        tree["shard000"] = {"p": p, "m": m}
-        gate, gating = None, "mono"
-    if int(getattr(server, "reshard_epoch", 0)) != epoch0:
+            TRACE.span("snapshot_shard", ts, shard=st.index)
+        tree[f"shard{st.index:03d}"] = {"p": p, "m": m}
+        versions.append(version)
+        shard_states.append({"tracker": trk, "policy": pol})
+    gate = None
+    if server.gating == "global":
+        with server._gate_cond:
+            gate = {"tracker": _tracker_state(server._gate_tracker),
+                    "policy": capture_policy_state(server._gate_policy)}
+    with server._metrics_lock:
+        metrics = _metrics_state(server.metrics)
+    if int(server.reshard_epoch) != epoch0:
         return None  # a reshard swapped plans mid-capture: retry
-    opt = (shards[0].optimizer if shards is not None
-           else server.optimizer)
+    opt = shards[0].optimizer
     extras = {
         "snapshot_version": SNAPSHOT_VERSION,
-        "kind": kind,
-        "gating": gating,
+        "kind": "sharded",
+        "gating": server.gating,
         "n_shards": len(versions),
         "versions": versions,
         "shards": shard_states,
@@ -283,77 +254,50 @@ def restore_server(server, tree: Dict[str, Any],
                    extras: Dict[str, Any]) -> None:
     """Install a captured snapshot into a freshly-built server of the
     same spec.  Per-shard installs run under each shard's lock and
-    notify waiters; caches keyed by version (packed-snapshot cache,
-    unpacked-piece cache) are invalidated."""
-    _require_packed(server)
+    notify waiters; the version-keyed packed-snapshot cache is
+    invalidated."""
     import jax.numpy as jnp
     ver = extras.get("snapshot_version")
     if ver != SNAPSHOT_VERSION:
         raise ValueError(f"snapshot version {ver!r} != supported "
                          f"{SNAPSHOT_VERSION}")
-    shards = getattr(server, "shards", None)
-    n = len(shards) if shards is not None else 1
-    if extras["n_shards"] != n:
+    if extras["kind"] != "sharded":
+        raise ValueError(f"snapshot kind {extras['kind']!r} cannot "
+                         "restore into the sharded server")
+    if extras["n_shards"] != len(server.shards):
         # Cross-plan restore: the snapshot was taken under a different
         # shard arity (a live reshard ran before — or after — the
-        # capture).  A resharding-capable server is moved to the
-        # snapshot's arity FIRST (which also installs the migration
-        # map old->new, so stale-epoch client pushes keep translating);
-        # then the install below proceeds shard-for-shard.
-        if shards is None or not hasattr(server, "reshard"):
-            raise ValueError(
-                f"snapshot has {extras['n_shards']} shard(s), server "
-                f"has {n} and cannot reshard — restore needs the same "
-                "RunSpec the snapshot came from")
+        # capture).  The server is moved to the snapshot's arity FIRST
+        # (which also installs the migration map old->new, so
+        # stale-epoch client pushes keep translating); then the install
+        # below proceeds shard-for-shard.
         server.reshard(extras["n_shards"])
-        shards = server.shards
-        n = len(shards)
+    shards = server.shards
     versions = [int(v) for v in extras["versions"]]
     states = extras["shards"]
-    if shards is not None:
-        if extras["kind"] != "sharded":
-            raise ValueError(f"snapshot kind {extras['kind']!r} cannot "
-                             "restore into a sharded server")
-        for st in shards:
-            blob = tree[f"shard{st.index:03d}"]
-            with st.cond:
-                st._packed_p = jnp.asarray(blob["p"])
-                st._packed_m = jnp.asarray(blob["m"])
-                st._pieces = None
-                st.version = versions[st.index]
-                _restore_tracker(st.tracker, states[st.index]["tracker"])
-                restore_policy_state(st.policy,
-                                     states[st.index]["policy"])
-                st.metrics.n_workers = len(st.tracker.workers)
-                st.cond.notify_all()
-        _equalize_counts(shards)
-        if extras.get("gate") and server.gating == "global":
-            with server._gate_cond:
-                _restore_tracker(server._gate_tracker,
-                                 extras["gate"]["tracker"])
-                restore_policy_state(server._gate_policy,
-                                     extras["gate"]["policy"])
-                server._gate_cond.notify_all()
-        with server._snap_lock:
-            server._snap_key = server._snap_wire = None
-        with server._metrics_lock:
-            _restore_metrics(server.metrics, extras["metrics"])
-            server.metrics.n_workers = len(shards[0].tracker.workers)
-    else:
-        if extras["kind"] != "mono":
-            raise ValueError(f"snapshot kind {extras['kind']!r} cannot "
-                             "restore into a monolithic server")
-        blob = tree["shard000"]
-        with server._cond:
-            server._wire_p = jnp.asarray(blob["p"])
-            server._wire_m = jnp.asarray(blob["m"])
-            server._params = None
-            server.version = versions[0]
-            _restore_tracker(server.tracker, states[0]["tracker"])
-            restore_policy_state(server.policy, states[0]["policy"])
-            _restore_metrics(server.metrics, extras["metrics"])
-            server.metrics.n_workers = len(server.tracker.workers)
-            server._cond.notify_all()
+    for st in shards:
+        blob = tree[f"shard{st.index:03d}"]
+        with st.cond:
+            st._packed_p = jnp.asarray(blob["p"])
+            st._packed_m = jnp.asarray(blob["m"])
+            st.version = versions[st.index]
+            _restore_tracker(st.tracker, states[st.index]["tracker"])
+            restore_policy_state(st.policy, states[st.index]["policy"])
+            st.metrics.n_workers = len(st.tracker.workers)
+            st.cond.notify_all()
+    _equalize_counts(shards)
+    if extras.get("gate") and server.gating == "global":
+        with server._gate_cond:
+            _restore_tracker(server._gate_tracker,
+                             extras["gate"]["tracker"])
+            restore_policy_state(server._gate_policy,
+                                 extras["gate"]["policy"])
+            server._gate_cond.notify_all()
+    with server._snap_lock:
+        server._snap_key = server._snap_wire = None
+    with server._metrics_lock:
+        _restore_metrics(server.metrics, extras["metrics"])
+        server.metrics.n_workers = len(shards[0].tracker.workers)
 
 
 def restore_latest(server, manager) -> Optional[int]:
@@ -362,10 +306,10 @@ def restore_latest(server, manager) -> Optional[int]:
     ``None`` when the directory holds no (complete) snapshot.
 
     Cross-plan aware: when the snapshot was captured at a different
-    shard arity (it straddles a live reshard), a resharding-capable
-    server is moved to the snapshot's arity BEFORE the template tree is
-    built, so the shape validation in ``CheckpointManager.restore``
-    sees matching region buffers.  The run then resumes under exactly
+    shard arity (it straddles a live reshard), the server is moved to
+    the snapshot's arity BEFORE the template tree is built, so the
+    shape validation in ``CheckpointManager.restore`` sees matching
+    region buffers.  The run then resumes under exactly
     the plan the snapshot recorded — never a torn mixture."""
     t0 = TRACE.now() if TRACE.enabled else 0.0
     step = manager.latest_step()
@@ -373,9 +317,7 @@ def restore_latest(server, manager) -> Optional[int]:
         return None
     peek = manager.peek_extras(step)
     want = int(peek.get("n_shards", 0))
-    shards = getattr(server, "shards", None)
-    if (shards is not None and want and want != len(shards)
-            and hasattr(server, "reshard")):
+    if want and want != len(server.shards):
         server.reshard(want)
     like, _ = snapshot_server(server)
     tree, extras = manager.restore(step, like)

@@ -16,8 +16,8 @@ Two independent elasticity paths live in this repo, one per engine:
 
 **Parameter-server layer**: elasticity has two axes —
   * *worker membership* is handled in-place: workers join/leave the
-    per-shard staleness trackers at runtime (``ps/server.py``,
-    ``add_worker``/``remove_worker``) and the barrier gate re-derives
+    per-shard staleness trackers at runtime
+    (``ps/sharded/server.py``, ``add_worker``/``remove_worker``) and the barrier gate re-derives
     its group from the live membership;
   * *shard arity* is handled by **live resharding**
     (``repro.ft.reshard`` + ``ShardedParameterServer.reshard``): the

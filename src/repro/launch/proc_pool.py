@@ -73,14 +73,9 @@ class WorkerTask:
         Only the int8 compression rides the frames (bytes shrink on the
         OS wire; the codec dequantizes on receipt) — topk has no
         frame-level encoding and stays a server-side pass.
-
-        ``n_shards`` is clamped to >= 1: a monolithic spec may carry
-        ``ps.shards=0`` (the ServerSpec default), but the worker-side
-        ``build_shard_plan`` — and the mono server's own packed plan —
-        are single-shard.
         """
         return cls(arch=spec.model.arch,
-                   n_shards=max(1, spec.ps.shards),
+                   n_shards=spec.ps.shards,
                    n_iterations=n_iterations,
                    smoke=spec.model.smoke,
                    kernels=spec.model.kernels,

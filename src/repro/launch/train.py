@@ -207,9 +207,9 @@ class Trainer:
 def train_ps(cfg, data_cfg: DataConfig, *, sync: str, n_steps: int,
              lr: float, n_shards: int, n_workers: int = 4,
              s_lower: int = 0, s_upper: int = 3,
-             compressor: str = "none", apply_mode: str = "tree",
+             compressor: str = "none",
              gating: str = "sharded", straggler: float = 1.0,
-             wire_format: str = "tree", transport: str = "inproc",
+             transport: str = "inproc",
              arch: Optional[str] = None, smoke: bool = True,
              verbose: bool = False):
     """Deprecated shim over ``repro.api.build_session``.
@@ -235,8 +235,8 @@ def train_ps(cfg, data_cfg: DataConfig, *, sync: str, n_steps: int,
         seq=data_cfg.seq_len, batch=data_cfg.global_batch,
         seed=data_cfg.seed, lr=lr, s_lower=s_lower, s_upper=s_upper,
         compress=compressor, ps_shards=max(1, n_shards),
-        ps_workers=n_workers, ps_apply=apply_mode, ps_wire=wire_format,
-        ps_gating=gating, ps_straggler=straggler, transport=transport)
+        ps_workers=n_workers, ps_gating=gating, ps_straggler=straggler,
+        transport=transport)
     session = api.build_session(spec, verbose=verbose)
     session.run(n_steps)
     return session.server
@@ -250,28 +250,24 @@ def spec_from_flags(*, arch: str, smoke: bool = True, sync: str = "dssp",
                     optimizer: Optional[str] = None,
                     s_lower: int = 0, s_upper: int = 3,
                     compress: str = "none", ps_shards: int = 0,
-                    ps_workers: int = 4, ps_apply: str = "tree",
-                    ps_wire: str = "tree", ps_gating: str = "sharded",
+                    ps_workers: int = 4, ps_gating: str = "sharded",
                     ps_straggler: float = 1.0, ps_coalesce: int = 1,
                     delta_pull: bool = False,
                     transport: str = "inproc",
                     trace_path: str = "",
                     ckpt_dir: str = "", snapshot_every: float = 5.0,
                     resume: bool = False):
-    """Translate the historical CLI flag surface into a ``RunSpec``.
+    """Translate the CLI flag surface into a ``RunSpec``.
 
-    Keeps the old implication chain (`--transport tcp` implies the
-    packed wire; packed wire implies the fused apply; process
-    transports imply `--ps-shards 1`) so every flag combination that
-    used to run still runs — the spec layer itself is stricter and
-    rejects the un-implied combinations outright.
+    Keeps the old implication (process transports imply `--ps-shards
+    1`) so every flag combination that used to run still runs — the
+    spec layer itself is stricter and rejects the un-implied
+    combinations outright.
     """
     from repro import api
 
     if transport != "inproc" and ps_shards < 1:
         ps_shards = 1          # process transports live in the PS layer
-    if transport != "inproc":
-        ps_wire = "packed"     # frames carry the packed buffer only
     if (ps_coalesce > 1 or delta_pull) and ps_shards < 1:
         # No implication here: silently switching the SPMD pipeline to
         # a parameter server (or dropping the knob) would train a
@@ -280,20 +276,13 @@ def spec_from_flags(*, arch: str, smoke: bool = True, sync: str = "dssp",
             "--ps-coalesce/--delta-pull act on the parameter server's "
             "packed hot path; the SPMD pipeline has no server — add "
             "--ps-shards N (or --transport tcp/shmem)")
-    if ps_coalesce > 1 or delta_pull:
-        ps_wire = "packed"     # both knobs ride the packed wire
-    if ps_wire == "packed" and ps_apply == "tree":
-        ps_apply = "fused"     # packed pushes fold through the kernel
-    if ckpt_dir and ps_shards >= 1 and ps_apply == "tree":
-        ps_apply = "fused"     # snapshots capture the packed store
     ft = (api.FtSpec(snapshot_every_s=snapshot_every, dir=ckpt_dir,
                      resume=resume)
           if ckpt_dir and ps_shards >= 1 else api.FtSpec())
     if ps_shards >= 1:
         ps = api.ServerSpec(kind="sharded", shards=ps_shards,
-                            workers=ps_workers, apply=ps_apply,
-                            gating=ps_gating, straggler=ps_straggler,
-                            coalesce=ps_coalesce)
+                            workers=ps_workers, gating=ps_gating,
+                            straggler=ps_straggler, coalesce=ps_coalesce)
         opt = api.OptimizerSpec(lr=lr)
     else:
         ps = api.ServerSpec(kind="none", shards=0, workers=ps_workers)
@@ -305,8 +294,7 @@ def spec_from_flags(*, arch: str, smoke: bool = True, sync: str = "dssp",
         sync=api.SyncSpec(mode=sync, staleness=max(s_lower, 1),
                           s_lower=s_lower, s_upper=s_upper),
         ps=ps,
-        wire=api.WireSpec(format=ps_wire if ps_shards >= 1 else "tree",
-                          compression=compress,
+        wire=api.WireSpec(compression=compress,
                           delta_pull=delta_pull and ps_shards >= 1),
         transport=api.TransportSpec(kind=transport),
         obs=api.ObsSpec(trace=bool(trace_path), trace_path=trace_path),
@@ -369,16 +357,6 @@ def main() -> None:
                     help="train through a sharded threaded parameter "
                          "server with N shards (0 = SPMD pipeline path)")
     ap.add_argument("--ps-workers", type=int, default=4)
-    ap.add_argument("--ps-apply", default="tree", choices=["tree", "fused"],
-                    help="per-shard apply: tree_map or one fused Pallas "
-                         "launch over the packed shard (fused runs in "
-                         "interpret mode on CPU — correctness validation "
-                         "only; native speed needs TPU)")
-    ap.add_argument("--ps-wire", default="tree", choices=["tree", "packed"],
-                    help="push/pull wire format: per-leaf pytrees, or the "
-                         "zero-repack packed (rows, 512) buffer (packed "
-                         "implies --ps-apply fused; --compress becomes the "
-                         "fused wire compression)")
     ap.add_argument("--ps-gating", default="sharded",
                     choices=["sharded", "global"])
     ap.add_argument("--ps-straggler", type=float, default=1.0,
@@ -386,12 +364,12 @@ def main() -> None:
     ap.add_argument("--ps-coalesce", type=int, default=1, metavar="K",
                     help="coalescing window: fold up to K concurrent "
                          "workers' packed pushes through ONE batched "
-                         "kernel launch per shard (implies --ps-wire "
-                         "packed; 1 = one launch per push)")
+                         "kernel launch per shard (1 = one launch per "
+                         "push)")
     ap.add_argument("--delta-pull", action="store_true",
                     help="version-delta pulls: workers pull only the "
                          "shard regions that advanced since their last "
-                         "pull (implies --ps-wire packed)")
+                         "pull")
     ap.add_argument("--trace", default="", metavar="PATH",
                     help="record a run-wide trace (repro.obs) and write "
                          "it here on exit: .jsonl = raw event lines, "
@@ -402,8 +380,7 @@ def main() -> None:
                     help="PS worker isolation: inproc = threads sharing "
                          "the heap (the classic path); tcp/shmem = spawned "
                          "worker PROCESSES pushing packed frames over a "
-                         "real wire (implies --ps-wire packed; enables "
-                         "--ps-shards 1 if unset)")
+                         "real wire (enables --ps-shards 1 if unset)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -425,8 +402,6 @@ def main() -> None:
             ("--compress", "none", args.compress),
             ("--ps-shards", 0, args.ps_shards),
             ("--ps-workers", 4, args.ps_workers),
-            ("--ps-apply", "tree", args.ps_apply),
-            ("--ps-wire", "tree", args.ps_wire),
             ("--ps-gating", "sharded", args.ps_gating),
             ("--ps-straggler", 1.0, args.ps_straggler),
             ("--ps-coalesce", 1, args.ps_coalesce),
@@ -449,7 +424,6 @@ def main() -> None:
             optimizer=args.optimizer, s_lower=args.s_lower,
             s_upper=args.s_upper, compress=args.compress,
             ps_shards=args.ps_shards, ps_workers=args.ps_workers,
-            ps_apply=args.ps_apply, ps_wire=args.ps_wire,
             ps_gating=args.ps_gating, ps_straggler=args.ps_straggler,
             ps_coalesce=args.ps_coalesce, delta_pull=args.delta_pull,
             transport=args.transport, trace_path=args.trace,

@@ -1,9 +1,10 @@
-"""Parameter-server substrate: discrete-event simulator + threaded runtime
-(monolithic in ``server.py``/``simulator.py``, partitioned in ``sharded/``)."""
+"""Parameter-server substrate: the threaded sharded server and its
+workers (``sharded/``, ``worker.py``, ``step.py``) and the
+discrete-event simulators (``simulator.py``, ``sharded/simulator.py``)."""
 
 from repro.ps.metrics import RunMetrics, compare
-from repro.ps.server import ParameterServer, ServerOptimizer
 from repro.ps.sharded import (
+    ServerOptimizer,
     ShardedParameterServer,
     ShardedPSSimulator,
     ShardPlan,
@@ -17,10 +18,12 @@ from repro.ps.simulator import (
     phase_shift_intervals,
     run_policy,
 )
+from repro.ps.step import loss_step, packed_step
 from repro.ps.worker import PSWorker, run_cluster
 
 __all__ = [
-    "ParameterServer", "ServerOptimizer", "PSWorker", "run_cluster",
+    "ServerOptimizer", "PSWorker", "run_cluster", "packed_step",
+    "loss_step",
     "PSSimulator", "run_policy", "constant_intervals",
     "jittered_intervals", "phase_shift_intervals",
     "RunMetrics", "compare",

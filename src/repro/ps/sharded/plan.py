@@ -17,15 +17,15 @@ which pytree leaves live on which shard.  The plan format (also in
   * within a shard, slices are kept sorted by ``(leaf, start)`` so the
     shard's wire layout is deterministic and reproducible across runs.
 
-The plan is pure metadata: ``split`` / ``assemble`` do the actual data
-movement (slicing on push, ``jnp.concatenate`` on pull) and are each
-other's inverse for any tree matching the plan's structure.
+The plan is pure metadata: ``split`` / ``assemble`` cut a tree into
+per-shard piece lists and join them back (each other's inverse for any
+tree matching the plan's structure) — what building the shard stores,
+the live reshard and ``snapshot()`` use.
 
 Packed wire format
 ------------------
-``split``/``assemble`` are the *tree* wire format: per-shard lists of
-arrays, one host-side op per piece.  The *packed* wire format makes the
-lane-aligned ``(rows, 512)`` buffer the native representation instead:
+Every push and pull speaks the *packed* wire format: the lane-aligned
+``(rows, 512)`` buffer is the native representation, not a piece list:
 the whole tree lives in ONE flat buffer laid out shard-by-shard (each
 shard's slices contiguous in ``(leaf, start)`` order, each shard region
 zero-padded to a multiple of 8 rows so a Pallas ``(8, 512)`` tile grid
@@ -309,7 +309,7 @@ class ShardPlan:
 
     def shard_pieces_from_wire(self, buf: jax.Array, shard: int,
                                dtype=None) -> List[jax.Array]:
-        """One shard's piece list (tree wire format) out of its packed
+        """One shard's piece list (the ``split`` format) out of its packed
         region — per-slice views, no concatenation."""
         layout = self.wire_layout(dtype)
         WIRE.unpacks += 1

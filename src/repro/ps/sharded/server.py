@@ -1,13 +1,12 @@
-"""Sharded (partitioned) parameter server — per-shard locks and gating.
+"""The parameter server: a partitioned packed store with per-shard gating.
 
-The monolithic ``ParameterServer`` serializes *every* push on one lock
-and one version counter: the exact single-machine bottleneck the PS
-framework exists to avoid.  Here the weight pytree is partitioned by a
-``ShardPlan`` into S size-balanced shards, and every shard owns its own
+The weight pytree is partitioned by a ``ShardPlan`` into S
+size-balanced shards, and every shard owns its own
 
   * lock (condition variable)      — pushes to distinct shards overlap,
   * version counter                — per-shard applied-update count,
-  * ``ServerOptimizer`` state      — momentum lives with its slice,
+  * packed store                   — params + momentum resident in the
+                                     plan's (rows, 512) wire layout,
   * ``SyncPolicy`` + ``StalenessTracker`` — per-shard Algorithm-1 gating,
   * ``RunMetrics``                 — per-shard staleness/wait accounting.
 
@@ -19,42 +18,30 @@ Gating modes
     per-shard credit schedules.  A worker's push returns when the LAST
     shard releases it.
 ``global``  one policy/tracker gates the worker exactly once per push
-    (the monolithic semantics) while the weight store stays partitioned —
-    the ablation that isolates lock-granularity wins from gating wins.
+    while the weight store stays partitioned — the ablation that
+    isolates lock-granularity wins from gating wins.
 
-Wire compression (``optim/compression.py``) runs per shard with
-per-(worker, shard) error-feedback state, emulating worker-side
-compression of each shard RPC.
-
-The apply path is pluggable: ``apply_mode='tree'`` steps the shard's
-piece list through its ``ServerOptimizer`` (bitwise-identical to the
-monolithic server), ``apply_mode='fused'`` keeps params+momentum packed
-in one lane-aligned (rows, 512) buffer and folds the whole shard through
-a single Pallas ``fused_update`` launch per push.
-
-Packed wire format (the zero-repack hot path)
----------------------------------------------
-``push``/``pull`` speak the *tree* wire format: per-leaf arrays, split
-and reassembled on every hop.  ``push_packed``/``pull_packed`` speak the
-plan's packed wire format instead — the worker packs its gradients once
-(inside its jitted step) and every later hop is layout-preserving:
+The packed wire (the zero-repack hot path)
+------------------------------------------
+Workers speak the plan's packed wire format: the worker packs its
+gradients once (inside its jitted step, ``repro.ps.step.packed_step``)
+and every later hop is layout-preserving:
 
   * ``push_packed`` slices the incoming wire buffer into per-shard
     row-range *views* (``ShardPlan.shard_wire``) — zero host-side
     per-leaf concatenations on the server, asserted by the
     ``repro.perfcount`` probes,
   * each shard folds its region straight through ONE ``fused_update``
-    launch (no ``pack_shard`` per push), plus at most one fused
-    compression launch (``wire_compression=``) with per-(worker, shard)
-    error-feedback buffers kept in wire layout,
+    launch, plus at most one fused compression launch
+    (``wire_compression=``) with per-(worker, shard) error-feedback
+    buffers kept in wire layout,
   * ``pull_packed`` serves a version-keyed packed snapshot: per-shard
     buffers are reference-grabbed under their own locks, the full wire
     buffer is concatenated OUTSIDE any lock and cached until some shard
     version moves.
 
-Tree-format ``pull`` in fused mode also rebuilds its per-shard piece
-cache outside the shard lock, so a pull after an apply never stalls
-concurrent pushes to that shard while it unpacks.
+``snapshot()`` (examples, benchmarks, tests) is the one pytree read: it
+unpacks the packed store.
 
 Coalesced apply + version-delta pulls (work ∝ rounds + change)
 --------------------------------------------------------------
@@ -62,9 +49,10 @@ With W workers the paths above still do O(W) kernel launches per round
 per shard and ship the full snapshot on every pull.  Two knobs make
 server work scale with *rounds and changed state* instead:
 
-  * ``coalesce=K`` arms a bounded micro-batching window per shard:
-    contributions that arrive while a flush is in flight (or within a
-    short linger, ``coalesce_wait``) are drained together through ONE
+  * ``coalesce=K`` arms a bounded micro-batching window per shard
+    (``repro.ps.sharded.apply.CoalesceWindow``): contributions that
+    arrive while a flush is in flight (or within a short linger,
+    ``coalesce_wait``) are drained together through ONE
     ``fused_update_batched`` launch — an in-kernel sequential fold, so
     numerics match the uncoalesced path (bitwise for f32 state and for
     any window of one) while launches per round drop from S x W toward
@@ -109,16 +97,14 @@ from repro.api.protocol import DeltaPull, ParameterServerProtocol
 from repro.core.policies import Decision, SyncPolicy
 from repro.core.staleness import StalenessTracker
 from repro.obs.trace import TRACE
-from repro.optim.compression import Compressor
 from repro.perfcount import WIRE
 from repro.ps.metrics import RunMetrics
-from repro.ps.server import (DEFAULT_COALESCE_WAIT_S, CoalesceWindow,
-                             ServerOptimizer)
+from repro.ps.sharded.apply import (DEFAULT_COALESCE_WAIT_S,
+                                    CoalesceWindow, ServerOptimizer)
 from repro.ps.sharded.plan import ShardPlan, build_shard_plan
 from repro.wireformat import WIRE_LANES
 
 Params = Any
-Grads = Any
 
 
 @jax.jit
@@ -157,22 +143,19 @@ class _ShardRegions(collections.abc.Sequence):
 class _ShardState:
     """Everything one shard owns.  All mutation under ``self.cond``."""
 
-    def __init__(self, index: int, plan: ShardPlan,
-                 pieces: List[jax.Array],
-                 policy: SyncPolicy, optimizer: ServerOptimizer,
-                 workers: Sequence[int], apply_mode: str):
+    def __init__(self, index: int, packed_p: jax.Array,
+                 packed_m: jax.Array, version: int, policy: SyncPolicy,
+                 optimizer: ServerOptimizer, workers: Sequence[int]):
         self.index = index
-        self.plan = plan
         self.cond = threading.Condition()
         self.policy = policy
         self.optimizer = optimizer
         self.tracker = StalenessTracker(workers)
         self.metrics = RunMetrics(policy=f"{policy.name}/shard{index}",
                                   n_workers=len(list(workers)))
-        self.version = 0
-        self.apply_mode = apply_mode
-        #: set by the server when coalescing is armed (fused mode):
-        #: the shard's ``CoalesceWindow`` over its packed buffers.
+        self.version = int(version)
+        #: set by the server: the shard's ``CoalesceWindow`` over its
+        #: packed buffers (used when coalescing is armed).
         self.window = None
         #: live-reshard state (see ``ShardedParameterServer.reshard``):
         #: a retired shard parks incoming applies for replay; an
@@ -181,98 +164,36 @@ class _ShardState:
         self.retired = False
         self.abandoned = False
         self.parked: List[Any] = []   # (packed region, staleness) pairs
-        if apply_mode == "fused":
-            # Params + momentum stay resident in the plan's wire layout
-            # (8-row-aligned (rows, 512) region), so an incoming packed
-            # push folds in directly with zero re-packing; unpacked
-            # pieces are a cache rebuilt at most once per version —
-            # OUTSIDE the shard lock (see ``_shard_snapshot``).
-            self._packed_p = plan.pack_shard_pieces(pieces, index)
-            self._packed_m = jnp.zeros_like(self._packed_p)
-            self._pieces: Optional[List[jax.Array]] = list(pieces)
-        else:
-            self._pieces = list(pieces)
+        # Params + momentum stay resident in the plan's wire layout
+        # (8-row-aligned (rows, 512) region), so an incoming packed
+        # push folds in directly with zero re-packing.
+        self._packed_p = packed_p
+        self._packed_m = packed_m
 
-    @classmethod
-    def from_packed(cls, index: int, plan: ShardPlan,
-                    packed_p: jax.Array, packed_m: jax.Array, version: int,
-                    policy: SyncPolicy, optimizer: ServerOptimizer,
-                    workers: Sequence[int]) -> "_ShardState":
-        """A shard state born from migrated packed buffers (fused mode
-        only): what a live reshard installs — params AND momentum carry
-        over bitwise, the version is the redistributed share of the old
-        sum."""
-        st = cls.__new__(cls)
-        st.index = index
-        st.plan = plan
-        st.cond = threading.Condition()
-        st.policy = policy
-        st.optimizer = optimizer
-        st.tracker = StalenessTracker(workers)
-        st.metrics = RunMetrics(policy=f"{policy.name}/shard{index}",
-                                n_workers=len(list(workers)))
-        st.version = int(version)
-        st.apply_mode = "fused"
-        st.window = None
-        st.retired = False
-        st.abandoned = False
-        st.parked = []
-        st._packed_p = packed_p
-        st._packed_m = packed_m
-        st._pieces = None
-        return st
-
-    # -- weight access (call under self.cond) -------------------------------
-    def pieces(self) -> List[jax.Array]:
-        if self._pieces is None:  # fused mode, invalidated by an apply
-            self._pieces = self.plan.shard_pieces_from_wire(
-                self._packed_p, self.index)
-        return self._pieces
-
-    def apply(self, grad_pieces: List[jax.Array], staleness: int) -> None:
-        """Tree-wire apply: one piece list, optimizer step or pack+fold."""
-        if not grad_pieces:
+    def apply_packed(self, wire_g: jax.Array, staleness: int) -> None:
+        """Fold the shard's (rows, 512) gradient region straight through
+        one ``fused_update`` launch — no per-leaf work."""
+        if wire_g.shape[0] == 0:
             # Empty shard (more shards than pieces): the gate/version
             # bookkeeping stays uniform, there is just nothing to fold in
             # (a zero-row pallas_call would reject its (8, 512) tile).
             self.version += 1
             return
-        if self.apply_mode == "fused":
-            self.apply_packed(
-                self.plan.pack_shard_pieces(grad_pieces, self.index),
-                staleness)
-        else:
-            self._pieces = self.optimizer.step(self.pieces(), grad_pieces,
-                                               staleness)
-            self.version += 1
-
-    def apply_packed(self, wire_g: jax.Array, staleness: int) -> None:
-        """Packed-wire apply: fold the shard's (rows, 512) gradient region
-        straight through one ``fused_update`` launch — no per-leaf work.
-        Fused mode only (``push_packed`` guards at the server boundary)."""
-        if wire_g.shape[0] == 0:      # empty shard
-            self.version += 1
-            return
-        # Kernel imports stay local to the fused path so plain
+        # Kernel imports stay local to the apply so plain
         # `import repro.ps` never pulls in the Pallas kernel stack.
         from repro.kernels import ops as kops
         opt = self.optimizer
-        scale = (1.0 / (1.0 + staleness)
-                 if opt.staleness_damping else 1.0)
         self._packed_p, self._packed_m = kops.fused_update(
             self._packed_p, self._packed_m, wire_g,
-            lr=opt.lr, beta=opt.momentum, scale=scale)
-        self._pieces = None
+            lr=opt.lr, beta=opt.momentum, scale=opt.scale(staleness))
         self.version += 1
 
 
 class ShardedParameterServer(ParameterServerProtocol):
-    """Partitioned weight store + per-shard Algorithm-1 gating.
+    """Partitioned packed store + per-shard Algorithm-1 gating.
 
     Implements ``repro.api.protocol.ParameterServerProtocol`` — the
-    same surface as the monolithic ``ParameterServer`` (plus the
-    overridden per-shard variants), so workers, endpoints and sessions
-    drive either server without a type branch.
+    surface workers, endpoints and sessions drive.
     """
 
     def __init__(self, params: Params, policy_factory: Callable[[], SyncPolicy],
@@ -280,8 +201,6 @@ class ShardedParameterServer(ParameterServerProtocol):
                  n_workers: int, n_shards: int, *,
                  split_oversized: bool = True,
                  gating: str = "sharded",
-                 apply_mode: str = "tree",
-                 compressor: Optional[Compressor] = None,
                  wire_compression: Optional[str] = None,
                  topk_fraction: float = 0.05,
                  coalesce: int = 1,
@@ -292,16 +211,11 @@ class ShardedParameterServer(ParameterServerProtocol):
                     "kind='sharded', ...)))")
         if gating not in ("sharded", "global"):
             raise ValueError(f"unknown gating mode {gating!r}")
-        if apply_mode not in ("tree", "fused"):
-            raise ValueError(f"unknown apply mode {apply_mode!r}")
         if wire_compression not in (None, "none", "", "int8", "topk"):
             raise ValueError(
                 f"unknown wire compression {wire_compression!r}")
         if coalesce < 1:
             raise ValueError(f"coalesce window must be >= 1, got {coalesce}")
-        if coalesce > 1 and apply_mode != "fused":
-            raise ValueError("coalesce > 1 batches packed applies; it "
-                             "requires apply_mode='fused'")
         self.coalesce = coalesce
         self.coalesce_wait = (coalesce_wait if coalesce_wait is not None
                               else (DEFAULT_COALESCE_WAIT_S
@@ -310,7 +224,6 @@ class ShardedParameterServer(ParameterServerProtocol):
             params, n_shards, split_oversized=split_oversized)
         self.gating = gating
         self.n_shards = n_shards
-        self.apply_mode = apply_mode
         self._split_oversized = split_oversized
         # Factories are kept so a live reshard can mint policies and
         # optimizer state for the new shard set.
@@ -329,13 +242,12 @@ class ShardedParameterServer(ParameterServerProtocol):
         self._migrations: Dict[int, Any] = {}     # epoch e -> map e -> e+1
         workers = range(n_workers)
         pieces = self.plan.split(params)
-        self.shards: List[_ShardState] = [
-            _ShardState(j, self.plan, pieces[j], policy_factory(),
-                        optimizer_factory(), workers, apply_mode)
-            for j in range(n_shards)]
-        if apply_mode == "fused":
-            for st in self.shards:
-                st.window = self._make_window(st)
+        self.shards: List[_ShardState] = []
+        for j in range(n_shards):
+            packed_p = self.plan.pack_shard_pieces(pieces[j], j)
+            self.shards.append(self._new_shard(
+                j, packed_p, jnp.zeros_like(packed_p), 0, policy_factory(),
+                workers))
         if gating == "global":
             self._gate_policy = policy_factory()
             self._gate_tracker = StalenessTracker(workers)
@@ -344,12 +256,8 @@ class ShardedParameterServer(ParameterServerProtocol):
             policy=f"{self.shards[0].policy.name} xS{n_shards}[{gating}]",
             n_workers=n_workers)
         self._metrics_lock = threading.Lock()
-        self.compressor = (compressor
-                           if compressor is not None
-                           and compressor.name != "none" else None)
-        self._err: Dict[int, List[Any]] = {}   # worker -> per-shard err state
-        # Packed-path fused wire compression: per-(worker, shard) f32
-        # error-feedback buffers, kept in wire layout.
+        # Fused wire compression: per-(worker, shard) f32 error-feedback
+        # buffers, kept in wire layout.
         from repro.optim.compression import make_packed_compressor
         self.wire_compression = make_packed_compressor(
             wire_compression, fraction=topk_fraction)
@@ -386,42 +294,6 @@ class ShardedParameterServer(ParameterServerProtocol):
                 "re-pull to resync")
         return plan, int(epoch)
 
-    def _shard_snapshot(self, st: _ShardState) -> List[jax.Array]:
-        """One shard's piece list, unpacking OUTSIDE the shard lock.
-
-        In fused mode an apply invalidates the piece cache; rebuilding it
-        while holding ``st.cond`` would stall every concurrent push to
-        that shard for the full unpack.  Instead: grab the (immutable)
-        packed buffer + version under the lock, unpack unlocked, and
-        install the cache only if the shard has not moved meanwhile.
-        """
-        with st.cond:
-            if st._pieces is not None:
-                return list(st._pieces)
-            packed, version = st._packed_p, st.version
-        pieces = self.plan.shard_pieces_from_wire(packed, st.index)
-        with st.cond:
-            if st.version == version and st._pieces is None:
-                st._pieces = list(pieces)
-        return pieces
-
-    def pull(self, worker: int) -> Params:
-        """Reassemble the full pytree from per-shard snapshots.
-
-        Each shard is snapshotted under its OWN lock; shards mutated
-        concurrently with the pull may differ in version — exactly the
-        per-shard consistency a partitioned PS offers (each shard's slice
-        is internally consistent; cross-shard skew is bounded by the
-        gating policies).
-        """
-        t0 = TRACE.now() if TRACE.enabled else 0.0
-        plan, shards, _ = self._plan_state()
-        params = plan.assemble(
-            [self._shard_snapshot(st) for st in shards])
-        if TRACE.enabled:
-            TRACE.span("pull", t0, worker=worker)
-        return params
-
     def pull_packed(self, worker: int = -1) -> jax.Array:
         """Full (total_rows, 512) wire snapshot of the parameters.
 
@@ -431,9 +303,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         lock and is cached keyed by the shard-version vector, so pulls
         between applies are a dictionary hit.
         """
-        if self.apply_mode != "fused":
-            raise ValueError("pull_packed requires apply_mode='fused' "
-                             "(tree mode has no resident packed store)")
         t0 = TRACE.now() if TRACE.enabled else 0.0
         _, shards, epoch = self._plan_state()
         snaps, versions = [], []
@@ -483,9 +352,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         """One shard's resident (rows, 512) region — a reference IS a
         consistent snapshot (jax arrays are immutable).  The per-shard
         granularity the transport endpoints route on."""
-        if self.apply_mode != "fused":
-            raise ValueError("pull_packed_shard requires apply_mode='fused' "
-                             "(tree mode has no resident packed store)")
         _, shards, _ = self._plan_state()
         st = shards[shard]
         with st.cond:
@@ -505,9 +371,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         snapshot (every non-empty shard, ``full=True``).  Bytes shipped
         and bytes avoided are accounted in ``repro.perfcount.WIRE``.
         """
-        if self.apply_mode != "fused":
-            raise ValueError("pull_delta requires apply_mode='fused' "
-                             "(tree mode has no resident packed store)")
         t0 = TRACE.now() if TRACE.enabled else 0.0
         plan, shards, epoch = self._plan_state()
         n_shards = len(shards)
@@ -562,9 +425,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         pushed through ``push_packed`` (which records the aggregate
         once, max-staleness folded).
         """
-        if self.apply_mode != "fused":
-            raise ValueError("push_packed_shard requires apply_mode='fused' "
-                             "(tree mode has no resident packed store)")
         if self.gating == "global":
             raise ValueError(
                 "per-shard routed pushes require gating='sharded' (the "
@@ -580,30 +440,11 @@ class ShardedParameterServer(ParameterServerProtocol):
                     f"layout ({layout.shard_rows[shard]}, {WIRE_LANES})")
             if self.wire_compression is not None:
                 buf = self._compress_packed_one(worker, shard, buf)
-            self._push_shard(shards[shard], worker, buf, packed=True)
+            self._push_shard(shards[shard], worker, buf)
         finally:
             with self._reshard_cond:
                 self._inflight[epoch] -= 1
                 self._reshard_cond.notify_all()
-
-    def push(self, worker: int, grads: Grads) -> None:
-        """Split grads by the plan and push shard-by-shard.
-
-        Every worker visits shards in the SAME canonical order 0..S-1:
-        with blocking policies a per-worker rotated order deadlocks
-        (worker A blocked at shard 0's barrier while worker B, whose push
-        would release it, is blocked at shard 1's — a circular wait).  A
-        total order keeps the wait-for graph acyclic while pushes to
-        distinct shards still overlap in pipeline fashion.  Blocks until
-        every shard's policy has released the worker (the ``global`` mode
-        gates once, after all applies).
-        """
-        plan, _, epoch = self._plan_state()
-        pieces_per_shard = plan.split(grads)
-        if self.compressor is not None:
-            pieces_per_shard = self._compress(worker, pieces_per_shard)
-        self._push_payloads(worker, pieces_per_shard, packed=False,
-                            epoch=epoch)
 
     def push_packed(self, worker: int, wire, epoch: Optional[int] = None
                     ) -> None:
@@ -614,8 +455,15 @@ class ShardedParameterServer(ParameterServerProtocol):
         The server only takes row-range VIEWS — no per-leaf concatenate,
         no ``pack_shard`` — and each shard folds its region through one
         ``fused_update`` launch (plus one fused-compression launch when
-        ``wire_compression`` is set).  Gating/metrics semantics are
-        identical to ``push``.
+        ``wire_compression`` is set).  Every worker visits shards in the
+        SAME canonical order 0..S-1: with blocking policies a per-worker
+        rotated order deadlocks (worker A blocked at shard 0's barrier
+        while worker B, whose push would release it, is blocked at shard
+        1's — a circular wait).  A total order keeps the wait-for graph
+        acyclic while pushes to distinct shards still overlap in
+        pipeline fashion.  Blocks until every shard's policy has
+        released the worker (the ``global`` mode gates once, after all
+        applies).
 
         ``epoch`` is the reshard epoch the pusher packed against
         (transports carry it on the frame).  A stale epoch means the
@@ -625,9 +473,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         layout this buffer matches" — inferred for in-heap callers that
         hold a plan reference rather than an epoch.
         """
-        if self.apply_mode != "fused":
-            raise ValueError("push_packed requires apply_mode='fused' "
-                             "(tree mode has no resident packed store)")
         if epoch is None and not isinstance(wire, (list, tuple)):
             epoch = self._infer_epoch(int(wire.shape[0]))
         plan, epoch = self._plan_for_epoch(epoch)
@@ -657,7 +502,7 @@ class ShardedParameterServer(ParameterServerProtocol):
         # beats quantizing against mismatched feedback state.
         if self.wire_compression is not None and epoch == self.reshard_epoch:
             shard_bufs = self._compress_packed(worker, shard_bufs)
-        self._push_payloads(worker, shard_bufs, packed=True, epoch=epoch)
+        self._push_payloads(worker, shard_bufs, epoch=epoch)
 
     def _infer_epoch(self, rows: int) -> Optional[int]:
         """Map a full-buffer row count onto the epoch whose layout it
@@ -672,7 +517,7 @@ class ShardedParameterServer(ParameterServerProtocol):
             return self.reshard_epoch   # let validation raise with detail
 
     def _push_payloads(self, worker: int, payloads: Sequence[Any],
-                       packed: bool, epoch: Optional[int] = None) -> None:
+                       epoch: Optional[int] = None) -> None:
         t_push = TRACE.now() if TRACE.enabled else 0.0
         # Atomically: which epoch's shard set does this push apply to,
         # and register it in flight — a live reshard replays parked
@@ -684,24 +529,12 @@ class ShardedParameterServer(ParameterServerProtocol):
             self._inflight[cur] = self._inflight.get(cur, 0) + 1
         try:
             if epoch is not None and epoch != cur:
-                if not packed:
-                    # A tree push that raced the swap: pack each piece
-                    # list into its OLD-plan region, then translate like
-                    # any other stale packed push.
-                    old_plan = self._retired_plans.get(epoch)
-                    if old_plan is None:
-                        raise ValueError(
-                            f"unknown reshard epoch {epoch}; re-pull")
-                    payloads = [old_plan.pack_shard_pieces(p, j)
-                                for j, p in enumerate(payloads)]
-                    packed = True
                 payloads = self._translate_stale(payloads, epoch, cur)
             now = self._clock() - self._t0
-            # Global mode: the gate decides FIRST (monolithic order —
-            # decide, apply, then maybe block), and its decision governs
-            # every shard's apply so update-dropping policies (backup
-            # workers) and credit accounting match the monolithic server
-            # exactly.
+            # Global mode: the gate decides FIRST (decide, apply, then
+            # maybe block), and its decision governs every shard's apply
+            # so update-dropping policies (backup workers) and credit
+            # accounting are those of one gate over the whole store.
             gate_dec = gate_stale = None
             if self.gating == "global":
                 gate_dec, gate_stale = self._gate_decide(worker)
@@ -709,7 +542,7 @@ class ShardedParameterServer(ParameterServerProtocol):
             total_wait = 0.0
             for j, st in enumerate(shards):
                 stale, applied, credit, waited = self._push_shard(
-                    st, worker, payloads[j], packed, gate_dec, gate_stale)
+                    st, worker, payloads[j], gate_dec, gate_stale)
                 max_stale = max(max_stale, stale)
                 any_applied = any_applied or applied
                 any_credit = any_credit or credit
@@ -753,7 +586,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         return [jnp.asarray(b) for b in bufs]
 
     def _push_shard(self, st: _ShardState, worker: int, payload: Any,
-                    packed: bool = False,
                     gate_dec: Optional[Decision] = None,
                     gate_stale: Optional[int] = None):
         j = st.index
@@ -765,8 +597,8 @@ class ShardedParameterServer(ParameterServerProtocol):
                 apply_staleness = rec.staleness
             else:
                 # Global gating: apply iff the gate said so, with the
-                # gate's staleness (what the monolithic optimizer saw);
-                # release decision belongs to the gate, not the shard.
+                # gate's staleness; the release decision belongs to the
+                # gate, not the shard.
                 dec = Decision(apply_update=gate_dec.apply_update,
                                release_now=True,
                                credit_used=gate_dec.credit_used)
@@ -778,14 +610,11 @@ class ShardedParameterServer(ParameterServerProtocol):
                     # copied out.  Park the region; the reshard replays
                     # it through the migration map onto the NEW shards
                     # — applied exactly once, never lost.
-                    self._park(st, payload, packed, apply_staleness)
+                    self._park(st, payload, apply_staleness)
                 elif self.coalesce > 1:
-                    self._apply_coalesced(st, payload, packed,
-                                          apply_staleness)
-                elif packed:
-                    st.apply_packed(payload, apply_staleness)
+                    self._apply_coalesced(st, payload, apply_staleness)
                 else:
-                    st.apply(payload, apply_staleness)
+                    st.apply_packed(payload, apply_staleness)
                 if TRACE.enabled:
                     TRACE.span("apply", t_apply, worker=worker, shard=j,
                                clock=rec.iteration)
@@ -812,53 +641,46 @@ class ShardedParameterServer(ParameterServerProtocol):
                                clock=rec.iteration)
             return rec.staleness, dec.apply_update, dec.credit_used, waited
 
-    def _make_window(self, st: _ShardState) -> CoalesceWindow:
-        """One ``CoalesceWindow`` per shard (the shard's lock domain):
-        ``install`` commits buffers + version together so a reader
-        snapshotting (buffer, version) under ``st.cond`` never sees one
-        without the other (the pull_packed cache is keyed by the
-        vector)."""
+    def _new_shard(self, index: int, packed_p: jax.Array,
+                   packed_m: jax.Array, version: int, policy: SyncPolicy,
+                   workers: Sequence[int]) -> _ShardState:
+        """A shard over the given packed state, with its own optimizer
+        and ``CoalesceWindow`` (the shard's lock domain): ``install``
+        commits buffers + version together so a reader snapshotting
+        (buffer, version) under ``st.cond`` never sees one without the
+        other (the pull_packed cache is keyed by the vector)."""
+        st = _ShardState(index, packed_p, packed_m, version, policy,
+                         self._optimizer_factory(), workers)
+
         def get_pm():
             return st._packed_p, st._packed_m
 
         def install(p, m, n: int) -> None:
             st._packed_p, st._packed_m = p, m
-            st._pieces = None
             st.version += n
 
-        return CoalesceWindow(self, st.cond, st.optimizer, st.tracker,
-                              get_pm, install)
+        st.window = CoalesceWindow(self, st.cond, st.optimizer, st.tracker,
+                                   get_pm, install)
+        return st
 
-    def _apply_coalesced(self, st: _ShardState, payload: Any,
-                         packed: bool, staleness: int) -> None:
+    def _apply_coalesced(self, st: _ShardState, payload: jax.Array,
+                         staleness: int) -> None:
         """Route one contribution through the shard's coalescing window
-        (``CoalesceWindow`` in ``repro.ps.server`` — the full flusher /
+        (``repro.ps.sharded.apply.CoalesceWindow`` — the full flusher /
         linger / lock-release protocol lives there).  Called under
         ``st.cond``."""
-        opt = st.optimizer
-        scale = (1.0 / (1.0 + staleness)
-                 if opt.staleness_damping else 1.0)
-        if not packed:
-            if not payload:              # empty shard: bookkeeping only
-                st.version += 1
-                return
-            payload = st.plan.pack_shard_pieces(payload, st.index)
         if payload.shape[0] == 0:        # empty shard region
             st.version += 1
             return
-        st.window.submit(payload, scale)
+        st.window.submit(payload, st.optimizer.scale(staleness))
 
     # -- live reshard ----------------------------------------------------------
-    def _park(self, st: _ShardState, payload: Any, packed: bool,
+    def _park(self, st: _ShardState, payload: jax.Array,
               staleness: int) -> None:
         """Called under ``st.cond`` on a retired shard: hold the packed
         gradient region for replay onto the new shards.  The retired
         shard's version does NOT move (its buffer does not change), so
         delta pulls stay truthful during the migration window."""
-        if not packed:
-            if not payload:
-                return
-            payload = st.plan.pack_shard_pieces(payload, st.index)
         if payload.shape[0] == 0:
             return
         st.parked.append((payload, int(staleness)))
@@ -880,9 +702,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         copy-out — ``FaultPlan.kill_mid_reshard`` SIGKILLs the server
         process there to exercise reshard x failover.
         """
-        if self.apply_mode != "fused":
-            raise ValueError("live reshard requires apply_mode='fused' "
-                             "(the packed store is what migrates)")
         if n_shards < 1:
             raise ValueError("n_shards must be >= 1")
         from repro.ft.reshard import (build_migration, equalized_counts,
@@ -911,7 +730,7 @@ class ShardedParameterServer(ParameterServerProtocol):
                 t_s = TRACE.now() if TRACE.enabled else 0.0
                 with st.cond:
                     st.retired = True
-                    while (st.window is not None and not self.stopped
+                    while (not self.stopped
                            and (st.window.applying or st.window.pending)):
                         st.cond.wait(timeout=0.1)
                     copied_p.append(st._packed_p)
@@ -940,13 +759,11 @@ class ShardedParameterServer(ParameterServerProtocol):
             for k in range(n_shards):
                 policy = self._policy_factory()
                 restore_policy_state(policy, pol_state)
-                st = _ShardState.from_packed(
-                    k, new_plan, jnp.asarray(new_p[k]),
-                    jnp.asarray(new_m[k]), new_versions[k], policy,
-                    self._optimizer_factory(), workers)
+                st = self._new_shard(
+                    k, jnp.asarray(new_p[k]), jnp.asarray(new_m[k]),
+                    new_versions[k], policy, workers)
                 st.tracker.counts.update(eq_counts)
                 st.tracker.credits.update(eq_credits)
-                st.window = self._make_window(st)
                 new_states.append(st)
             # Phase 3 — atomic swap + epoch bump.  The old plan and the
             # map are retained so stale-epoch pushes still translate.
@@ -962,7 +779,6 @@ class ShardedParameterServer(ParameterServerProtocol):
                 self._snap_key = self._snap_wire = None
             # Error-feedback state is layout-shaped; reset it (the next
             # compressed push starts a fresh feedback loop).
-            self._err.clear()
             self._wire_err.clear()
             # Phase 4 — release barrier waiters stranded on old shards:
             # their peers push to the new shards now, so those barriers
@@ -1012,8 +828,7 @@ class ShardedParameterServer(ParameterServerProtocol):
             st = self.shards[k]
             with st.cond:
                 opt = st.optimizer
-                scale = (1.0 / (1.0 + staleness)
-                         if opt.staleness_damping else 1.0)
+                scale = opt.scale(staleness)
                 p = np.asarray(st._packed_p).reshape(-1).copy()
                 m = np.asarray(st._packed_m).reshape(-1).copy()
                 lr = p.dtype.type(opt.lr)
@@ -1028,7 +843,6 @@ class ShardedParameterServer(ParameterServerProtocol):
                 rows = p.size // WIRE_LANES
                 st._packed_p = jnp.asarray(p.reshape(rows, WIRE_LANES))
                 st._packed_m = jnp.asarray(m.reshape(rows, WIRE_LANES))
-                st._pieces = None
                 # The buffer changed, so the version MUST move (delta
                 # pulls diff on it) — one bump per replayed contribution
                 # per touched shard.
@@ -1058,18 +872,6 @@ class ShardedParameterServer(ParameterServerProtocol):
         if TRACE.enabled:
             TRACE.span("gate_wait", t_wait, worker=worker)
         return waited
-
-    def _compress(self, worker: int,
-                  pieces_per_shard: List[List[jax.Array]]):
-        err = self._err.get(worker)
-        if err is None:
-            err = [self.compressor.init_error(p) for p in pieces_per_shard]
-        out = []
-        for j, pieces in enumerate(pieces_per_shard):
-            compressed, err[j] = self.compressor.apply(pieces, err[j])
-            out.append(compressed)
-        self._err[worker] = err
-        return out
 
     def _compress_packed(self, worker: int,
                          shard_bufs: List[jax.Array]) -> List[jax.Array]:
@@ -1109,7 +911,6 @@ class ShardedParameterServer(ParameterServerProtocol):
                 self._gate_cond.notify_all()
         with self._metrics_lock:
             self.metrics.n_workers = len(self.shards[0].tracker.workers)
-        self._err.pop(worker, None)
         self._wire_err.pop(worker, None)
 
     def remove_worker(self, worker: int) -> None:
@@ -1126,7 +927,6 @@ class ShardedParameterServer(ParameterServerProtocol):
                 self._gate_cond.notify_all()
         with self._metrics_lock:
             self.metrics.n_workers = len(self.shards[0].tracker.workers)
-        self._err.pop(worker, None)
         self._wire_err.pop(worker, None)
 
     def stop(self) -> None:
@@ -1139,11 +939,24 @@ class ShardedParameterServer(ParameterServerProtocol):
                 self._gate_cond.notify_all()
 
     # -- inspection ------------------------------------------------------------
-    # (``params``/``snapshot``/``shutdown`` come from the protocol base.)
+    # (``params``/``shutdown`` come from the protocol base.)
+    def snapshot(self) -> Params:
+        """The weights as a pytree, unpacked from the per-shard packed
+        buffers (each grabbed under its own lock — the per-shard
+        consistency ``pull_packed`` offers).  Examples, benchmarks and
+        tests read this; workers pull the packed wire instead."""
+        plan, shards, _ = self._plan_state()
+        regions = []
+        for st in shards:
+            with st.cond:
+                regions.append(st._packed_p)
+        return plan.assemble([plan.shard_pieces_from_wire(r, j)
+                              for j, r in enumerate(regions)])
+
     @property
     def version(self) -> int:
-        """Total applied shard-updates.  At S=1 this equals the monolithic
-        server's version (one applied update per released push)."""
+        """Total applied shard-updates (at S=1, one applied update per
+        released push)."""
         return sum(st.version for st in self.shards)
 
     def shard_versions(self) -> List[int]:

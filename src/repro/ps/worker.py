@@ -5,22 +5,20 @@ Each worker runs the paper's loop (Alg. 1 worker block):
     pull w_s  →  replace local weights  →  compute grads on a mini-batch
     →  push grads  →  (blocked until the server sends OK)
 
-``step_fn`` is any jitted ``(params, batch) -> (grads, aux)`` function;
-batches come from a per-worker data shard (data parallelism, §I).  A
-``speed_factor > 1`` makes the worker proportionally slower by sleeping
+The worker pulls the server's packed (rows, 512) wire buffer
+(``pull_packed``), hands it to ``step_fn`` unchanged, and pushes the
+packed gradient buffer back (``push_packed``).  ``step_fn`` is a packed
+step ``(wire_p, wire_g_prev, batch) -> (wire_g, aux)`` — a pytree step
+wrapped by ``repro.ps.step.packed_step``, which unpacks, differentiates
+and re-packs in one jitted program; the worker owns the gradient buffer
+and donates it back into the step every iteration.  Batches come from a
+per-worker data shard (data parallelism, §I).  A ``speed_factor > 1``
+makes the worker proportionally slower by sleeping
 ``(speed_factor − 1) × measured_compute`` per iteration — this emulates
 the paper's heterogeneous cluster (GTX1060 vs GTX1080Ti) on one machine
 without depending on scheduler noise.
 
-``wire_format='packed'`` switches the worker onto the zero-repack hot
-path: it pulls the server's packed (rows, 512) wire buffer
-(``pull_packed``), hands it to ``step_fn`` unchanged (the jitted step
-unpacks, differentiates and re-packs in one fused program — see
-``repro.launch.train.train_ps``), and pushes the packed gradient buffer
-back (``push_packed``).  The pytree<->wire boundary is crossed exactly
-once per direction, inside the worker's jit.
-
-``delta_pull=True`` (packed only) replaces the full-snapshot pull with
+``delta_pull=True`` replaces the full-snapshot pull with
 ``server.pull_delta``: the worker keeps a resident packed buffer plus
 the per-shard version vector from its last pull and patches in only
 the shard regions that advanced — pull bytes proportional to change.
@@ -37,7 +35,8 @@ from repro.api.protocol import ParameterServerProtocol
 from repro.obs.trace import TRACE
 from repro.perfcount import WIRE, add_counts
 
-StepFn = Callable[[Any, Any], Any]  # (params, batch) -> (grads, aux)
+#: (wire_p, wire_g_prev, batch) -> (wire_g, aux), wire_g_prev donated
+StepFn = Callable[[Any, Any, Any], Any]
 
 
 class PSWorker(threading.Thread):
@@ -45,7 +44,6 @@ class PSWorker(threading.Thread):
                  step_fn: StepFn, batches: Iterator[Any], n_iterations: int,
                  *, speed_factor: float = 1.0,
                  loss_from_aux: Optional[Callable[[Any], float]] = None,
-                 wire_format: str = "tree",
                  delta_pull: bool = False,
                  reconnect: Optional[Callable[[], Any]] = None,
                  name: Optional[str] = None):
@@ -53,12 +51,6 @@ class PSWorker(threading.Thread):
         warn_legacy("PSWorker",
                     "repro.api.build_session (sessions construct and "
                     "join their own workers)")
-        if wire_format not in ("tree", "packed"):
-            raise ValueError(f"unknown wire format {wire_format!r}")
-        if delta_pull and wire_format != "packed":
-            raise ValueError("delta_pull tracks per-shard versions of "
-                             "the packed snapshot; it requires "
-                             "wire_format='packed'")
         self.worker_id = worker_id
         self.server = server
         self.step_fn = step_fn
@@ -66,7 +58,6 @@ class PSWorker(threading.Thread):
         self.n_iterations = n_iterations
         self.speed_factor = speed_factor
         self.loss_from_aux = loss_from_aux
-        self.wire_format = wire_format
         self.delta_pull = delta_pull
         #: Failover hook (``repro.ft``): a zero-arg callable returning a
         #: fresh server handle after the current one dies with a
@@ -103,7 +94,7 @@ class PSWorker(threading.Thread):
             "host": np.zeros((layout.total_rows, WIRE_LANES),
                              layout.dtype),
             "wire": None,
-            "versions": (-1,) * getattr(self.server, "n_shards", 1),
+            "versions": (-1,) * self.server.n_shards,
         }
 
         def pull(worker_id: int):
@@ -141,15 +132,13 @@ class PSWorker(threading.Thread):
     def _bind(self):
         """(pull, push) against the CURRENT ``self.server`` — re-run
         after a reconnect swaps the handle."""
-        packed = self.wire_format == "packed"
-        pull = (self._delta_puller() if packed and self.delta_pull
-                else self.server.pull_packed if packed
-                else self.server.pull)
-        push = self.server.push_packed if packed else self.server.push
-        return pull, push
+        pull = (self._delta_puller() if self.delta_pull
+                else self.server.pull_packed)
+        return pull, self.server.push_packed
 
     def run(self) -> None:
         pull, push = self._bind()
+        grads = None      # the gradient buffer, donated to every step
         try:
             it = 0
             while it < self.n_iterations:
@@ -165,7 +154,9 @@ class PSWorker(threading.Thread):
                         TRACE.span("batch", t_tr, worker=self.worker_id,
                                    clock=it)
                     t_call = TRACE.now() if TRACE.enabled else 0.0
-                    grads, aux = self.step_fn(params, batch)
+                    if grads is None or grads.shape != params.shape:
+                        grads = _zeros_like(params)
+                    grads, aux = self.step_fn(params, grads, batch)
                     if TRACE.enabled:
                         TRACE.span("dispatch", t_call,
                                    worker=self.worker_id, clock=it)
@@ -216,6 +207,11 @@ class PSWorker(threading.Thread):
             # crash.  A departed worker must not gate survivors (fault
             # tolerance) nor stall late joiners (elasticity).
             self.server.remove_worker(self.worker_id)
+
+
+def _zeros_like(wire: Any) -> Any:
+    import jax.numpy as jnp
+    return jnp.zeros_like(wire)
 
 
 def _block(tree: Any) -> Any:

@@ -94,7 +94,7 @@ class DirectSubscription(Subscription):
     def __init__(self, server, replica_id: int):
         self.server = server
         self.replica_id = int(replica_id)
-        self.n_shards = int(getattr(server, "n_shards", 1))
+        self.n_shards = int(server.n_shards)
         self.rows = server.plan.wire_layout().total_rows
 
     def refresh(self, versions: Sequence[int]):

@@ -3,9 +3,8 @@
 The packed (rows, 512) buffer that PR 2 made the native push/pull
 representation gets its bytes-on-the-wire story here: a ``Transport``
 ABC with ``inproc`` / ``tcp`` / ``shmem`` backends, a
-``PSServerEndpoint`` that serves push/pull/policy-gate RPCs for both
-``ParameterServer`` and ``ShardedParameterServer`` (with per-shard
-routing), and the worker-side ``PSTransportClient``.  Frame layout
+``PSServerEndpoint`` that serves push/pull/policy-gate RPCs for the
+``ShardedParameterServer`` (with per-shard routing), and the worker-side ``PSTransportClient``.  Frame layout
 lives in ``repro.wireformat``; see README.md in this directory for the
 byte-level format.
 

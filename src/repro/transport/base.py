@@ -72,8 +72,8 @@ class Channel(abc.ABC):
 class PSTransportClient:
     """Parameter-server RPCs over any ``Channel``.
 
-    Mirrors the worker-facing surface of ``ParameterServer`` /
-    ``ShardedParameterServer`` (pull/push packed, record_loss, leave)
+    Mirrors the worker-facing surface of ``ShardedParameterServer``
+    (pull/push packed, record_loss, leave)
     plus an ``echo`` diagnostic.  ``push_packed``/``pull_packed``
     return ``False``/``None`` once the server has stopped — the worker
     loop's clean-exit signal.

@@ -1,12 +1,12 @@
 """Server-side RPC dispatch: frames in, parameter-server calls out.
 
-``PSServerEndpoint`` adapts a ``ParameterServer`` (``apply_mode=
-'packed'``) or ``ShardedParameterServer`` (``apply_mode='fused'``) to
-the frame protocol.  The endpoint is transport-agnostic: every backend
-funnels each decoded request through ``handle`` (or raw bytes through
-``handle_bytes``) on its own thread, so a push that blocks inside the
-sync-policy gate simply parks that connection's thread — exactly the
-semantics the threaded in-process workers had, now across processes.
+``PSServerEndpoint`` adapts the parameter server
+(``ShardedParameterServer``) to the frame protocol.  The endpoint is
+transport-agnostic: every backend funnels each decoded request through
+``handle`` (or raw bytes through ``handle_bytes``) on its own thread,
+so a push that blocks inside the sync-policy gate simply parks that
+connection's thread — exactly the semantics the threaded in-process
+workers had, now across processes.
 
 Per-shard routing: an endpoint built with ``shards={0, 2}`` serves only
 those shards' regions (frames must carry ``shard >= 0``), so different
@@ -48,30 +48,21 @@ from repro.wireformat import (
 
 
 class PSServerEndpoint:
-    """Frame-level RPC surface over a packed-mode parameter server.
-
-    ``server`` must speak the packed wire format (``push_packed`` /
-    ``pull_packed``); per-shard routing additionally needs the sharded
-    server's ``push_packed_shard`` / ``pull_packed_shard``.
+    """Frame-level RPC surface over a parameter server
+    (``repro.api.protocol.ParameterServerProtocol``): full-store frames
+    call ``push_packed`` / ``pull_packed``, shard-routed frames
+    ``push_packed_shard`` / ``pull_packed_shard``.
     """
 
     def __init__(self, server, *, shards: Optional[Sequence[int]] = None,
                  collector=None):
-        # Any ParameterServerProtocol implementation works — per-shard
-        # calls included (single-shard servers answer shard 0 via the
-        # protocol's default impls), so no concrete-type checks here.
-        if not getattr(server, "packed_wire", False):
-            raise ValueError(
-                "endpoint needs a packed-wire server (apply_mode="
-                f"'packed'/'fused'), got apply_mode="
-                f"{getattr(server, 'apply_mode', None)!r}")
         self.server = server
         #: ``repro.obs.TraceCollector`` to merge MSG_TRACE flushes into;
         #: without one the frames are acknowledged and dropped.
         self.collector = collector
         self.shards = None if shards is None else frozenset(shards)
         if self.shards is not None:
-            known = range(getattr(server, "n_shards", 1))
+            known = range(server.n_shards)
             bad = sorted(self.shards - set(known))
             if bad:
                 raise ValueError(f"endpoint routes shards {bad} but the "
@@ -90,10 +81,10 @@ class PSServerEndpoint:
         self._pull_cache: Dict[int, tuple] = {}  # shard->(epoch, ver, np)
 
     def _epoch(self) -> int:
-        """The server's live-reshard epoch (0 for servers without the
-        surface) — stamped into HELLO/SUB/PULL/DELTA replies via the
-        frame's otherwise-unused ``shard`` field."""
-        return int(getattr(self.server, "reshard_epoch", 0))
+        """The server's live-reshard epoch — stamped into
+        HELLO/SUB/PULL/DELTA replies via the frame's otherwise-unused
+        ``shard`` field."""
+        return int(self.server.reshard_epoch)
 
     # -- sizing (transports pre-allocate from this) ----------------------
     def wire_rows(self) -> int:
@@ -177,7 +168,7 @@ class PSServerEndpoint:
             return Frame(kind=MSG_DELTA, worker=frame.worker,
                          clock=server.version,
                          flags=FLAG_FULL if d.full else 0,
-                         shard=int(getattr(d, "epoch", 0)),
+                         shard=int(d.epoch),
                          versions=tuple(d.versions), delta=entries)
         if kind == MSG_PUSH:
             if server.stopped:
@@ -256,14 +247,11 @@ class PSServerEndpoint:
         # worker loop guards with copy=True on pulls.)
         buf = jnp.asarray(np.array(frame.payload))
         if shard < 0:
-            if hasattr(self.server, "reshard"):
-                # Epoch-aware server: ``aux`` carries the layout epoch
-                # the client packed against, so a push that raced a
-                # live reshard is translated instead of rejected.
-                self.server.push_packed(frame.worker, buf,
-                                        epoch=int(frame.aux))
-            else:
-                self.server.push_packed(frame.worker, buf)
+            # ``aux`` carries the layout epoch the client packed
+            # against, so a push that raced a live reshard is
+            # translated instead of rejected.
+            self.server.push_packed(frame.worker, buf,
+                                    epoch=int(frame.aux))
         else:
             self.server.push_packed_shard(frame.worker, shard, buf)
 

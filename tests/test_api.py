@@ -6,11 +6,12 @@ Covers the PR-4 acceptance surface:
 * every sync paradigm x every valid (server, wire, transport)
   combination builds via ``build_session`` from a plain dict and
   round-trips ``to_dict``/``from_dict`` bitwise;
-* invalid combinations raise ``SpecError`` with an actionable message;
+* invalid combinations raise ``SpecError`` with an actionable message,
+  and the removed server kind, applies and wire format name what
+  replaces them;
 * legacy direct construction still works, emits a single
   ``DeprecationWarning`` naming the replacement, and is behaviorally
   identical to the api-built server;
-* ``ServerOptimizer`` LR changes and second instances do not retrace;
 * a process-transport run driven purely by a spec matches the
   pre-refactor manual wiring bitwise (single worker = deterministic).
 """
@@ -56,7 +57,6 @@ def tiny_problem():
         logp = jax.nn.log_softmax(bx @ params["w"] + params["b"])
         return -jnp.mean(jnp.take_along_axis(logp, by[:, None], 1))
 
-    @jax.jit
     def step(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return grads, {"loss": loss}
@@ -73,35 +73,27 @@ def tiny_problem():
 
 
 def all_valid_specs():
-    """The full (sync) x (server, apply, wire, transport) grid."""
+    """The full (sync) x (shards, transport, compression) grid."""
     combos = [
-        # (kind, apply, wire_format, transport, compression)
-        ("mono", "tree", "tree", "inproc", "none"),
-        ("mono", "packed", "tree", "inproc", "none"),
-        ("mono", "packed", "packed", "inproc", "none"),
-        ("mono", "packed", "packed", "tcp", "none"),
-        ("mono", "packed", "packed", "shmem", "none"),
-        ("sharded", "tree", "tree", "inproc", "none"),
-        ("sharded", "tree", "tree", "inproc", "int8"),
-        ("sharded", "fused", "tree", "inproc", "topk"),
-        ("sharded", "fused", "packed", "inproc", "int8"),
-        ("sharded", "fused", "packed", "tcp", "none"),
-        ("sharded", "fused", "packed", "tcp", "int8"),
-        ("sharded", "fused", "packed", "shmem", "topk"),
+        # (shards, transport, compression)
+        (1, "inproc", "none"),
+        (2, "inproc", "int8"),
+        (2, "inproc", "topk"),
+        (1, "tcp", "none"),
+        (2, "tcp", "int8"),
+        (2, "shmem", "topk"),
     ]
     specs = []
     for sync in ("bsp", "ssp", "dssp"):          # spmd has no asp
         specs.append(RunSpec(sync=SyncSpec(mode=sync, s_lower=1,
                                            s_upper=3)))
     for sync in ("bsp", "asp", "ssp", "dssp"):
-        for kind, apply_, wire, tp, comp in combos:
+        for shards, tp, comp in combos:
             specs.append(RunSpec(
                 sync=SyncSpec(mode=sync, staleness=2, s_lower=1,
                               s_upper=3),
-                ps=ServerSpec(kind=kind,
-                              shards=1 if kind == "mono" else 2,
-                              workers=2, apply=apply_),
-                wire=WireSpec(format=wire, compression=comp),
+                ps=ServerSpec(kind="sharded", shards=shards, workers=2),
+                wire=WireSpec(compression=comp),
                 transport=TransportSpec(kind=tp)))
     return specs
 
@@ -122,30 +114,17 @@ def test_every_valid_combo_builds_and_roundtrips():
 
 def test_engine_selection():
     assert RunSpec().engine == "spmd"
-    assert RunSpec(ps=ServerSpec(kind="mono", shards=1)).engine == \
+    assert RunSpec(ps=ServerSpec(kind="sharded", shards=1)).engine == \
         "ps-threads"
-    assert RunSpec(ps=ServerSpec(kind="sharded", shards=2, apply="fused"),
-                   wire=WireSpec(format="packed"),
+    assert RunSpec(ps=ServerSpec(kind="sharded", shards=2),
                    transport=TransportSpec(kind="tcp")).engine == \
         "ps-transport"
 
 
 @pytest.mark.parametrize("mutate,needle", [
-    # the two combinations the issue names explicitly:
-    (dict(ps=dict(kind="sharded", shards=2, apply="fused"),
-          wire=dict(format="tree"),
-          transport=dict(kind="shmem")), "packed"),
-    (dict(ps=dict(kind="mono", shards=1, apply="fused")), "monolithic"),
-    # and the rest of the cross-field matrix:
-    (dict(ps=dict(kind="sharded", shards=2, apply="packed")), "fused"),
     (dict(sync=dict(mode="asp")), "SPMD"),
     (dict(transport=dict(kind="tcp")), "ps.kind='sharded'"),
-    (dict(wire=dict(format="packed")), "wire"),
-    (dict(ps=dict(kind="sharded", shards=2, apply="tree"),
-          wire=dict(format="packed")), "packed-resident"),
-    (dict(ps=dict(kind="mono", shards=1),
-          wire=dict(compression="int8")), "compression"),
-    (dict(ps=dict(kind="mono", shards=1, gating="global")), "gating"),
+    (dict(ps=dict(gating="global")), "gating"),
     (dict(ps=dict(kind="sharded", shards=0)), "shards"),
     (dict(ps=dict(kind="none", shards=2)), "shards=0"),
     (dict(sync=dict(mode="hogwild")), "sync.mode"),
@@ -156,10 +135,6 @@ def test_engine_selection():
     (dict(optimizer=dict(lr=-1.0)), "lr"),
     # PR-5 knobs: delta pulls and coalescing ride the packed wire only
     (dict(wire=dict(delta_pull=True)), "delta_pull"),
-    (dict(ps=dict(kind="sharded", shards=2, apply="fused"),
-          wire=dict(format="tree", delta_pull=True)), "packed"),
-    (dict(ps=dict(kind="sharded", shards=2, apply="fused", coalesce=4),
-          wire=dict(format="tree")), "coalesce"),
     (dict(ps=dict(coalesce=2)), "coalesce"),
     (dict(ps=dict(kind="sharded", shards=2, coalesce=0)), "window"),
     (dict(ps=dict(kind="sharded", shards=2, coalesce_wait_ms=-5.0)),
@@ -168,9 +143,6 @@ def test_engine_selection():
     # transport, and a restartable (tcp) one for server kills
     (dict(ft=dict(snapshot_every_s=1.0, dir="/tmp/ck")),
      "parameter server"),
-    (dict(ps=dict(kind="sharded", shards=2, apply="tree"),
-          ft=dict(snapshot_every_s=1.0, dir="/tmp/ck")),
-     "packed-resident"),
     (dict(ps=dict(kind="sharded", shards=2, apply="fused"),
           wire=dict(format="packed"),
           ft=dict(fault_drop_prob=0.1)), "transport.kind"),
@@ -203,6 +175,48 @@ def test_invalid_combos_raise_actionable_spec_errors(mutate, needle):
         f"error not actionable: {e.value}"
 
 
+@pytest.mark.parametrize("section,field,value,replacement", [
+    ("ps", "kind", "mono", "ps.kind='sharded' with ps.shards=1"),
+    ("ps", "apply", "tree", "fused Pallas apply"),
+    ("ps", "apply", "packed", "fused Pallas apply"),
+    ("wire", "format", "tree", "packed (rows, 512) buffer"),
+])
+def test_removed_choices_name_their_replacement(section, field, value,
+                                                replacement):
+    """A spec from JSON that names the removed monolithic server, tree
+    or packed apply, or tree wire is refused with what replaces it."""
+    d = RunSpec(ps=ServerSpec(kind="sharded", shards=1)).to_dict()
+    d[section][field] = value
+    with pytest.raises(SpecError) as e:
+        RunSpec.from_dict(json.loads(json.dumps(d)))
+    assert f"{section}.{field}={value!r}" in str(e.value)
+    assert replacement in str(e.value)
+
+
+@pytest.mark.parametrize("config", ["chip_smoke", "danube-ps",
+                                    "moonlight-ps"])
+def test_frozen_harness_specs_still_validate(config):
+    """The benchmark harness and ``chip_smoke.py`` still pass
+    ``ps.apply`` and ``wire.format``: their values are the one apply and
+    the one wire, and the specs build the threaded PS engine."""
+    root = SCHEMA_PATH.parents[3]
+    if config == "chip_smoke":
+        server, wire = {"apply": "fused", "gating": "sharded"}, \
+            {"format": "packed", "delta_pull": False}
+    else:
+        cfg = json.loads((root / "benchmarks" / "chip" / "configs"
+                          / f"{config}.json").read_text())
+        server, wire = cfg["server"], cfg["wire"]
+    spec = RunSpec(ps=ServerSpec(kind="sharded", shards=4, workers=1,
+                                 apply=server["apply"],
+                                 gating=server["gating"]),
+                   wire=WireSpec(format=wire["format"],
+                                 delta_pull=wire["delta_pull"]))
+    assert spec.engine == "ps-threads"
+    assert spec.ps == ServerSpec(kind="sharded", shards=4, workers=1)
+    assert spec.wire == WireSpec()
+
+
 def test_from_dict_rejects_unknown_keys():
     d = RunSpec().to_dict()
     d["psx"] = {}
@@ -223,8 +237,8 @@ def test_from_dict_missing_sections_use_defaults():
 def test_json_roundtrip_bitwise():
     spec = RunSpec(sync=SyncSpec(mode="dssp", s_lower=2, s_upper=9),
                    ps=ServerSpec(kind="sharded", shards=4, workers=3,
-                                 apply="fused", straggler=2.5),
-                   wire=WireSpec(format="packed", compression="topk",
+                                 straggler=2.5),
+                   wire=WireSpec(compression="topk",
                                  topk_fraction=0.125),
                    transport=TransportSpec(kind="tcp", port=7001))
     assert RunSpec.from_json(spec.to_json()) == spec
@@ -247,26 +261,12 @@ def test_build_session_rejects_unknown_overrides():
 
 
 # ======================================================= session engines
-def test_threaded_mono_session_trains():
-    params, step, batches = tiny_problem()
-    spec = RunSpec(model=ModelSpec(arch="custom"),
-                   optimizer=OptimizerSpec(lr=0.5),
-                   sync=SyncSpec(mode="bsp"),
-                   ps=ServerSpec(kind="mono", shards=1, workers=2))
-    with build_session(spec, params=params, step_fn=step,
-                       batches=batches) as session:
-        m = session.run(30)
-    assert m["pushes"] == 30
-    assert m["final_loss"] < m["first_loss"]
-    assert session.server.stopped
-
-
 def test_threaded_sharded_session_matches_manual_wiring():
     """The api-built sharded run applies exactly like the pre-refactor
     direct wiring (single worker => deterministic push sequence)."""
     from repro.core.policies import make_policy_factory
-    from repro.ps.server import ServerOptimizer
-    from repro.ps.sharded import ShardedParameterServer
+    from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
+    from repro.ps.step import packed_step
     from repro.ps.worker import PSWorker, run_cluster
 
     params, step, batches = tiny_problem()
@@ -282,7 +282,8 @@ def test_threaded_sharded_session_matches_manual_wiring():
     manual = ShardedParameterServer(
         params, make_policy_factory("ssp", n_workers=1, staleness=2),
         lambda: ServerOptimizer(lr=0.3), 1, 2)
-    workers = [PSWorker(0, manual, step, batches(0, 1), 12,
+    workers = [PSWorker(0, manual, packed_step(manual.plan, step),
+                        batches(0, 1), 12,
                         loss_from_aux=lambda a: float(a["loss"]))]
     run_cluster(manual, workers, timeout=120.0)
     for a, b in zip(jax.tree_util.tree_leaves(api_params),
@@ -329,82 +330,29 @@ def test_external_workers_session_refuses_run():
 
 def test_custom_arch_without_overrides_is_actionable():
     spec = RunSpec(model=ModelSpec(arch="custom"),
-                   ps=ServerSpec(kind="mono", shards=1, workers=1),
+                   ps=ServerSpec(kind="sharded", shards=1, workers=1),
                    sync=SyncSpec(mode="asp"))
     session = build_session(spec)
     with pytest.raises(SpecError, match="overrides"):
         session.start()
 
 
-# ===================================================== server protocol
-def test_protocol_single_shard_defaults_on_mono():
-    _compat.reset_legacy_warnings()
-    from repro.core.policies import make_policy
-    from repro.ps.server import ParameterServer, ServerOptimizer
-
-    params = {"w": jnp.ones((16, 8)), "b": jnp.zeros((5,))}
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        server = ParameterServer(params, make_policy("asp"),
-                                 ServerOptimizer(lr=0.1), 1,
-                                 apply_mode="packed")
-    assert server.packed_wire and server.n_shards == 1
-    # shard 0 == the whole store
-    np.testing.assert_array_equal(
-        np.asarray(server.pull_packed_shard(0)),
-        np.asarray(server.pull_packed()))
-    wire_g = jnp.ones_like(server.pull_packed())
-    server.push_packed_shard(0, 0, wire_g)
-    assert server.version == 1
-    with pytest.raises(ValueError, match="shard"):
-        server.pull_packed_shard(1)
-    assert server.shard_versions() == [1]
-    # lifecycle aliases
-    snap = server.snapshot()
-    assert set(snap) == {"w", "b"}
-    server.shutdown()
-    assert server.stopped
-
-
-def test_endpoint_accepts_mono_server_per_shard_routing():
-    """The endpoint no longer type-checks the server: the protocol's
-    single-shard defaults make a packed mono server routable."""
-    _compat.reset_legacy_warnings()
-    from repro.core.policies import make_policy
-    from repro.ps.server import ParameterServer, ServerOptimizer
-    from repro.transport import PSServerEndpoint
-
-    params = {"w": jnp.ones((16, 8))}
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        server = ParameterServer(params, make_policy("asp"),
-                                 ServerOptimizer(lr=0.1), 1,
-                                 apply_mode="packed")
-        endpoint = PSServerEndpoint(server, shards=[0])
-    assert endpoint.wire_rows() == server.plan.wire_layout().total_rows
-    with pytest.raises(ValueError, match="shard"):
-        PSServerEndpoint(server, shards=[0, 1])
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        tree_server = ParameterServer(params, make_policy("asp"),
-                                      ServerOptimizer(lr=0.1), 1)
-    with pytest.raises(ValueError, match="packed"):
-        PSServerEndpoint(tree_server)
-
-
 # ================================================== deprecation shims
 def test_legacy_construction_warns_once_and_behaves_identically():
-    from repro.core.policies import make_policy
-    from repro.ps.server import ParameterServer, ServerOptimizer
+    from repro.core.policies import make_policy_factory
+    from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
 
     params = {"w": jnp.zeros((6, 3))}
-    grads = {"w": jnp.ones((6, 3))}
+
+    def construct():
+        return ShardedParameterServer(
+            params, make_policy_factory("asp", n_workers=1),
+            lambda: ServerOptimizer(lr=0.1), 1, 1)
 
     _compat.reset_legacy_warnings()
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        legacy = ParameterServer(params, make_policy("asp"),
-                                 ServerOptimizer(lr=0.1), 1)
+        legacy = construct()
     dep = [w for w in caught
            if issubclass(w.category, DeprecationWarning)]
     assert len(dep) == 1 and "repro.api" in str(dep[0].message)
@@ -412,16 +360,15 @@ def test_legacy_construction_warns_once_and_behaves_identically():
     # a second construction does NOT warn again (single warning)
     with warnings.catch_warnings(record=True) as caught2:
         warnings.simplefilter("always")
-        ParameterServer(params, make_policy("asp"),
-                        ServerOptimizer(lr=0.1), 1)
+        construct()
     assert not [w for w in caught2
                 if issubclass(w.category, DeprecationWarning)]
 
-    # the api-built mono server never warns and applies identically
+    # the api-built server never warns and applies identically
     spec = RunSpec(model=ModelSpec(arch="custom"),
                    optimizer=OptimizerSpec(lr=0.1),
                    sync=SyncSpec(mode="asp"),
-                   ps=ServerSpec(kind="mono", shards=1, workers=1))
+                   ps=ServerSpec(kind="sharded", shards=1, workers=1))
     _compat.reset_legacy_warnings()
     with warnings.catch_warnings(record=True) as caught3:
         warnings.simplefilter("always")
@@ -429,8 +376,9 @@ def test_legacy_construction_warns_once_and_behaves_identically():
                                 external_workers=True).start()
     assert not [w for w in caught3
                 if issubclass(w.category, DeprecationWarning)]
-    legacy.push(0, grads)
-    session.server.push(0, grads)
+    grads = legacy.plan.pack({"w": jnp.ones((6, 3))})
+    legacy.push_packed(0, grads)
+    session.server.push_packed(0, grads)
     np.testing.assert_array_equal(np.asarray(legacy.params["w"]),
                                   np.asarray(session.server.params["w"]))
     session.close()
@@ -452,71 +400,13 @@ def test_train_ps_shim_warns_and_trains():
     assert server.stopped
 
 
-# ============================================ ServerOptimizer satellite
-def test_server_optimizer_shares_one_trace_across_lr_and_instances():
-    from repro.ps.server import APPLY_TRACES, ServerOptimizer
-
-    # unique leaf shape => guaranteed-fresh jit cache entry
-    params = {"q": jnp.ones((3, 17), jnp.float32)}
-    grads = {"q": jnp.full((3, 17), 2.0, jnp.float32)}
-    opt = ServerOptimizer(lr=0.5)
-    before = APPLY_TRACES["count"]
-    p1 = opt.step(params, grads, staleness=0)
-    assert APPLY_TRACES["count"] == before + 1
-    np.testing.assert_allclose(np.asarray(p1["q"]),
-                               np.asarray(params["q"]) - 0.5 * 2.0)
-
-    # LR change: new math, NO new trace
-    opt.lr = 0.25
-    p2 = opt.step(p1, grads, staleness=0)
-    assert APPLY_TRACES["count"] == before + 1
-    np.testing.assert_allclose(np.asarray(p2["q"]),
-                               np.asarray(p1["q"]) - 0.25 * 2.0)
-
-    # a second instance (different lr AND momentum) shares the entry
-    opt2 = ServerOptimizer(lr=0.1, momentum=0.9,
-                           staleness_damping=True)
-    opt2.step(params, grads, staleness=3)
-    assert APPLY_TRACES["count"] == before + 1
-
-
-def test_server_optimizer_momentum_and_damping_math():
-    from repro.ps.server import ServerOptimizer
-
-    params = {"w": jnp.zeros((4,), jnp.float32)}
-    grads = {"w": jnp.ones((4,), jnp.float32)}
-    opt = ServerOptimizer(lr=1.0, momentum=0.5, staleness_damping=True)
-    p = opt.step(params, grads, staleness=1)      # scale = 1/2
-    np.testing.assert_allclose(np.asarray(p["w"]), -0.5 * np.ones(4))
-    p = opt.step(p, grads, staleness=0)           # v = .5*.5 + 1 = 1.25
-    np.testing.assert_allclose(np.asarray(p["w"]),
-                               -0.5 - 1.25 * np.ones(4))
-
-
-def test_worker_task_from_mono_spec_clamps_shards():
-    """A mono spec may carry ps.shards=0 (the ServerSpec default); the
-    spawn payload must still derive a 1-shard plan or every transport
-    worker dies in build_shard_plan."""
-    from repro.launch.proc_pool import WorkerTask
-
-    spec = RunSpec(model=ModelSpec(arch="xlstm-125m"),
-                   ps=ServerSpec(kind="mono", shards=0, workers=1,
-                                 apply="packed"),
-                   wire=WireSpec(format="packed"),
-                   transport=TransportSpec(kind="tcp"))
-    task = WorkerTask.from_spec(spec, 3)
-    assert task.n_shards == 1
-    assert task.arch == "xlstm-125m" and task.n_iterations == 3
-    assert task.delta_pull is False
-
-
 def test_worker_task_carries_delta_pull():
     from repro.launch.proc_pool import WorkerTask
 
     spec = RunSpec(model=ModelSpec(arch="xlstm-125m"),
                    ps=ServerSpec(kind="sharded", shards=2, workers=2,
-                                 apply="fused", coalesce=2),
-                   wire=WireSpec(format="packed", delta_pull=True),
+                                 coalesce=2),
+                   wire=WireSpec(delta_pull=True),
                    transport=TransportSpec(kind="tcp"))
     task = WorkerTask.from_spec(spec, 3)
     assert task.delta_pull is True
@@ -532,7 +422,7 @@ def test_cli_spec_rejects_every_wiring_flag():
     spec_path = "/tmp/test_api_cli_spec.json"
     with open(spec_path, "w") as f:
         f.write(RunSpec().to_json())
-    for extra in (["--ps-wire", "packed"], ["--lr", "0.1"],
+    for extra in (["--ps-gating", "global"], ["--lr", "0.1"],
                   ["--compress", "int8"], ["--ps-workers", "8"]):
         proc = subprocess.run(
             [sys.executable, "-m", "repro.launch.train",
@@ -555,8 +445,7 @@ def test_tcp_spec_run_matches_prerefactor_wiring_bitwise():
     from repro.launch.proc_pool import (ProcessWorkerPool, WorkerTask,
                                         raise_on_failure)
     from repro.models import registry
-    from repro.ps.server import ServerOptimizer
-    from repro.ps.sharded import ShardedParameterServer
+    from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
     from repro.transport import PSServerEndpoint, make_transport
 
     steps, seq, batch = 3, 16, 4
@@ -565,9 +454,7 @@ def test_tcp_spec_run_matches_prerefactor_wiring_bitwise():
                    optimizer=OptimizerSpec(lr=3e-3),
                    sync=SyncSpec(mode="dssp", staleness=1, s_lower=1,
                                  s_upper=3),
-                   ps=ServerSpec(kind="sharded", shards=2, workers=1,
-                                 apply="fused"),
-                   wire=WireSpec(format="packed"),
+                   ps=ServerSpec(kind="sharded", shards=2, workers=1),
                    transport=TransportSpec(kind="tcp"))
     with build_session(spec) as session:
         m = session.run(steps)
@@ -583,7 +470,7 @@ def test_tcp_spec_run_matches_prerefactor_wiring_bitwise():
             params,
             make_policy_factory("dssp", n_workers=1, staleness=1,
                                 s_lower=1, s_upper=3),
-            lambda: ServerOptimizer(lr=3e-3), 1, 2, apply_mode="fused")
+            lambda: ServerOptimizer(lr=3e-3), 1, 2)
     endpoint = PSServerEndpoint(server)
     tp = make_transport("tcp", n_workers=1)
     tp.serve(endpoint)

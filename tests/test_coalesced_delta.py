@@ -4,7 +4,9 @@ Covers the acceptance surface:
 
 * ``fused_update_batched`` is bitwise-identical to K sequential
   ``fused_update`` launches for f32 state at every K (and for every
-  dtype at K=1), and matches the jnp oracle;
+  dtype at K=1), and matches the jnp oracle (the bitwise comparisons
+  are computed once in a child process with XLA:CPU pinned to SSE4.2 —
+  see ``pinned``);
 * a coalescing window of 1, and a window fed strictly sequential
   pushes, match the uncoalesced packed path bitwise;
 * W concurrent pushes into a window of W fold through ONE batched
@@ -26,6 +28,10 @@ Covers the acceptance surface:
 
 from __future__ import annotations
 
+import os
+import pathlib
+import subprocess
+import sys
 import threading
 import warnings
 
@@ -64,20 +70,23 @@ def grads_like(params, seed):
 def make_sharded(params, *, n_workers=4, n_shards=2, policy="asp",
                  coalesce=1, coalesce_wait=None, momentum=0.9,
                  lr=0.05, damping=False):
-    from repro.ps.server import ServerOptimizer
-    from repro.ps.sharded.server import ShardedParameterServer
+    from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
     return ShardedParameterServer(
         params, make_policy_factory(policy, n_workers=n_workers),
         lambda: ServerOptimizer(lr=lr, momentum=momentum,
                                 staleness_damping=damping),
-        n_workers=n_workers, n_shards=n_shards, apply_mode="fused",
+        n_workers=n_workers, n_shards=n_shards,
         coalesce=coalesce, coalesce_wait=coalesce_wait)
 
 
-# ================================================================ kernel
-@pytest.mark.parametrize("k", [1, 2, 3, 5])
-@pytest.mark.parametrize("shape", [(16, 512), (40, 512), (7, 13)])
-def test_batched_kernel_bitwise_equals_sequential_launches(k, shape):
+# ===================================================== pinned CPU ISA
+KS = [1, 2, 3, 5]
+SHAPES = [(16, 512), (40, 512), (7, 13)]
+
+
+def _batched_vs_sequential(k, shape):
+    """(batched p, m) and (sequential p, m) of one kernel case, plus the
+    inputs."""
     rng = np.random.RandomState(k)
     p = jnp.asarray(rng.randn(*shape).astype(np.float32))
     m = jnp.asarray(rng.randn(*shape).astype(np.float32))
@@ -89,8 +98,104 @@ def test_batched_kernel_bitwise_equals_sequential_launches(k, shape):
     for j in range(k):
         ps_, ms_ = kops.fused_update(ps_, ms_, gs[j], lr=0.01, beta=0.9,
                                      scale=scales[j])
-    assert jnp.array_equal(po, ps_) and jnp.array_equal(mo, ms_)
+    return (po, mo), (ps_, ms_), (p, m, gs, scales)
+
+
+W, S = 4, 2
+
+
+def _concurrent_round():
+    """W concurrent pushes of one gradient into a window of W, beside W
+    sequential pushes into an uncoalesced server.  Returns both servers
+    and the wire counters of the concurrent round."""
+    params = make_params()
+    co = make_sharded(params, n_workers=W, n_shards=S, coalesce=W,
+                      coalesce_wait=5.0)
+    base = make_sharded(params, n_workers=W, n_shards=S, coalesce=1)
+    # identical grads for every worker: the sequential in-kernel fold is
+    # then order-independent, so the concurrent batch must be BITWISE
+    # equal to W sequential pushes regardless of enqueue order.
+    wire = base.plan.pack(grads_like(params, 7))
+    for w in range(W):
+        base.push_packed(w, wire)
+    co.push_packed(0, wire)        # warm the compile caches
+    base2 = make_sharded(params, n_workers=W, n_shards=S, coalesce=1)
+    for w in range(W):
+        base2.push_packed(w, wire)
+
+    co2 = make_sharded(params, n_workers=W, n_shards=S, coalesce=W,
+                       coalesce_wait=5.0)
+    WIRE.reset()
+    threads = [threading.Thread(target=co2.push_packed, args=(w, wire))
+               for w in range(W)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    ev = WIRE.snapshot()
+    for srv in (co, base):
+        srv.stop()
+    return co2, base2, ev
+
+
+def _write_pinned_arrays(path: str) -> None:
+    """The child's work: every array the bitwise cases compare."""
+    out = {}
+    for k in KS:
+        for i, shape in enumerate(SHAPES):
+            (po, mo), (ps_, ms_), _ = _batched_vs_sequential(k, shape)
+            for name, a in (("po", po), ("mo", mo), ("ps", ps_),
+                            ("ms", ms_)):
+                out[f"{k}-{i}-{name}"] = np.asarray(a)
+    co2, base2, _ = _concurrent_round()
+    out["window-co"] = np.asarray(co2.pull_packed())
+    out["window-base"] = np.asarray(base2.pull_packed())
+    for srv in (co2, base2):
+        srv.stop()
+    np.savez(path, **out)
+
+
+@pytest.fixture(scope="module")
+def pinned(tmp_path_factory):
+    """The arrays the bitwise cases compare, computed once in a child
+    process with XLA:CPU pinned to SSE4.2.
+
+    On an x86 host with FMA, XLA:CPU contracts multiply-adds into FMAs
+    differently in the batched K-step program and in K single-step
+    programs, so the two can round apart by an ulp there.  Without FMA
+    both programs round every multiply and every add, which is the
+    equality the batched kernel promises (and what a TPU computes).
+    The flag has to be set before the CPU client starts, hence the
+    child."""
+    here = pathlib.Path(__file__).resolve().parent
+    path = tmp_path_factory.mktemp("pinned_isa") / "arrays.npz"
+    flags = os.environ.get("XLA_FLAGS", "")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"{flags} --xla_cpu_max_isa=SSE4_2".strip(),
+               PYTHONPATH=os.pathsep.join(
+                   [str(here.parent / "src"), str(here),
+                    os.environ.get("PYTHONPATH", "")]))
+    code = ("import sys, test_coalesced_delta as t; "
+            "t._write_pinned_arrays(sys.argv[1])")
+    proc = subprocess.run([sys.executable, "-c", code, str(path)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    with np.load(path) as arrays:
+        return dict(arrays)
+
+
+# ================================================================ kernel
+@pytest.mark.parametrize("k", KS)
+@pytest.mark.parametrize("shape", SHAPES)
+def test_batched_kernel_bitwise_equals_sequential_launches(k, shape,
+                                                          pinned):
+    i = SHAPES.index(shape)
+    po, mo = pinned[f"{k}-{i}-po"], pinned[f"{k}-{i}-mo"]
+    assert np.array_equal(po, pinned[f"{k}-{i}-ps"])
+    assert np.array_equal(mo, pinned[f"{k}-{i}-ms"])
     # and the jnp oracle agrees to fp tolerance
+    _, _, (p, m, gs, scales) = _batched_vs_sequential(k, shape)
     pr, mr = kref.fused_update_batched_ref(p, m, gs, lr=0.01, beta=0.9,
                                            scales=scales)
     np.testing.assert_allclose(np.asarray(po), np.asarray(pr),
@@ -135,39 +240,14 @@ def test_window_of_one_is_bitwise_the_uncoalesced_path():
     base.stop(), co.stop()
 
 
-def test_concurrent_window_one_launch_per_shard_per_round():
-    params = make_params()
-    W, S = 4, 2
-    co = make_sharded(params, n_workers=W, n_shards=S, coalesce=W,
-                      coalesce_wait=5.0)
-    base = make_sharded(params, n_workers=W, n_shards=S, coalesce=1)
-    # identical grads for every worker: the sequential in-kernel fold is
-    # then order-independent, so the concurrent batch must be BITWISE
-    # equal to W sequential pushes regardless of enqueue order.
-    wire = base.plan.pack(grads_like(params, 7))
-    for w in range(W):
-        base.push_packed(w, wire)
-    co.push_packed(0, wire)        # warm the compile caches
-    base2 = make_sharded(params, n_workers=W, n_shards=S, coalesce=1)
-    for w in range(W):
-        base2.push_packed(w, wire)
-
-    co2 = make_sharded(params, n_workers=W, n_shards=S, coalesce=W,
-                       coalesce_wait=5.0)
-    WIRE.reset()
-    threads = [threading.Thread(target=co2.push_packed, args=(w, wire))
-               for w in range(W)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    ev = WIRE.snapshot()
+def test_concurrent_window_one_launch_per_shard_per_round(pinned):
+    co2, base2, ev = _concurrent_round()
     # ONE batched launch per shard for the whole 4-worker round
     assert ev["pallas_calls"] == S, ev
     assert ev["apply_launches_saved"] == S * (W - 1), ev
     assert co2.shard_versions() == [W] * S          # every push applied
-    assert jnp.array_equal(co2.pull_packed(), base2.pull_packed())
-    for srv in (co, base, base2, co2):
+    assert np.array_equal(pinned["window-co"], pinned["window-base"])
+    for srv in (co2, base2):
         srv.stop()
 
 
@@ -188,38 +268,6 @@ def test_coalesced_gating_still_blocks_per_worker():
     assert not any(t.is_alive() for t in threads), "BSP round deadlocked"
     assert srv.shard_versions() == [W, W]
     srv.stop()
-
-
-def test_mono_coalesced_matches_uncoalesced():
-    from repro.core.policies import make_policy_factory as mpf
-    from repro.ps.server import ParameterServer, ServerOptimizer
-    params = make_params()
-    def mk(c):
-        return ParameterServer(
-            params, mpf("asp", n_workers=2)(),
-            ServerOptimizer(lr=0.05, momentum=0.9), 2,
-            apply_mode="packed", coalesce=c, coalesce_wait=0.0)
-    base, co = mk(1), mk(4)
-    wires = [base.plan.pack(grads_like(params, s)) for s in range(3)]
-    for i, w in enumerate(wires):
-        base.push_packed(i % 2, w)
-        co.push_packed(i % 2, w)
-    assert co.version == base.version == 3
-    assert jnp.array_equal(co.pull_packed(), base.pull_packed())
-    base.stop(), co.stop()
-
-
-def test_coalesce_rejects_tree_apply():
-    from repro.core.policies import make_policy_factory as mpf
-    from repro.ps.server import ParameterServer, ServerOptimizer
-    with pytest.raises(ValueError, match="coalesce"):
-        ParameterServer(make_params(), mpf("asp", n_workers=1)(),
-                        ServerOptimizer(lr=0.1), 1, coalesce=2)
-    from repro.ps.sharded.server import ShardedParameterServer
-    with pytest.raises(ValueError, match="coalesce"):
-        ShardedParameterServer(
-            make_params(), mpf("asp", n_workers=1),
-            lambda: ServerOptimizer(lr=0.1), 1, 2, coalesce=2)
 
 
 # ======================================================== delta pulls
@@ -301,54 +349,27 @@ def test_delta_arity_mismatch_after_live_reshard_full_fallback():
     srv.stop()
 
 
-def test_mono_delta_paths():
-    from repro.core.policies import make_policy_factory as mpf
-    from repro.ps.server import ParameterServer, ServerOptimizer
-    params = make_params()
-    srv = ParameterServer(params, mpf("asp", n_workers=1)(),
-                          ServerOptimizer(lr=0.1), 1, apply_mode="packed")
-    d = srv.pull_delta(0, None)
-    assert d.full and d.shards == (0,)
-    d2 = srv.pull_delta(0, d.versions)
-    assert d2.empty and not d2.full
-    srv.push_packed(0, srv.plan.pack(grads_like(params, 2)))
-    d3 = srv.pull_delta(0, d2.versions)
-    assert d3.shards == (0,) and not d3.full
-    assert jnp.array_equal(d3.regions[0], srv.pull_packed())
-    srv.stop()
-
-
-def test_tree_mode_server_rejects_pull_delta():
-    from repro.ps.server import ServerOptimizer
-    from repro.ps.sharded.server import ShardedParameterServer
-    srv = ShardedParameterServer(
-        make_params(), make_policy_factory("asp", n_workers=1),
-        lambda: ServerOptimizer(lr=0.1), 1, 2, apply_mode="tree")
-    with pytest.raises(ValueError, match="fused"):
-        srv.pull_delta(0, (0, 0))
-    srv.stop()
-
-
 def test_worker_delta_pull_loop_matches_full_pulls():
     """A PSWorker with delta_pull=True trains bitwise-identically to
     one doing full packed pulls (single worker = deterministic)."""
+    from repro.ps.step import packed_step
     from repro.ps.worker import PSWorker, run_cluster
 
     def run(delta):
         params = make_params()
         srv = make_sharded(params, n_workers=1, n_shards=2,
                            policy="dssp")
-        plan = srv.plan
 
-        def step(wire_p, batch):
-            return wire_p * 0 + 0.01, {"loss": 1.0}
+        def tree_step(p, batch):
+            grads = jax.tree_util.tree_map(lambda x: x * 0 + 0.01, p)
+            return grads, {"loss": 1.0}
 
         def batches():
             while True:
                 yield None
 
-        w = PSWorker(0, srv, step, batches(), 5, wire_format="packed",
-                     delta_pull=delta)
+        w = PSWorker(0, srv, packed_step(srv.plan, tree_step), batches(),
+                     5, delta_pull=delta)
         run_cluster(srv, [w], timeout=120.0)
         out = np.asarray(srv.pull_packed())
         srv.stop()

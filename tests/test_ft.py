@@ -44,8 +44,7 @@ from repro.ft.snapshot import (
     restore_server,
     snapshot_server,
 )
-from repro.ps.server import ServerOptimizer
-from repro.ps.sharded import ShardedParameterServer
+from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
 from repro.transport import (
     PSServerEndpoint,
     TransportClosed,
@@ -75,7 +74,7 @@ def make_server(n_workers=1, n_shards=2, policy="asp", s_lower=0,
         make_policy_factory(policy, n_workers=n_workers, staleness=2,
                             s_lower=s_lower, s_upper=s_upper, **pkw),
         lambda: ServerOptimizer(lr=0.05),
-        n_workers, n_shards, apply_mode="fused")
+        n_workers, n_shards)
 
 
 def push_rounds(server, n, workers=(0,), seed=0):
@@ -250,18 +249,6 @@ class TestSnapshotRestore:
         assert packed_state(a) == packed_state(b)
         assert a.shard_versions() == b.shard_versions()
 
-    def test_restore_rejects_mismatched_mono_topology(self):
-        """The monolithic server cannot reshard — a snapshot from a
-        different arity still refuses loudly."""
-        from repro.ps.server import ParameterServer
-        a = make_server(n_shards=2)
-        tree, extras = snapshot_server(a)
-        mono = ParameterServer(
-            tiny_params(), make_policy_factory("asp", n_workers=1)(),
-            ServerOptimizer(lr=0.05), 1, apply_mode="packed")
-        with pytest.raises(ValueError, match="reshard"):
-            restore_server(mono, tree, extras)
-
     def test_snapshotter_skips_unchanged_and_keeps_k(self, tmp_path):
         server = make_server()
         mgr = CheckpointManager(str(tmp_path), keep=2)
@@ -294,14 +281,6 @@ class TestSnapshotRestore:
         assert restore_latest(
             b, CheckpointManager(str(tmp_path), keep=3)) is None
         assert b.version == 0
-
-    def test_tree_mode_server_is_rejected(self):
-        server = ShardedParameterServer(
-            tiny_params(), make_policy_factory("asp"),
-            lambda: ServerOptimizer(lr=0.05), 1, 2, apply_mode="tree")
-        with pytest.raises(ValueError, match="packed"):
-            snapshot_server(server)
-
 
 # ============================================================ fault plans
 class _CountingChannel:
@@ -455,8 +434,7 @@ def _registry_server(n_workers, policy="bsp"):
     return ShardedParameterServer(
         params, make_policy_factory(policy, n_workers=n_workers,
                                     s_lower=0, s_upper=3),
-        lambda: ServerOptimizer(lr=0.05), n_workers, 2,
-        apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.05), n_workers, 2)
 
 
 @pytest.mark.parametrize("kind", ["tcp", "shmem"])

@@ -414,9 +414,7 @@ def test_traced_threaded_run_and_disabled_run(tmp_path):
         model=api.ModelSpec(arch="xlstm-125m"),
         data=api.DataSpec(seq_len=16, global_batch=4),
         sync=api.SyncSpec(mode="dssp", staleness=1, s_lower=1, s_upper=3),
-        ps=api.ServerSpec(kind="mono", shards=0, workers=2,
-                          apply="packed"),
-        wire=api.WireSpec(format="packed"),
+        ps=api.ServerSpec(kind="sharded", shards=1, workers=2),
         obs=api.ObsSpec(trace=True, trace_path=trace_path))
     with api.build_session(spec) as session:
         m = session.run(6)

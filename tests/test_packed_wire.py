@@ -1,15 +1,13 @@
 """Packed wire format: layout round-trips, zero-repack server contract,
 fused compression kernels, dtype preservation.
 
-Acceptance probes (ISSUE 2):
+Acceptance probes:
   * one packed push performs ZERO host-side per-leaf concatenations /
     packs on the server (perfcount probe),
   * at most one ``pallas_call`` per shard for apply plus one for
     compression,
-  * packed-path numerics match the tree path on the same push sequence,
-  * bf16 trees round-trip without the silent f32 bounce (satellite),
-  * the fused-mode piece cache is rebuilt OUTSIDE the shard lock
-    (satellite).
+  * bf16 trees round-trip without the silent f32 bounce,
+  * ``snapshot()`` unpacks OUTSIDE the shard lock.
 """
 
 import threading
@@ -19,13 +17,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.policies import make_policy, make_policy_factory
+from repro.core.policies import make_policy_factory
 from repro.kernels import ref
 from repro.kernels.fused_compress import fused_int8_ef, fused_topk_ef
 from repro.kernels.fused_update import pack_shard, unpack_shard
 from repro.perfcount import WIRE
-from repro.ps.server import ParameterServer, ServerOptimizer
-from repro.ps.sharded import ShardedParameterServer, build_shard_plan
+from repro.ps.sharded import (ServerOptimizer, ShardedParameterServer,
+                              build_shard_plan)
+from repro.ps.step import packed_step
 from repro.ps.worker import PSWorker, run_cluster
 
 
@@ -155,7 +154,7 @@ def test_bf16_fused_server_keeps_bf16_store():
         lambda x: x.astype(jnp.bfloat16), _tree())
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 1, 2, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.1), 1, 2)
     for st in server.shards:
         assert st._packed_p.dtype == jnp.bfloat16
         assert st._packed_m.dtype == jnp.bfloat16
@@ -166,33 +165,13 @@ def test_bf16_fused_server_keeps_bf16_store():
 
 
 # -------------------------------------------------- packed server contract
-def test_packed_push_matches_tree_push():
-    """Acceptance: packed-path numerics == tree path on the same push
-    sequence (momentum SGD, several shards)."""
-    params = _tree()
-    tree_srv = ShardedParameterServer(
-        params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1, momentum=0.9), 2, 3,
-        apply_mode="tree")
-    pk_srv = ShardedParameterServer(
-        params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1, momentum=0.9), 2, 3,
-        apply_mode="fused")
-    for i in range(12):
-        g = _grads_like(params, seed=i)
-        tree_srv.push(i % 2, g)
-        pk_srv.push_packed(i % 2, pk_srv.plan.pack(g))
-    assert _max_diff(tree_srv.params, pk_srv.params) < 1e-5
-    assert tree_srv.shard_versions() == pk_srv.shard_versions()
-
-
 def test_packed_push_zero_server_repacks():
     """Acceptance probe: one packed push = zero per-leaf concats, zero
     pack/unpack events, and at most one kernel launch per shard."""
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 1, 3, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.1), 1, 3)
     wire = server.plan.pack(_grads_like(params, seed=0))
     server.push_packed(0, wire)          # warm up
     WIRE.reset()
@@ -207,7 +186,7 @@ def test_packed_push_with_compression_one_extra_launch_per_shard():
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 1, 3, apply_mode="fused",
+        lambda: ServerOptimizer(lr=0.1), 1, 3,
         wire_compression="int8")
     wire = server.plan.pack(_grads_like(params, seed=0))
     server.push_packed(0, wire)
@@ -222,23 +201,13 @@ def test_pull_packed_version_keyed_snapshot_cache():
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 1, 3, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.1), 1, 3)
     w1 = server.pull_packed(0)
     assert server.pull_packed(0) is w1      # cache hit, same versions
     server.push_packed(0, server.plan.pack(_grads_like(params, seed=1)))
     w2 = server.pull_packed(0)
     assert w2 is not w1
     assert _max_diff(server.plan.unpack(w2), server.params) < 1e-6
-
-
-def test_packed_api_requires_fused_store():
-    server = ShardedParameterServer(
-        _tree(), make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 1, 2, apply_mode="tree")
-    with pytest.raises(ValueError):
-        server.pull_packed(0)
-    with pytest.raises(ValueError):
-        server.push_packed(0, server.plan.pack(_tree()))
 
 
 def test_push_packed_rejects_mismatched_wire():
@@ -248,38 +217,33 @@ def test_push_packed_rejects_mismatched_wire():
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 1, 3, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.1), 1, 3)
     rows = server.plan.wire_layout().total_rows
     with pytest.raises(ValueError):
         server.push_packed(0, jnp.zeros((rows - 8, 512)))
     with pytest.raises(ValueError):
         server.push_packed(0, [jnp.zeros((8, 512))])   # wrong count
-    mono = ParameterServer(params, make_policy("asp"),
-                           ServerOptimizer(lr=0.1), 1,
-                           apply_mode="packed")
-    assert mono.plan.wire_layout().total_rows == 8
     with pytest.raises(ValueError):
-        mono.push_packed(0, jnp.zeros((16, 512)))
+        server.push_packed(0, jnp.zeros((rows + 8, 512)))
 
 
 def test_tree_pull_unpacks_outside_shard_lock(monkeypatch):
-    """Satellite: after an apply, a fused-mode pull rebuilds the piece
-    cache WITHOUT holding the shard lock — a concurrent push must be
-    able to take the lock mid-pull."""
+    """After an apply, ``snapshot()`` (the pytree read) unpacks WITHOUT
+    holding the shard lock — a concurrent push must be able to take the
+    lock mid-read."""
     from repro.ps.sharded.plan import ShardPlan
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 2, 1, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.1), 2, 1)
     server.push_packed(0, server.plan.pack(_grads_like(params, seed=0)))
     st = server.shards[0]
-    assert st._pieces is None               # cache invalidated
 
     lock_free_during_unpack = threading.Event()
     orig = ShardPlan.shard_pieces_from_wire
 
     def probed(self, buf, j, dtype=None):
-        # While the pull is unpacking, the shard lock must be free.
+        # While the read is unpacking, the shard lock must be free.
         got = st.cond.acquire(timeout=5.0)
         if got:
             st.cond.release()
@@ -287,82 +251,46 @@ def test_tree_pull_unpacks_outside_shard_lock(monkeypatch):
         return orig(self, buf, j, dtype)
 
     monkeypatch.setattr(ShardPlan, "shard_pieces_from_wire", probed)
-    server.pull(0)
+    snap = server.snapshot()
     assert lock_free_during_unpack.is_set()
-    # second pull is a cache hit (no new unpack)
-    monkeypatch.setattr(ShardPlan, "shard_pieces_from_wire", orig)
-    WIRE.reset()
-    server.pull(0)
-    assert WIRE.snapshot()["unpacks"] == 0
+    assert _max_diff(snap, server.plan.unpack(server.pull_packed())) == 0.0
 
 
-def test_pull_cache_not_installed_if_version_moved(monkeypatch):
-    """The outside-lock unpack must not clobber a newer version's state:
-    if a push lands mid-unpack, the stale piece cache is discarded."""
-    from repro.ps.sharded.plan import ShardPlan
+@pytest.mark.parametrize("n_shards", [1, 3, 8])
+def test_snapshot_unpacks_the_packed_store(n_shards):
+    """``snapshot()`` is the packed store unpacked, empty shards
+    included (8 shards over 4 pieces), before and after an apply."""
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), 2, 1, apply_mode="fused")
-    wire0 = server.plan.pack(_grads_like(params, seed=0))
-    server.push_packed(0, wire0)
-    st = server.shards[0]
-    orig = ShardPlan.shard_pieces_from_wire
-
-    def racing(self, buf, j, dtype=None):
-        out = orig(self, buf, j, dtype)
-        monkeypatch.setattr(ShardPlan, "shard_pieces_from_wire", orig)
-        server.push_packed(1, wire0)                # version moves mid-pull
-        return out
-
-    monkeypatch.setattr(ShardPlan, "shard_pieces_from_wire", racing)
-    stale = server.pull(0)
-    assert st._pieces is None                       # stale cache discarded
-    fresh = server.pull(0)
-    assert _max_diff(fresh, server.params) == 0.0
-    assert _max_diff(stale, fresh) > 0.0            # pull saw the old version
+        lambda: ServerOptimizer(lr=0.1), 1, n_shards)
+    assert _max_diff(server.snapshot(), params) == 0.0
+    server.push_packed(0, server.plan.pack(_grads_like(params, seed=2)))
+    snap = server.snapshot()
+    assert _max_diff(snap, server.plan.unpack(server.pull_packed())) == 0.0
+    assert _max_diff(snap, params) > 0.0
 
 
-# ------------------------------------------------------ monolithic packed
-def test_monolithic_packed_matches_tree():
+@pytest.mark.parametrize("n_shards", [1, 3])
+def test_packed_step_packs_into_the_donated_buffer(n_shards):
+    """``packed_step`` runs the pytree step on the unpacked wire, packs
+    its gradients into the donated gradient buffer and passes ``aux``
+    through."""
     params = _tree()
-    mono = ParameterServer(params, make_policy("ssp", staleness=2),
-                           ServerOptimizer(lr=0.1, momentum=0.9), 3)
-    packed = ParameterServer(params, make_policy("ssp", staleness=2),
-                             ServerOptimizer(lr=0.1, momentum=0.9), 3,
-                             apply_mode="packed")
-    for i in range(30):
-        g = _grads_like(params, seed=100 + i)
-        mono.push(i % 3, g)
-        packed.push_packed(i % 3, packed.plan.pack(g))
-    assert mono.version == packed.version == 30
-    assert _max_diff(mono.params, packed.params) < 1e-5
-    assert mono.metrics.staleness_hist == packed.metrics.staleness_hist
+    plan = build_shard_plan(params, n_shards)
 
+    def tree_step(p, batch):
+        grads = jax.tree_util.tree_map(lambda x: x * batch, p)
+        return grads, {"loss": batch, "counts": {"g": {"n": 3}}}
 
-def test_monolithic_packed_tree_push_packs_once():
-    params = _tree()
-    server = ParameterServer(params, make_policy("asp"),
-                             ServerOptimizer(lr=0.1), 1,
-                             apply_mode="packed")
-    g = _grads_like(params, seed=0)
-    server.push(0, g)                       # warm up
-    WIRE.reset()
-    server.push(0, g)
-    snap = WIRE.snapshot()
-    assert snap["packs"] == 1 and snap["pallas_calls"] == 1, snap
-
-
-def test_monolithic_packed_guards():
-    server = ParameterServer(_tree(), make_policy("asp"),
-                             ServerOptimizer(lr=0.1), 1)
-    with pytest.raises(ValueError):
-        server.push_packed(0, jnp.zeros((8, 512)))
-    with pytest.raises(ValueError):
-        server.pull_packed(0)
-    with pytest.raises(ValueError):
-        ParameterServer(_tree(), make_policy("asp"),
-                        ServerOptimizer(lr=0.1), 1, apply_mode="bogus")
+    step = packed_step(plan, tree_step)
+    wire = plan.pack(params)
+    buf = jnp.zeros_like(wire)
+    out, aux = step(wire, buf, jnp.float32(0.5))
+    assert buf.is_deleted()                       # donated
+    want = plan.pack(jax.tree_util.tree_map(lambda x: x * 0.5, params))
+    assert jnp.array_equal(out, want)
+    assert float(aux["loss"]) == 0.5 and int(aux["counts"]["g"]["n"]) == 3
 
 
 # ------------------------------------------------------ fused compression
@@ -413,10 +341,10 @@ def test_wire_compression_error_feedback_converges():
     params = _tree()
     raw = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.05), 1, 2, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.05), 1, 2)
     comp = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.05), 1, 2, apply_mode="fused",
+        lambda: ServerOptimizer(lr=0.05), 1, 2,
         wire_compression="int8")
     for i in range(16):
         w = raw.plan.pack(_grads_like(params, seed=i))
@@ -444,28 +372,26 @@ def _batches(x, y, worker, n_workers, bs=32, seed=0):
 
 
 def test_packed_worker_trains_through_sharded_server():
-    """PSWorker(wire_format='packed') + jitted unpack-grad-pack step
+    """PSWorker + the jitted unpack-grad-pack step (``packed_step``)
     converges through the packed hot path."""
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
     server = ShardedParameterServer(
         params, make_policy_factory("dssp", n_workers=3, s_lower=1,
                                     s_upper=5),
-        lambda: ServerOptimizer(lr=0.05), 3, 2, apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.05), 3, 2)
     plan = server.plan
 
     def loss_fn(p, batch):
         xb, yb = batch
         return jnp.mean((xb @ p["w"] + p["b"] - yb) ** 2)
 
-    @jax.jit
-    def step(wire, batch):
-        p = plan.unpack(wire)
+    def tree_step(p, batch):
         loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-        return plan.pack(grads), {"loss": loss}
+        return grads, {"loss": loss}
 
-    workers = [PSWorker(w, server, step, _batches(x, y, w, 3), 30,
-                        wire_format="packed")
+    step = packed_step(plan, tree_step)
+    workers = [PSWorker(w, server, step, _batches(x, y, w, 3), 30)
                for w in range(3)]
     run_cluster(server, workers, timeout=120.0)
     pred = x @ server.params["w"] + server.params["b"]

@@ -34,8 +34,8 @@ from repro.obs import device, summarize
 from repro.obs.trace import (EVENT_NAMES, FOLD_PHASES, PHASE_PROGRAMS,
                              PHASES, TRACE, TraceRecorder)
 from repro.perfcount import WIRE
-from repro.ps.server import ServerOptimizer
-from repro.ps.sharded import ShardedParameterServer
+from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
+from repro.ps.step import packed_step
 from repro.ps.worker import PSWorker, run_cluster
 from repro.wireformat import WIRE_LANES
 
@@ -56,8 +56,7 @@ def _tree(seed=0, shapes=((40, 16), (16,), (8, 8), ())):
 def _server(shards=3, workers=1):
     return ShardedParameterServer(
         _tree(), make_policy_factory("asp"),
-        lambda: ServerOptimizer(lr=0.1), workers, shards,
-        apply_mode="fused")
+        lambda: ServerOptimizer(lr=0.1), workers, shards)
 
 
 def _debug_text(fn, *args) -> str:
@@ -189,9 +188,7 @@ def test_packed_step_scopes_its_wire_glue():
         model=api.ModelSpec(arch="xlstm-125m"),
         data=api.DataSpec(seq_len=16, global_batch=2),
         sync=api.SyncSpec(mode="asp"),
-        ps=api.ServerSpec(kind="sharded", shards=2, workers=1,
-                          apply="fused"),
-        wire=api.WireSpec(format="packed"))
+        ps=api.ServerSpec(kind="sharded", shards=2, workers=1))
     with api.build_session(spec) as session:
         session.run(1)
         step, plan = session.worker_step, session.server.plan
@@ -308,8 +305,7 @@ def test_push_regions_come_from_the_apply_program():
 
 def test_delta_pull_counts_the_buffer_it_uploads():
     server = _server(shards=3)
-    worker = PSWorker(0, server, None, iter(()), 0, wire_format="packed",
-                      delta_pull=True)
+    worker = PSWorker(0, server, None, iter(()), 0, delta_pull=True)
     pull = worker._delta_puller()
     layout = server.plan.wire_layout()
     full = layout.total_rows * WIRE_LANES * jnp.dtype(layout.dtype).itemsize
@@ -349,17 +345,15 @@ def test_worker_spans_nest_and_the_sleep_is_its_own():
     server = _server(shards=2, workers=1)
     plan = server.plan
 
-    @jax.jit
-    def step(wire, batch):
-        p = plan.unpack(wire)
+    def tree_step(p, batch):
         grads = jax.tree_util.tree_map(lambda x: x * batch, p)
-        return plan.pack(grads), {"loss": jnp.float32(0.0)}
+        return grads, {"loss": jnp.float32(0.0)}
 
     batches = iter([jnp.float32(0.01)] * 4)
     TRACE.enable(source="t")
-    run_cluster(server, [PSWorker(0, server, step, batches, 4,
-                                  wire_format="packed",
-                                  speed_factor=2.0)], timeout=120.0)
+    run_cluster(server, [PSWorker(0, server, packed_step(plan, tree_step),
+                                  batches, 4, speed_factor=2.0)],
+                timeout=120.0)
     events = TRACE.drain()
     steps = _spans(events, "compute_step", 0)
     assert len(steps) == 4

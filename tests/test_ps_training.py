@@ -1,4 +1,5 @@
-"""Integration: threaded PS + real jitted JAX training under every paradigm."""
+"""Integration: threaded PS + real jitted JAX training under every paradigm
+(one-shard server, packed wire)."""
 
 
 import jax
@@ -6,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.policies import make_policy
-from repro.ps.server import ParameterServer, ServerOptimizer
+from repro.core.policies import make_policy_factory
+from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
+from repro.ps.step import packed_step
 from repro.ps.worker import PSWorker, run_cluster
 
 
@@ -19,19 +21,24 @@ def _make_problem(seed=0, dim=8, n=512):
     return x, y
 
 
-def _step_fn():
+def _server(params, policy_name, n_workers, **kw):
+    return ShardedParameterServer(
+        params, make_policy_factory(policy_name, n_workers=n_workers, **kw),
+        lambda: ServerOptimizer(lr=0.05), n_workers, 1)
+
+
+def _step_fn(server):
     def loss_fn(params, batch):
         x, y = batch
         pred = x @ params["w"] + params["b"]
         return jnp.mean((pred - y) ** 2)
 
-    @jax.jit
+    # the pytree step returns (grads, aux); the packed step wraps it
     def step(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return grads, {"loss": loss}
 
-    # step_fn must return (grads, aux)
-    return step
+    return packed_step(server.plan, step)
 
 
 def _batches(x, y, worker, n_workers, bs=32, seed=0):
@@ -47,10 +54,13 @@ def _batches(x, y, worker, n_workers, bs=32, seed=0):
 def _run(policy_name, n_workers=4, iters=30, speed_factors=None, **kw):
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
-    policy = make_policy(policy_name, n_workers=n_workers, **kw)
-    server = ParameterServer(params, policy,
-                             ServerOptimizer(lr=0.05), n_workers)
-    step = _step_fn()
+    server = _server(params, policy_name, n_workers, **kw)
+    step = _step_fn(server)
+    # Compile the step before the clock starts: a straggler sleeps in
+    # proportion to its measured step, compile time included.
+    wire = server.pull_packed()
+    jax.block_until_ready(step(wire, jnp.zeros_like(wire),
+                               next(_batches(x, y, 0, n_workers))))
     speed_factors = speed_factors or [1.0] * n_workers
     workers = [
         PSWorker(w, server, step,
@@ -107,9 +117,8 @@ def test_worker_failure_does_not_deadlock_bsp():
     """Fault tolerance: a worker dying mid-run leaves the barrier group."""
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
-    server = ParameterServer(params, make_policy("bsp"),
-                             ServerOptimizer(lr=0.05), 4)
-    step = _step_fn()
+    server = _server(params, "bsp", 4)
+    step = _step_fn(server)
     workers = [PSWorker(w, server, step, _batches(x, y, w, 4), 40)
                for w in range(4)]
     workers[3].abort()          # dies before its first pull
@@ -122,9 +131,8 @@ def test_worker_failure_does_not_deadlock_bsp():
 def test_elastic_worker_join():
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
-    server = ParameterServer(params, make_policy("ssp", staleness=2),
-                             ServerOptimizer(lr=0.05), 2)
-    step = _step_fn()
+    server = _server(params, "ssp", 2, staleness=2)
+    step = _step_fn(server)
     first = [PSWorker(w, server, step, _batches(x, y, w, 4), 15)
              for w in range(2)]
     run_cluster(server, first, timeout=60.0)

@@ -43,7 +43,7 @@ from repro.ft.reshard import (
     spread_versions,
 )
 from repro.perfcount import WIRE
-from repro.ps.server import ServerOptimizer
+from repro.ps.sharded import ServerOptimizer
 from repro.ps.sharded.plan import build_shard_plan
 from repro.ps.sharded.server import ShardedParameterServer
 from repro.wireformat import WIRE_LANES, FrameError
@@ -74,7 +74,7 @@ def make_server(params, *, n_workers=2, n_shards=4, policy="asp",
     return ShardedParameterServer(
         params, make_policy_factory(policy, n_workers=n_workers, **pkw),
         lambda: ServerOptimizer(lr=0.05, momentum=momentum),
-        n_workers, n_shards, apply_mode="fused")
+        n_workers, n_shards)
 
 
 def max_leaf_diff(a, b):
@@ -316,12 +316,6 @@ class TestLiveReshard:
         srv.stop()
 
     def test_reshard_rejects_tree_mode_and_bad_arity(self):
-        srv = ShardedParameterServer(
-            make_params(), make_policy_factory("asp", n_workers=1),
-            lambda: ServerOptimizer(lr=0.1), 1, 2, apply_mode="tree")
-        with pytest.raises(ValueError, match="fused"):
-            srv.reshard(3)
-        srv.stop()
         srv2 = make_server(make_params())
         with pytest.raises(ValueError, match="n_shards"):
             srv2.reshard(0)
@@ -375,7 +369,7 @@ def test_tcp_client_observes_epoch_and_stale_push_lands():
 
 # ========================================================= session API
 def test_session_manual_reshard_trigger(tmp_path):
-    from repro.api import SpecError, build_session
+    from repro.api import build_session
     spec = {
         "model": {"arch": "xlstm-125m", "smoke": True},
         "ps": {"kind": "sharded", "shards": 2, "workers": 1,
@@ -389,12 +383,6 @@ def test_session_manual_reshard_trigger(tmp_path):
         assert session.reshard(3) is True
         assert session.reshard(3) is False       # already there
         assert session.server.n_shards == 3
-    mono = dict(spec, ps={"kind": "mono", "workers": 1,
-                          "apply": "packed"})
-    with build_session(mono, external_workers=True) as session:
-        session.start()
-        with pytest.raises(SpecError, match="sharded"):
-            session.reshard(3)
 
 
 # ===================================================== e2e acceptance

@@ -20,8 +20,7 @@ import pytest
 
 from repro.core.policies import make_policy_factory
 from repro.obs.trace import TRACE
-from repro.ps.server import ServerOptimizer
-from repro.ps.sharded import ShardedParameterServer
+from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
 from repro.serve import (
     BatchQueue,
     DecodeRequest,
@@ -48,7 +47,7 @@ def make_server(n_workers=1, n_shards=2, policy="asp", **pkw):
         make_policy_factory(policy, n_workers=n_workers, staleness=2,
                             s_lower=0, s_upper=2, **pkw),
         lambda: ServerOptimizer(lr=0.05),
-        n_workers, n_shards, apply_mode="fused")
+        n_workers, n_shards)
 
 
 def make_subscriber(server, replica_id=9):

@@ -2,15 +2,18 @@
 
 Covers the subsystem's contract:
   * ShardPlan split/assemble round-trip, balance, oversized-leaf splitting,
-  * S=1 behavior-equivalence with the monolithic ParameterServer /
-    PSSimulator (same applied-update count, same params, same metrics),
+  * the server's p and m after a fixed push sequence equal a plain numpy
+    damped-momentum loop at every shard count, coalesced or not, and the
+    damping scale is 1/(1+staleness),
+  * global gating gives the same apply/drop sequence at any shard count,
+  * S=1 simulator equivalence with PSSimulator (same metrics),
   * per-shard staleness stays within the policy bound on EVERY shard,
   * pushes to distinct shards genuinely overlap (no global lock),
   * elastic membership (join/leave mid-run) never deadlocks any shard's
     barrier and keeps per-shard staleness profiles consistent,
-  * the batched fused apply matches the tree apply,
-  * per-shard wire compression round-trips through the identity
-    compressor (the make_compressor("none") error-state fix).
+  * per-shard wire compression: the identity compressor is bitwise a
+    plain push, int8 is close (the make_compressor("none") error-state
+    fix too).
 """
 
 import threading
@@ -23,10 +26,11 @@ import pytest
 
 from repro.core.policies import make_policy, make_policy_factory
 from repro.optim.compression import make_compressor
-from repro.ps.server import ParameterServer, ServerOptimizer
-from repro.ps.sharded import (ShardedParameterServer, build_shard_plan,
-                              hot_shard_service, run_sharded_policy)
+from repro.ps.sharded import (ServerOptimizer, ShardedParameterServer,
+                              build_shard_plan, hot_shard_service,
+                              run_sharded_policy)
 from repro.ps.simulator import run_policy
+from repro.ps.step import packed_step
 from repro.ps.worker import PSWorker, run_cluster
 
 
@@ -85,60 +89,98 @@ def test_plan_deterministic():
     assert a.shards == b.shards
 
 
-# ------------------------------------------------- S=1 server equivalence
-def test_s1_equivalent_to_monolithic_server():
-    """Acceptance: ShardedParameterServer with S=1 == ParameterServer on
-    the same deterministic push sequence (same applied-update count,
-    identical final params)."""
+# ----------------------------------------------------- apply arithmetic
+def _momenta(server):
+    """The momentum pytree, unpacked from the shards' packed stores."""
+    plan = server.plan
+    return plan.assemble([plan.shard_pieces_from_wire(st._packed_m, j)
+                          for j, st in enumerate(server.shards)])
+
+
+@pytest.mark.parametrize("coalesce", [1, 3])
+@pytest.mark.parametrize("n_shards", [1, 2, 3, 4])
+def test_server_p_and_m_match_numpy_momentum_loop(n_shards, coalesce):
+    """Three workers push the same gradient concurrently each round; the
+    server's p and m equal a plain numpy damped-momentum loop over the
+    same pushes (identical gradients within a round make the fold order
+    within a coalesced window immaterial)."""
+    lr, beta, rounds, workers = 0.1, 0.9, 4, 3
     params = _tree()
-    mono = ParameterServer(params, make_policy("ssp", staleness=2),
-                           ServerOptimizer(lr=0.1, momentum=0.9), 3)
-    shrd = ShardedParameterServer(
-        params, make_policy_factory("ssp", staleness=2),
-        lambda: ServerOptimizer(lr=0.1, momentum=0.9), 3, 1)
-    for i in range(30):   # round-robin never exceeds the SSP threshold
-        g = _grads_like(params, seed=100 + i)
-        mono.push(i % 3, g)
-        shrd.push(i % 3, g)
-    assert mono.version == shrd.version == 30
-    assert _max_diff(mono.params, shrd.params) < 1e-6
-    assert (mono.metrics.staleness_hist == shrd.metrics.staleness_hist)
+    server = ShardedParameterServer(
+        params, make_policy_factory("asp", n_workers=workers),
+        lambda: ServerOptimizer(lr=lr, momentum=beta), workers, n_shards,
+        coalesce=coalesce, coalesce_wait=5.0 if coalesce > 1 else None)
+    p = {k: np.asarray(v, np.float32) for k, v in params.items()}
+    m = {k: np.zeros_like(v) for k, v in p.items()}
+    for r in range(rounds):
+        g = _grads_like(params, seed=300 + r)
+        wire = server.plan.pack(g)
+        threads = [threading.Thread(target=server.push_packed,
+                                    args=(w, wire))
+                   for w in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+        assert not any(t.is_alive() for t in threads)
+        for _ in range(workers):
+            for k in p:
+                m[k] = np.float32(beta) * m[k] + np.asarray(g[k])
+                p[k] = p[k] - np.float32(lr) * m[k]
+    assert server.shard_versions() == [rounds * workers] * n_shards
+    got_p, got_m = server.snapshot(), _momenta(server)
+    for k in p:
+        np.testing.assert_allclose(np.asarray(got_p[k]), p[k],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got_m[k]), m[k],
+                                   rtol=1e-5, atol=1e-6)
+    server.stop()
 
 
-def test_global_gating_matches_monolithic_for_dropping_policy():
-    """Regression: in gating='global' the gate's decision must govern
-    every shard's apply — with the backup-workers policy (which DROPS
-    straggler gradients) the sharded server must apply/drop exactly the
-    pushes the monolithic server does."""
+def test_staleness_damping_scales_the_fold():
+    """Damping weighs a push by 1/(1+staleness) inside the fused apply:
+    ASP with two workers, pushes at staleness 1, 2 and 0."""
+    params = {"w": jnp.zeros((4,), jnp.float32)}
+    server = ShardedParameterServer(
+        params, make_policy_factory("asp", n_workers=2),
+        lambda: ServerOptimizer(lr=1.0, momentum=0.5,
+                                staleness_damping=True), 2, 1)
+    g = server.plan.pack({"w": jnp.ones((4,), jnp.float32)})
+    p = m = 0.0
+    for worker, staleness in ((0, 1), (0, 2), (1, 0)):
+        server.push_packed(worker, g)
+        m = 0.5 * m + 1.0 / (1.0 + staleness)
+        p = p - m
+        np.testing.assert_allclose(np.asarray(server.snapshot()["w"]),
+                                   np.full(4, p, np.float32), rtol=1e-6)
+    assert server.metrics.staleness_hist == {1: 1, 2: 1, 0: 1}
+    server.stop()
+
+
+@pytest.mark.parametrize("n_shards", [2, 4])
+def test_global_gating_drops_and_applies_as_at_one_shard(n_shards):
+    """In gating='global' the gate's decision governs every shard's
+    apply: with the backup-workers policy (which DROPS straggler
+    gradients) a partitioned store applies and drops exactly the pushes
+    the one-shard server does, and ends at the same weights."""
     params = _tree()
-    mono = ParameterServer(params,
-                           make_policy("backup", n_workers=2, backups=1),
-                           ServerOptimizer(lr=0.1), 2)
-    shrd = ShardedParameterServer(
-        params, make_policy_factory("backup", n_workers=2, backups=1),
-        lambda: ServerOptimizer(lr=0.1), 2, 2, gating="global")
-    for i in range(10):
-        g = _grads_like(params, seed=200 + i)
-        mono.push(i % 2, g)
-        shrd.push(i % 2, g)
-    assert mono.metrics.applied_updates == shrd.metrics.applied_updates
-    assert mono.metrics.dropped_updates == shrd.metrics.dropped_updates
-    assert _max_diff(mono.params, shrd.params) < 1e-6
 
+    def run(s):
+        server = ShardedParameterServer(
+            params, make_policy_factory("backup", n_workers=2, backups=1),
+            lambda: ServerOptimizer(lr=0.1), 2, s, gating="global")
+        applied = []
+        for i in range(10):
+            server.push_packed(i % 2, server.plan.pack(
+                _grads_like(params, seed=200 + i)))
+            applied.append(server.metrics.applied_updates)
+        server.stop()
+        return applied, server.metrics.dropped_updates, server.snapshot()
 
-def test_fused_apply_matches_tree_apply():
-    params = _tree()
-    servers = [
-        ShardedParameterServer(params, make_policy_factory("asp"),
-                               lambda: ServerOptimizer(lr=0.1, momentum=0.9),
-                               2, 3, apply_mode=mode)
-        for mode in ("tree", "fused")]
-    for i in range(12):
-        g = _grads_like(params, seed=i)
-        for s in servers:
-            s.push(i % 2, g)
-    assert _max_diff(servers[0].params, servers[1].params) < 1e-5
-    assert servers[0].shard_versions() == servers[1].shard_versions()
+    one, many = run(1), run(n_shards)
+    assert one[0] == many[0]
+    assert one[1] == many[1] > 0
+    assert _max_diff(one[2], many[2]) < 1e-6
 
 
 def test_fused_apply_handles_empty_shards():
@@ -146,12 +188,11 @@ def test_fused_apply_handles_empty_shards():
     zero-row pallas_call would reject its tile — apply must no-op."""
     params = {"w": jnp.ones((4, 4)), "b": jnp.ones((4,))}
     server = ShardedParameterServer(params, make_policy_factory("asp"),
-                                    lambda: ServerOptimizer(lr=0.1), 2, 8,
-                                    apply_mode="fused")
+                                    lambda: ServerOptimizer(lr=0.1), 2, 8)
     assert any(len(s.slices) == 0 for s in server.plan.shards)
-    g = jax.tree_util.tree_map(jnp.ones_like, params)
+    g = server.plan.pack(jax.tree_util.tree_map(jnp.ones_like, params))
     for i in range(4):
-        server.push(i % 2, g)
+        server.push_packed(i % 2, g)
     assert server.shard_versions() == [4] * 8
     assert _max_diff(server.params["w"],
                      jnp.ones((4, 4)) - 0.1 * 4 * jnp.ones(())) < 1e-5
@@ -188,18 +229,15 @@ def test_ps_package_import_stays_kernel_free():
 
 def test_identity_compressor_roundtrips_through_shards():
     params = _tree()
-    plain = ShardedParameterServer(params, make_policy_factory("asp"),
-                                   lambda: ServerOptimizer(lr=0.1), 2, 2)
-    ident = ShardedParameterServer(params, make_policy_factory("asp"),
-                                   lambda: ServerOptimizer(lr=0.1), 2, 2,
-                                   compressor=make_compressor("none"))
-    int8 = ShardedParameterServer(params, make_policy_factory("asp"),
-                                  lambda: ServerOptimizer(lr=0.1), 2, 2,
-                                  compressor=make_compressor("int8"))
+    servers = [ShardedParameterServer(params, make_policy_factory("asp"),
+                                      lambda: ServerOptimizer(lr=0.1), 2, 2,
+                                      wire_compression=comp)
+               for comp in (None, "none", "int8")]
+    plain, ident, int8 = servers
     for i in range(8):
-        g = _grads_like(params, seed=i)
-        for s in (plain, ident, int8):
-            s.push(i % 2, g)
+        wire = plain.plan.pack(_grads_like(params, seed=i))
+        for s in servers:
+            s.push_packed(i % 2, wire)
     assert _max_diff(plain.params, ident.params) == 0.0
     # int8 is lossy-but-error-fed-back: close, not identical
     assert 0.0 < _max_diff(plain.params, int8.params) < 0.1
@@ -263,44 +301,36 @@ def test_sim_hot_shard_adds_wait_but_keeps_bound():
 
 
 # -------------------------------------------------- threaded: concurrency
-class _SlowOptimizer(ServerOptimizer):
-    """ServerOptimizer that sleeps inside apply and records how many
-    applies run concurrently — the lock-granularity probe."""
-
-    gauge_lock = threading.Lock()
-    active = 0
-    max_active = 0
-
-    def __init__(self, sleep_s: float):
-        super().__init__(lr=0.01)
-        self._sleep = sleep_s
-
-    def step(self, params, grads, staleness):
-        cls = _SlowOptimizer
-        with cls.gauge_lock:
-            cls.active += 1
-            cls.max_active = max(cls.max_active, cls.active)
-        time.sleep(self._sleep)
-        try:
-            return super().step(params, grads, staleness)
-        finally:
-            with cls.gauge_lock:
-                cls.active -= 1
-
-
-def test_pushes_to_distinct_shards_do_not_serialize():
+def test_pushes_to_distinct_shards_do_not_serialize(monkeypatch):
     """Acceptance: concurrent pushes to distinct shards overlap — with a
     global lock the in-apply concurrency gauge could never exceed 1."""
-    _SlowOptimizer.active = 0
-    _SlowOptimizer.max_active = 0
+    from repro.kernels import ops as kops
+    real = kops.fused_update
+    gauge = {"active": 0, "max": 0}
+    gauge_lock = threading.Lock()
+
+    def slow_apply(*args, **kwargs):
+        with gauge_lock:
+            gauge["active"] += 1
+            gauge["max"] = max(gauge["max"], gauge["active"])
+        time.sleep(0.03)
+        try:
+            return real(*args, **kwargs)
+        finally:
+            with gauge_lock:
+                gauge["active"] -= 1
+
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp"),
-        lambda: _SlowOptimizer(0.03), 3, 3)
+        lambda: ServerOptimizer(lr=0.01), 3, 3)
+    wires = {(w, i): server.plan.pack(_grads_like(params, seed=w * 100 + i))
+             for w in range(3) for i in range(6)}
+    monkeypatch.setattr(kops, "fused_update", slow_apply)
 
     def pusher(w):
         for i in range(6):
-            server.push(w, _grads_like(params, seed=w * 100 + i))
+            server.push_packed(w, wires[w, i])
 
     threads = [threading.Thread(target=pusher, args=(w,)) for w in range(3)]
     for t in threads:
@@ -308,7 +338,7 @@ def test_pushes_to_distinct_shards_do_not_serialize():
     for t in threads:
         t.join(timeout=60.0)
     assert not any(t.is_alive() for t in threads)
-    assert _SlowOptimizer.max_active >= 2, (
+    assert gauge["max"] >= 2, (
         "shard applies never overlapped — pushes serialized globally")
 
 
@@ -321,18 +351,17 @@ def _make_problem(seed=0, dim=8, n=512):
     return x, y
 
 
-def _step_fn():
+def _step_fn(server):
     def loss_fn(params, batch):
         x, y = batch
         pred = x @ params["w"] + params["b"]
         return jnp.mean((pred - y) ** 2)
 
-    @jax.jit
     def step(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         return grads, {"loss": loss}
 
-    return step
+    return packed_step(server.plan, step)
 
 
 def _batches(x, y, worker, n_workers, bs=32, seed=0):
@@ -354,7 +383,7 @@ def test_training_converges_through_sharded_server(policy):
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
     server = _sharded_server(params, policy, 4, 3, s_lower=1, s_upper=5)
-    step = _step_fn()
+    step = _step_fn(server)
     workers = [PSWorker(w, server, step, _batches(x, y, w, 4), 30)
                for w in range(4)]
     run_cluster(server, workers, timeout=120.0)
@@ -370,7 +399,7 @@ def test_dssp_straggler_bounded_on_every_shard_threaded():
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
     server = _sharded_server(params, "dssp", 4, 4, s_lower=1, s_upper=4)
-    step = _step_fn()
+    step = _step_fn(server)
     workers = [PSWorker(w, server, step, _batches(x, y, w, 4), 30,
                         speed_factor=(6.0 if w == 3 else 1.0))
                for w in range(4)]
@@ -385,7 +414,7 @@ def test_worker_failure_does_not_deadlock_any_shard_barrier():
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
     server = _sharded_server(params, "bsp", 4, 3)
-    step = _step_fn()
+    step = _step_fn(server)
     workers = [PSWorker(w, server, step, _batches(x, y, w, 4), 25)
                for w in range(4)]
     workers[2].abort()
@@ -431,7 +460,7 @@ def test_remove_worker_shrinks_inflight_coalesce_window():
     params = _tree()
     server = ShardedParameterServer(
         params, make_policy_factory("asp", n_workers=2),
-        lambda: ServerOptimizer(lr=0.05), 2, 2, apply_mode="fused",
+        lambda: ServerOptimizer(lr=0.05), 2, 2,
         coalesce=3, coalesce_wait=20.0)
     wire = server.plan.pack(_grads_like(params, 1))
     done = threading.Event()
@@ -466,7 +495,7 @@ def test_elastic_join_mid_run_keeps_shard_profiles_consistent():
     x, y = _make_problem()
     params = {"w": jnp.zeros((x.shape[1], 1)), "b": jnp.zeros((1,))}
     server = _sharded_server(params, "ssp", 2, 3, staleness=2)
-    step = _step_fn()
+    step = _step_fn(server)
     first = [PSWorker(w, server, step, _batches(x, y, w, 4), 15)
              for w in range(2)]
     run_cluster(server, first, timeout=120.0)

@@ -23,8 +23,7 @@ import pytest
 
 from repro.core.policies import make_policy_factory
 from repro.perfcount import TRANSPORT
-from repro.ps.server import ServerOptimizer
-from repro.ps.sharded import ShardedParameterServer
+from repro.ps.sharded import ServerOptimizer, ShardedParameterServer
 from repro.transport import (
     PSServerEndpoint,
     ShardRouter,
@@ -50,7 +49,7 @@ def make_server(n_workers=1, n_shards=2, policy="asp", **pkw):
         make_policy_factory(policy, n_workers=n_workers, staleness=2,
                             s_lower=0, s_upper=2, **pkw),
         lambda: ServerOptimizer(lr=0.05),
-        n_workers, n_shards, apply_mode="fused")
+        n_workers, n_shards)
 
 
 def serve(kind, server, n_workers=1, shards=None):
@@ -238,7 +237,7 @@ def test_per_shard_routing_across_two_endpoints():
     """Different shards behind different endpoints (even different
     backends) apply exactly like one full-buffer push."""
     routed = make_server()
-    mono = make_server()
+    whole = make_server()
     layout = routed.plan.wire_layout()
     ep0, t0 = serve("tcp", routed, shards=[0])
     ep1, t1 = serve("shmem", routed, n_workers=1, shards=[1])
@@ -249,25 +248,24 @@ def test_per_shard_routing_across_two_endpoints():
     wire = np.random.RandomState(5).randn(
         layout.total_rows, 512).astype(np.float32)
     assert router.push_packed(wire)
-    mono.push_packed(0, jnp.asarray(wire))
+    whole.push_packed(0, jnp.asarray(wire))
     assert digest(router.pull_packed()) == \
-        digest(np.asarray(mono.pull_packed()))
-    assert routed.shard_versions() == mono.shard_versions()
+        digest(np.asarray(whole.pull_packed()))
+    assert routed.shard_versions() == whole.shard_versions()
 
     # frames for a shard an endpoint does not serve are rejected
     with pytest.raises(wf.FrameError):
         c0.pull_packed(shard=1)
     with pytest.raises(wf.FrameError):
         c0.pull_packed()  # routed endpoints require an explicit shard
-    routed.stop(), mono.stop()
+    routed.stop(), whole.stop()
     t0.shutdown(), t1.shutdown()
 
 
 def test_routed_push_rejects_global_gating():
     server = ShardedParameterServer(
         tiny_params(), make_policy_factory("asp", n_workers=1),
-        lambda: ServerOptimizer(lr=0.05), 1, 2,
-        apply_mode="fused", gating="global")
+        lambda: ServerOptimizer(lr=0.05), 1, 2, gating="global")
     with pytest.raises(ValueError, match="gating"):
         server.push_packed_shard(
             0, 0, jnp.zeros((server.plan.wire_layout().shard_rows[0], 512)))
@@ -428,8 +426,7 @@ def test_e2e_tcp_processes_match_inproc_threads():
     kw = dict(sync="dssp", n_steps=24, lr=0.02, n_shards=2, n_workers=3,
               s_lower=0, s_upper=3, straggler=1.5, arch="xlstm-125m",
               smoke=True)
-    inproc = train_ps(cfg, data_cfg, wire_format="packed",
-                      transport="inproc", **kw)
+    inproc = train_ps(cfg, data_cfg, transport="inproc", **kw)
     tcp = train_ps(cfg, data_cfg, transport="tcp", **kw)
 
     assert tcp.version > 0 and tcp.metrics.total_pushes >= 3
